@@ -1,0 +1,418 @@
+"""The quickest proof that euler_tpu still starts on the chip.
+
+    python chip_smoke.py          # on a machine with one TPU chip
+
+ONE process, which owns the chip from first touch to exit, drives the
+repo's main paths once through the entry points a user calls:
+
+  device   jax.devices(); anything but a TPU exits non-zero at once
+  engine   cpp/graph_engine.cc built in this run, Graph.load(native=True)
+  trainer  GraphSAGESupervised(dims=[128,128], bf16) on the flagship graph
+           (200 k nodes x 15, feat 64, batch 1024, fanout [10,10],
+           16 steps per dispatch) — the device lane (DeviceSageFlow) and
+           the host lane (SageDataFlow rows/lean + 4 prefetch workers
+           that device_put)
+  kernels  every Pallas entry point, impl="pallas" (compiled, never the
+           interpreter) against its impl="xla" reference
+  server   tools.serve.selftest: train -> ModelServer -> ServingClient
+           .predict -> parity with offline infer
+  cache    the device-lane train step compiled a second time after
+           jax.clear_caches() must come from the persistent compile cache
+
+Any phase that fails ends the run with a non-zero exit code and no result
+line. The last stdout line of a passing run is one JSON object whose
+first keys are {"ok": true, "device": {"platform", "kind", "count"}}.
+The seconds it reports are set-up times for the record, not metrics.
+"""
+
+from __future__ import annotations
+
+import collections
+import json
+import os
+import sys
+import tempfile
+import time
+import traceback
+
+import numpy as np
+
+# the flagship configuration, as bench.py sizes its headline leg
+NUM_NODES, OUT_DEGREE, FEAT_DIM = 200_000, 15, 64
+DIMS, BATCH, FANOUTS, STEPS_PER_CALL = [128, 128], 1024, [10, 10], 16
+WARM_DISPATCHES, MORE_DISPATCHES = 2, 3
+
+# gather_weighted_sum sums D products per output on the MXU while the
+# reference reduces on the VPU, so the two differ by f32 summation order
+# only: the inputs are bf16-representable, which makes every product exact
+# in f32 at any matmul precision. Bound: max |pallas - xla| over the
+# largest |xla| value. A dropped term or a bf16 accumulator is >= 1e-3.
+GWS_REL_TOL = 1e-5
+
+
+def phase_engine(workdir: str):
+    """Flagship graph, round-tripped through the on-disk shard format so
+    the C++ engine — built here, from source — serves it."""
+    from euler_tpu.datasets.synthetic import random_graph
+    from euler_tpu.graph import Graph
+    from euler_tpu.graph import format as tformat
+    from euler_tpu.graph.native import NativeGraphStore, build_engine
+
+    t0 = time.perf_counter()
+    so_path = build_engine(force=True)
+    build_s = time.perf_counter() - t0
+    graph = random_graph(
+        num_nodes=NUM_NODES, out_degree=OUT_DEGREE, feat_dim=FEAT_DIM, seed=0
+    )
+    os.makedirs(workdir)
+    tformat.write_arrays(
+        os.path.join(workdir, "part_0"), graph.shards[0].arrays
+    )
+    graph.meta.save(workdir)
+    graph = Graph.load(workdir, native=True)
+    assert isinstance(graph.shards[0], NativeGraphStore), type(graph.shards[0])
+    return graph, {"ok": True, "build_s": round(build_s, 2), "so": so_path}
+
+
+def _on_tpu(tree) -> bool:
+    import jax
+
+    return all(
+        d.platform == "tpu"
+        for leaf in jax.tree_util.tree_leaves(tree)
+        for d in leaf.devices()
+    )
+
+
+def _train_and_check(est, cache) -> dict:
+    """Two warm dispatches (the first compiles), then a few more; every
+    loss finite, the loss falling, params moving and resident on the TPU."""
+    import jax
+
+    k = STEPS_PER_CALL
+    t0 = time.perf_counter()
+    losses = est.train(total_steps=WARM_DISPATCHES * k, log=False, save=False)
+    jax.block_until_ready(est.params)
+    first_s = time.perf_counter() - t0
+    before = jax.device_get(est.params)
+    t0 = time.perf_counter()
+    losses += est.train(total_steps=MORE_DISPATCHES * k, log=False, save=False)
+    jax.block_until_ready(est.params)
+    warm_s = time.perf_counter() - t0
+    after = jax.device_get(est.params)
+
+    losses = np.asarray(losses, np.float64)
+    assert losses.shape == ((WARM_DISPATCHES + MORE_DISPATCHES) * k,), (
+        losses.shape
+    )
+    assert np.isfinite(losses).all(), losses
+    assert losses[-k:].mean() < losses[:k].mean(), (
+        "loss did not fall", losses[:k].mean(), losses[-k:].mean()
+    )
+    moved = [
+        not np.array_equal(np.asarray(a), np.asarray(b))
+        for a, b in zip(
+            jax.tree_util.tree_leaves(before), jax.tree_util.tree_leaves(after)
+        )
+    ]
+    assert all(moved), f"{moved.count(False)} param leaves did not change"
+    assert _on_tpu(est.params), "params are not on a TPU device"
+    assert _on_tpu(cache.table), "feature table is not on a TPU device"
+    return {
+        "ok": True,
+        "steps": int(len(losses)),
+        "loss_first": round(float(losses[:k].mean()), 4),
+        "loss_last": round(float(losses[-k:].mean()), 4),
+        "setup_first_two_dispatches_s": round(first_s, 2),
+        "setup_next_three_dispatches_s": round(warm_s, 3),
+    }
+
+
+def _model():
+    import jax.numpy as jnp
+
+    from euler_tpu.models import GraphSAGESupervised
+
+    return GraphSAGESupervised(
+        dims=DIMS, label_dim=2, conv_kwargs={"dtype": jnp.bfloat16}
+    )
+
+
+def _estimator(batch_fn, cache, model_dir: str):
+    from euler_tpu.estimator import Estimator, EstimatorConfig
+
+    return Estimator(
+        _model(),
+        batch_fn,
+        EstimatorConfig(
+            model_dir=model_dir,
+            learning_rate=0.01,
+            log_steps=10**9,
+            steps_per_call=STEPS_PER_CALL,
+        ),
+        feature_cache=cache,
+    )
+
+
+def phase_device_lane(graph, cache, workdir: str):
+    from euler_tpu.dataflow import DeviceSageFlow
+
+    flow = DeviceSageFlow(
+        graph, fanouts=FANOUTS, batch_size=BATCH, label_feature="label"
+    )
+    est = _estimator(flow, cache, os.path.join(workdir, "ckpt_device"))
+    return flow, _train_and_check(est, cache)
+
+
+def phase_host_lane(graph, cache, workdir: str) -> dict:
+    import itertools
+
+    from euler_tpu.dataflow import SageDataFlow
+    from euler_tpu.estimator.estimator import stack_batches
+    from euler_tpu.estimator.prefetch import Prefetcher
+
+    flow = SageDataFlow(
+        graph, ["feat"], fanouts=FANOUTS, label_feature="label",
+        rng=np.random.default_rng(0), feature_mode="rows", lean=True,
+    )
+    # batch_fn runs on the prefetch worker threads: a fresh Generator per
+    # call (a shared one would race), seeded from an atomic counter
+    seq = itertools.count()
+
+    def batch_fn():
+        rng = np.random.default_rng(np.random.SeedSequence([17, next(seq)]))
+        return (flow.query(graph.sample_node(BATCH, rng=rng)),)
+
+    prefetch = Prefetcher(
+        stack_batches(batch_fn, STEPS_PER_CALL),
+        depth=4, workers=4, device_put=True,
+    )
+    try:
+        est = _estimator(prefetch, cache, os.path.join(workdir, "ckpt_host"))
+        return _train_and_check(est, cache)
+    finally:
+        prefetch.close()
+
+
+def phase_cache(flow, cache, workdir: str, events) -> dict:
+    """The second identical program: with the in-memory executables
+    dropped, the device-lane train step must load from the persistent
+    cache instead of compiling again."""
+    import jax
+
+    jax.clear_caches()
+    hits0 = events["/jax/compilation_cache/cache_hits"]
+    est = _estimator(flow, cache, os.path.join(workdir, "ckpt_cache"))
+    t0 = time.perf_counter()
+    losses = est.train(total_steps=STEPS_PER_CALL, log=False, save=False)
+    jax.block_until_ready(est.params)
+    again_s = time.perf_counter() - t0
+    assert np.isfinite(losses).all(), losses
+    hits = events["/jax/compilation_cache/cache_hits"] - hits0
+    assert hits > 0, "second compile of the train step missed the cache"
+    return {
+        "ok": True,
+        "second_program_cache_hit": True,
+        "cache_hits": int(hits),
+        "setup_second_program_s": round(again_s, 2),
+    }
+
+
+# -- kernels ---------------------------------------------------------------
+
+
+def _bf16_exact(a: np.ndarray) -> np.ndarray:
+    import jax.numpy as jnp
+
+    return np.asarray(jnp.asarray(a, jnp.bfloat16).astype(jnp.float32))
+
+
+def _kernel_cases():
+    """(name, fn(impl) -> array, compare(out, ref) -> error-or-None) at the
+    shapes the trainers above and ROADMAP R1/R2 produce."""
+    import jax
+    import jax.numpy as jnp
+
+    from euler_tpu.ops import pallas_kernels as pk
+
+    rng = np.random.default_rng(7)
+
+    def bitwise(out, ref):
+        if out.shape != ref.shape or out.dtype != ref.dtype:
+            return f"shape/dtype {out.shape}/{out.dtype} vs {ref.shape}/{ref.dtype}"
+        bad = int((out != ref).sum())
+        return f"{bad} of {out.size} values differ" if bad else None
+
+    def close(out, ref):
+        if out.shape != ref.shape:
+            return f"shape {out.shape} vs {ref.shape}"
+        if not np.isfinite(out).all():
+            return "non-finite values"
+        err = float(np.abs(out - ref).max() / np.abs(ref).max())
+        return f"rel err {err:.2e} > {GWS_REL_TOL}" if err > GWS_REL_TOL else None
+
+    cases = []
+    # gather_weighted_sum: inside auto's region, lane-padded, chunked
+    for f, n_dst, d in ((128, 10_240, 10), (64, 10_240, 10), (602, 5_120, 25)):
+        n_src = 20_000
+        x = jnp.asarray(_bf16_exact(rng.normal(size=(n_src, f))))
+        slots = jnp.asarray(rng.integers(0, n_src, (n_dst, d)), jnp.int32)
+        w = jnp.asarray(_bf16_exact(rng.random((n_dst, d))))
+        cases.append((
+            f"gather_weighted_sum[F={f},n_dst={n_dst},D={d}]",
+            lambda impl, x=x, slots=slots, w=w: jax.jit(
+                lambda x, s, w: pk.gather_weighted_sum(x, s, w, impl)
+            )(x, slots, w),
+            close,
+        ))
+
+    # paged kernels: page size 16, k = 10 draws, a few thousand rows
+    page_size, k, rows, n_pages = 16, 10, 4096, 4096
+    n_flat = n_pages * page_size
+    fidx = jnp.asarray(rng.integers(0, n_flat, (rows, k)), jnp.int32)
+    for dtype in (np.int32, np.float32):
+        flat = jnp.asarray(rng.integers(0, 1 << 20, n_flat).astype(dtype))
+        t2d = pk._as_lane_rows(flat)
+        cases.append((
+            f"paged_gather[{np.dtype(dtype).name},rows={rows},k={k}]",
+            lambda impl, t2d=t2d: jax.jit(
+                lambda t, i: pk.paged_gather(t, i, impl)
+            )(t2d, fidx),
+            bitwise,
+        ))
+    packed = pk._as_lane_rows(
+        pk.pack_bf16_words(jnp.asarray(rng.normal(size=n_flat), jnp.float32))
+    )
+    cases.append((
+        f"paged_gather_dequant[rows={rows},k={k}]",
+        lambda impl: jax.jit(
+            lambda t, i: pk.paged_gather_dequant(t, i, impl)
+        )(packed, fidx),
+        bitwise,
+    ))
+    # per-page ascending quantized CDF, as DeviceGraphTables stages it
+    q = np.sort(
+        rng.integers(0, 1 << 32, (n_pages, page_size), dtype=np.uint64), axis=1
+    ).astype(np.uint32)
+    q2d = pk._as_lane_rows(jnp.asarray(q.reshape(-1)))
+    page = jnp.asarray(rng.integers(0, n_pages, (rows, k)), jnp.int32)
+    rbits = jnp.asarray(
+        rng.integers(0, 1 << 32, (rows, k), dtype=np.uint64).astype(np.uint32)
+    )
+    cases.append((
+        f"paged_cdf_count[page={page_size},rows={rows},k={k}]",
+        lambda impl: jax.jit(
+            lambda q, p, r: pk.paged_cdf_count(q, p, r, page_size, impl)
+        )(q2d, page, rbits),
+        bitwise,
+    ))
+
+    return cases
+
+
+def phase_kernels() -> dict:
+    """One line per kernel: compiled / refused (Mosaic's first line) /
+    mismatch. The per-kernel except exists so one run names every kernel
+    that fails; any failure still fails the phase, and with it the run."""
+    status = {}
+    for name, fn, compare in _kernel_cases():
+        ref = np.asarray(fn("xla"))
+        try:
+            t0 = time.perf_counter()
+            out = np.asarray(fn("pallas"))
+            compile_s = time.perf_counter() - t0
+        except Exception as e:  # report, finish the table, then fail
+            traceback.print_exc()
+            lines = [ln for ln in str(e).splitlines() if ln.strip()]
+            first = lines[0] if lines else ""
+            status[name] = f"refused: {type(e).__name__}: {first[:200]}"
+        else:
+            err = compare(out, ref)
+            status[name] = (
+                f"mismatch: {err}" if err else f"compiled ({compile_s:.1f}s)"
+            )
+        print(f"kernel {name}: {status[name]}", flush=True)
+    bad = [n for n, s in status.items() if not s.startswith("compiled")]
+    if bad:
+        raise SystemExit(f"chip_smoke: kernel phase failed: {bad}")
+    return {"ok": True, "kernels": status}
+
+
+def phase_server() -> dict:
+    from euler_tpu.tools.serve import selftest
+
+    t0 = time.perf_counter()
+    rc = selftest(replicas=1)
+    if rc != 0:
+        raise SystemExit(f"chip_smoke: serving selftest exited {rc}")
+    return {"ok": True, "setup_total_s": round(time.perf_counter() - t0, 2)}
+
+
+def main() -> int:
+    t_start = time.perf_counter()
+    from euler_tpu.utils.compile_cache import configure_compile_cache
+
+    cache_dir = configure_compile_cache()
+    import jax
+    import jaxlib
+
+    devices = jax.devices()
+    platform = devices[0].platform
+    if platform != "tpu":
+        print(
+            f"chip_smoke: jax platform is {platform!r}, not 'tpu' — this "
+            "script only passes on the chip and has no CPU fallback",
+            file=sys.stderr,
+        )
+        return 2
+    device = {
+        "platform": platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+    print(
+        f"device: platform={platform} device_kind={device['kind']} "
+        f"count={device['count']} jax={jax.__version__} "
+        f"jaxlib={jaxlib.__version__} compile_cache={cache_dir}",
+        flush=True,
+    )
+    events: collections.Counter = collections.Counter()
+    jax.monitoring.register_event_listener(
+        lambda name, **kw: events.update([name])
+    )
+
+    phases: dict = {}
+
+    def done(name: str, report: dict) -> None:
+        phases[name] = report
+        print(f"phase {name}: {json.dumps(report)}", flush=True)
+
+    with tempfile.TemporaryDirectory(prefix="etpu_chip_smoke_") as workdir:
+        from euler_tpu.estimator import DeviceFeatureCache
+
+        graph, report = phase_engine(os.path.join(workdir, "graph"))
+        done("engine", report)
+        cache = DeviceFeatureCache(graph, ["feat"])
+        flow, report = phase_device_lane(graph, cache, workdir)
+        done("trainer_device_lane", report)
+        done("trainer_host_lane", phase_host_lane(graph, cache, workdir))
+        done("kernels", phase_kernels())
+        done("server", phase_server())
+        done("compile_cache", phase_cache(flow, cache, workdir, events))
+
+    print(json.dumps({
+        "ok": True,
+        "device": device,
+        "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__,
+        "compile_cache_dir": cache_dir,
+        "compile_cache_hits": events["/jax/compilation_cache/cache_hits"],
+        "compile_cache_misses": events["/jax/compilation_cache/cache_misses"],
+        "total_s": round(time.perf_counter() - t_start, 1),
+        "phases": phases,
+    }))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

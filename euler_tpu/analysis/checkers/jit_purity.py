@@ -41,7 +41,6 @@ _JIT_WRAPPERS = {
     "jax.jit",
     "jax.pjit",
     "jax.experimental.pjit.pjit",
-    "jax.experimental.shard_map.shard_map",
     "jax.sharding.shard_map",
     "jax.shard_map",
 }

@@ -52,7 +52,7 @@ class ModuleSymbols:
     def __init__(self, tree: ast.Module):
         self.tree = tree
         # alias -> canonical module path ("np" -> "numpy",
-        # "shard_map" -> "jax.experimental.shard_map.shard_map")
+        # "pjit" -> "jax.experimental.pjit.pjit")
         self.aliases: dict[str, str] = {}
         self._scan_imports(tree)
         # module-level name -> canonical constructor dotted name (for

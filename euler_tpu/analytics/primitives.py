@@ -121,8 +121,7 @@ def reduce_messages(rows, keys, vals, mode: str):
 
 def stage_frontier_part(values: np.ndarray):
     """Stage one frontier shard's f64 state on device (delegates to
-    dataflow/device so the device-residency policy lives in one place);
-    returns the host array unchanged when x64 staging is unavailable."""
+    dataflow/device so the device-residency policy lives in one place)."""
     from euler_tpu.dataflow import device as _device
 
     return _device.stage_frontier(values)
@@ -546,9 +545,7 @@ class WholeGraphEngine:
         if self.device:
             from euler_tpu.dataflow import device as _device
 
-            out = _device.frontier_contrib(w, global_vec, src)
-            if out is not None:
-                return out
+            return _device.frontier_contrib(w, global_vec, src)
         return w * np.asarray(global_vec, np.float64)[src]
 
     def by_id(self, values: np.ndarray):

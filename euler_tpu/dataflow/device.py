@@ -2,8 +2,8 @@
 
 The host flows (sage.py, walk.py) sample subgraphs and walks on the CPU
 and ship int32 feature rows over PCIe/network every step — the lean wire
-minimizes the bytes, but a tunneled or remote device still pays
-per-dispatch transfer for ~10^5 rows/step. This module removes the wire
+minimizes the bytes, but every dispatch still pays a host→device
+transfer for ~10^5 rows/step. This module removes the wire
 entirely: the padded adjacency lives in HBM next to the feature cache,
 and every step of the scanned train loop *traces* root sampling +
 multi-hop fanout (or walk + skip-gram pair generation) as XLA ops.
@@ -159,53 +159,33 @@ def _segment_arange(counts: np.ndarray) -> np.ndarray:
 # ---------------------------------------------------------------------------
 # The whole-graph engine keeps per-shard dense f64 vertex state; staging
 # it in HBM needs jax's x64 mode, which this repo leaves OFF globally
-# (conftest runs f32). The scoped enable_x64 context preserves f64 end
-# to end, so the device path's gathers and elementwise multiplies are
-# IEEE-exact twins of the numpy host path — the order-sensitive segment
-# reductions stay on the host in primitives.reduce_messages either way.
-
-
-def _x64():
-    try:
-        from jax.experimental import enable_x64
-
-        return enable_x64
-    except ImportError:  # pragma: no cover - very old jax
-        return None
+# (conftest runs f32). The scoped jax.enable_x64 context preserves f64 end
+# to end, so on the CPU backend the device path's gathers and elementwise
+# multiplies are IEEE-exact twins of the numpy host path. The v5e emulates
+# f64: there the multiply differs from numpy by up to 5.6e-15 relative
+# (PERF.md, PR 21), so bit-parity with the host lane is a CPU property.
+# The order-sensitive segment reductions stay on the host in
+# primitives.reduce_messages either way.
 
 
 def stage_frontier(values: np.ndarray):
-    """Put one frontier shard's f64 state on device; host array when
-    x64 staging is unavailable (callers stay correct either way)."""
-    ctx = _x64()
+    """Put one frontier shard's f64 state on device."""
     values = np.ascontiguousarray(values, np.float64)
-    if ctx is None:
-        return values
-    with ctx():
-        arr = jax.device_put(values)
-    if arr.dtype != jnp.float64:  # x64 unavailable on this backend
-        return values
-    return arr
+    with jax.enable_x64(True):
+        return jax.device_put(values)
 
 
 def frontier_contrib(weights, global_vec, src_rows):
-    """Per-edge w[e] * frontier[src[e]] on device (f64 gather + multiply
-    — elementwise IEEE ops, bit-identical to the numpy host path).
-    Returns a host f64 array, or None when x64 staging is unavailable
-    (the caller then runs the numpy path)."""
-    ctx = _x64()
-    if ctx is None:
-        return None
-    with ctx():
+    """Per-edge w[e] * frontier[src[e]] on device (f64 gather + multiply);
+    returns a host f64 array. Bit-identical to the numpy host path on the
+    CPU backend only — see the note above."""
+    with jax.enable_x64(True):
         vec = jnp.asarray(np.asarray(global_vec, np.float64))
         w = jnp.asarray(np.asarray(weights, np.float64))
-        if vec.dtype != jnp.float64 or w.dtype != jnp.float64:
-            return None
         out = w * jnp.take(
             vec, jnp.asarray(np.asarray(src_rows, np.int64)), axis=0
         )
-        host = np.asarray(out, np.float64)
-    return host
+        return np.asarray(out, np.float64)
 
 
 class DeviceGraphTables:
@@ -727,9 +707,8 @@ class DeviceGraphTables:
     def _kimpl(self) -> str:
         """Paged-kernel impl derived from the global pallas mode: 'off'
         rides the jitted jnp reference, 'interpret'/'pallas' are the
-        explicit kernel forms, 'auto' defers to the kernels' own
-        measured-boundary auto (currently the reference — see
-        ops/PALLAS_BENCH.md)."""
+        explicit kernel forms, 'auto' defers to the kernels' own auto
+        (currently the reference — ops/pallas_kernels.py _paged_impl)."""
         from euler_tpu.ops import pallas_mode
 
         mode = pallas_mode()

@@ -799,7 +799,6 @@ class ReshardCoordinator:
     def _spawn_dests(self, data_dir: str) -> list[int]:
         env = dict(os.environ)
         env.update(self.env)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         env.pop("EULER_TPU_RESHARD_KILL_AT", None)  # chaos targets US
         for d in range(self.new_num_shards):
             cmd = [

@@ -176,7 +176,6 @@ class ShardSupervisor:
             cmd.append("--no-native")
         sh.log_path = os.path.join(self.wal_root, f"shard_{sh.shard}.log")
         env = dict(os.environ if self.env is None else self.env)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         if self.scrub_s is not None:
             env["EULER_TPU_SCRUB_S"] = str(self.scrub_s)
         log = open(sh.log_path, "ab")
@@ -445,7 +444,6 @@ class ReplicaGroupSupervisor:
             self.wal_root, f"shard_{m.shard}_r{m.rid}.log"
         )
         env = dict(os.environ if self.env is None else self.env)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         if self.scrub_s is not None:
             env["EULER_TPU_SCRUB_S"] = str(self.scrub_s)
         log = open(m.log_path, "ab")
@@ -687,7 +685,6 @@ class TrainerSupervisor:
             argv.append("--resume")
         cmd = [sys.executable, "-m", "euler_tpu.tools.train", *argv]
         env = dict(os.environ if self.env is None else self.env)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         log = open(self.log_path, "ab")
         try:
             # graftlint: disable=lock-unguarded-write -- callers hold self._lock around _spawn

@@ -38,15 +38,9 @@ class DeviceFeatureCache:
         feature_names,
         dtype=jnp.float32,
         sharding=None,
-        stage_chunk_rows: int | None = None,
         quant: str | None = None,
     ):
-        """stage_chunk_rows: stage the table onto the device in row chunks
-        instead of one transfer — big tables (hundreds of MB) shipped as a
-        single device_put can trip transport limits on proxied/tunneled
-        devices; chunking bounds each transfer.
-
-        quant: HBM page dtype — "f32" (exact, the default), "bf16" (half
+        """quant: HBM page dtype — "f32" (exact, the default), "bf16" (half
         the HBM, one rounding per value), or "int8" (quarter the HBM,
         per-row affine scale/zero-point) — defaults to the
         EULER_TPU_PAGE_DTYPE env knob. Dequantize happens inside
@@ -78,25 +72,7 @@ class DeviceFeatureCache:
             table = table.astype(jnp.bfloat16)
         else:
             table = table.astype(np.dtype(dtype))
-        if stage_chunk_rows and len(table) > stage_chunk_rows:
-            put = (
-                (lambda a: jax.device_put(a, sharding))
-                if sharding is not None
-                else jax.device_put
-            )
-            parts = [
-                put(table[lo : lo + stage_chunk_rows])
-                for lo in range(0, len(table), stage_chunk_rows)
-            ]
-            self.table = jnp.concatenate(parts, axis=0)
-            if sharding is not None:
-                self.table = jax.device_put(self.table, sharding)
-        else:
-            self.table = (
-                jax.device_put(table, sharding)
-                if sharding is not None
-                else jax.device_put(table)
-            )
+        self.table = jax.device_put(table, sharding)
 
     def gather(self, rows) -> jnp.ndarray:
         """int32 rows (0 = padding) → dense [n, F]; jit-safe. Quantized

@@ -25,13 +25,20 @@ _lib = None
 
 
 def build_engine(force: bool = False) -> str:
-    """Compile the engine .so if missing or stale; returns its path."""
+    """Compile the engine .so if missing or stale; returns its path.
+
+    force=True rebuilds from cpp/graph_engine.cc whatever is on disk —
+    the chip smoke and the device bench use it so they never dlopen a
+    library some other machine built. The compiler writes to a
+    per-process temp name and the result is renamed into place, so a
+    concurrent starter can never dlopen a half-written library."""
     if (
         not force
         and os.path.exists(_SO_PATH)
         and os.path.getmtime(_SO_PATH) >= os.path.getmtime(_SRC_PATH)
     ):
         return _SO_PATH
+    tmp = f"{_SO_PATH}.tmp-{os.getpid()}"
     cmd = [
         "g++",
         "-O3",
@@ -41,9 +48,14 @@ def build_engine(force: bool = False) -> str:
         "-pthread",
         _SRC_PATH,
         "-o",
-        _SO_PATH,
+        tmp,
     ]
-    subprocess.run(cmd, check=True, capture_output=True)
+    try:
+        subprocess.run(cmd, check=True, capture_output=True)
+        os.replace(tmp, _SO_PATH)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
     return _SO_PATH
 
 

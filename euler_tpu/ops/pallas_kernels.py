@@ -16,16 +16,18 @@ custom_vjp — gradient layout matches mp_ops (reference mp_ops.py:39-62).
 CPU/interpret fallback makes the same entry point usable in tests.
 
 The paged device-sampling lane (dataflow/device.py, layout="paged") adds
-two more entry points with the same impl discipline — `paged_gather`
-(ragged neighbor/weight gather through a fixed-size-page indirection,
-the Ragged-Paged-Attention access shape) and `paged_cdf_count` (the
-in-page step of the two-level quantized-CDF neighbor draw). Both carry a
-jitted jnp reference (`impl="xla"`) that is the `auto` fallback off-TPU
-and the A/B oracle; the Pallas forms are validated in interpret mode
-(tests/test_pallas.py) and exposed via `impl='pallas'`. The page-table
-binary search (`paged_page_search`) is scalar log-depth work that stays
-plain XLA in every impl — only the bandwidth-bound page reads are kernel
-territory.
+three more entry points with the same impl discipline — `paged_gather`
+and `paged_gather_dequant` (ragged neighbor/weight gather through a
+fixed-size-page indirection, the Ragged-Paged-Attention access shape) and
+`paged_cdf_count` (the in-page step of the two-level quantized-CDF
+neighbor draw). Each carries a jitted jnp reference (`impl="xla"`) that is
+the `auto` choice and the A/B oracle; the Pallas forms are exposed via
+`impl='pallas'`. chip_smoke.py compiles every Pallas form on the chip and
+compares it with its reference — that run, not the interpreter tests in
+tests/test_pallas.py (which only pin the semantics on CPU), is the
+evidence that Mosaic accepts them. The page-table binary search
+(`paged_page_search`) is scalar log-depth work that stays plain XLA in
+every impl — only the bandwidth-bound page reads are kernel territory.
 """
 
 from __future__ import annotations
@@ -119,15 +121,11 @@ def _reference_forward(x, slots, w):
     return jnp.einsum("nd,ndf->nf", w, gathered)
 
 
-# Where the DMA kernel beats XLA's gather+einsum, measured on v5e
-# (ops/PALLAS_BENCH.md has the full grid): auto picks the fused kernel in
-# the region validated end-to-end (+14% GraphSAGE at f=128 in r2;
-# re-confirmed r5: 5.12M vs 3.25M edges/s back to back). The 128 cap is a
-# MEASURED boundary, not caution: the r5 on-chip wide-F A/B (dims 256,
-# artifacts/widef_{off,pallas}.json) has XLA at 8.18M vs pallas 5.18M
-# edges/s — at f > 128 the chunked gather's k-fold DMA descriptors lose
-# to XLA's single-stream fused gather+einsum. f > 128 stays fully
-# supported via impl='pallas' for chips where that tradeoff shifts.
+# Where `auto` picks the DMA kernel over XLA's gather+einsum on TPU. The
+# boundary predates any run on the chip this repo now has: neither side of
+# it has a timing there (PERF.md), and choosing it from a measurement is
+# ROADMAP S5. f > 128 rides the chunked gather (k-fold DMA descriptors per
+# neighbor) via impl='pallas'.
 _PALLAS_AUTO_MAX_F = 128
 _PALLAS_MIN_DST = 4096
 
@@ -137,8 +135,8 @@ def gather_weighted_sum(x, slots, w, impl: str = "auto"):
     """out[i] = Σ_j w[i,j] · x[slots[i,j]].
 
     impl: 'pallas' | 'interpret' | 'xla' | 'auto'. 'auto' picks the DMA
-    kernel only where it measured faster than XLA on TPU (see
-    ops/PALLAS_BENCH.md); an explicit 'pallas' never silently falls back.
+    kernel inside the region above on TPU and XLA elsewhere; an explicit
+    'pallas' never silently falls back.
     """
     return _forward(x, slots, w, impl)
 
@@ -267,10 +265,10 @@ def _paged_gather_pallas(table2d, fidx, interpret: bool):
 
 
 def _paged_impl(impl: str) -> str:
-    # no on-chip profiling exists yet for the paged kernels, so `auto`
-    # routes everywhere to the jitted jnp reference (same stance as the
-    # measured _PALLAS_AUTO_MAX_F boundary above: auto only picks pallas
-    # where a win is measured). 'pallas'/'interpret' stay explicit.
+    # the paged kernels compile on the chip and match the reference
+    # (chip_smoke.py) but have no timing there yet, so `auto` routes
+    # everywhere to the jitted jnp reference. 'pallas'/'interpret' stay
+    # explicit; choosing between them is ROADMAP S5.
     if impl == "auto":
         return "xla"
     if impl not in ("xla", "pallas", "interpret"):
@@ -309,19 +307,23 @@ def pack_bf16_words(flat):
 
 def _unpack_bf16_word(word, odd):
     # select the half, re-widen to f32 by shifting into the high bits —
-    # bf16 is a truncated f32, so this is the exact inverse of the pack
+    # bf16 is a truncated f32, so this is the exact inverse of the pack.
+    # Works on uint32 and int32 words alike: the mask drops whatever an
+    # arithmetic >> smeared into the high half.
     half = jnp.where(odd, word >> 16, word) & 0xFFFF
-    return jax.lax.bitcast_convert_type(
-        (half << 16).astype(jnp.uint32), jnp.float32
-    )
+    return jax.lax.bitcast_convert_type(half << 16, jnp.float32)
 
 
 def _paged_gather_dequant_kernel(k, table_ref, fidx_ref, out_ref, scratch,
                                  sems):
     # same DMA/iota-select shape as _paged_gather_kernel, but fidx is a
-    # logical bf16 element index: the holding u32 word sits at fidx // 2,
-    # and the selected word is unpacked in-kernel (the RPA playbook:
+    # logical bf16 element index: the holding 32-bit word sits at
+    # fidx // 2, and the word is unpacked in-kernel (the RPA playbook:
     # compact pages in HBM, pay decode next to the gather, not on host).
+    # Two Mosaic limits (v5e, jaxlib 0.9.0) shape the body: it has no
+    # reduction over unsigned integers, so the words arrive as int32, and
+    # tpu.bitcast takes vectors only, so the whole lane row is unpacked to
+    # f32 BEFORE the select-sum picks one lane (exact: one non-zero term).
     def copies(i, buf):
         for j in range(k):
             yield pltpu.make_async_copy(
@@ -343,8 +345,8 @@ def _paged_gather_dequant_kernel(k, table_ref, fidx_ref, out_ref, scratch,
         for j in range(k):
             lane = (fidx_ref[i, j] // 2) % PAGE_LANES
             row = scratch[i % 2, j].reshape(1, PAGE_LANES)
-            word = jnp.sum(jnp.where(lanes == lane, row, 0))
-            vals.append(_unpack_bf16_word(word, fidx_ref[i, j] % 2 == 1))
+            vals_f32 = _unpack_bf16_word(row, fidx_ref[i, j] % 2 == 1)
+            vals.append(jnp.sum(jnp.where(lanes == lane, vals_f32, 0.0)))
         out_ref[i, :] = jnp.stack(vals)
 
 
@@ -367,11 +369,11 @@ def _paged_gather_dequant_pallas(table2d, fidx, interpret: bool):
         ),
         out_shape=jax.ShapeDtypeStruct((fidx.shape[0], k), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((2, k, PAGE_LANES), jnp.uint32),
+            pltpu.VMEM((2, k, PAGE_LANES), jnp.int32),
             pltpu.SemaphoreType.DMA((2, k)),
         ],
         interpret=interpret,
-    )(table2d, fidx.astype(jnp.int32))
+    )(jax.lax.bitcast_convert_type(table2d, jnp.int32), fidx.astype(jnp.int32))
     return out[:n]
 
 
@@ -382,7 +384,7 @@ def paged_gather_dequant(table2d, fidx, impl: str = "auto"):
     word); `fidx` indexes LOGICAL bf16 elements. Dequantize happens at
     the gather (in-kernel for 'pallas'), so HBM and DMA bytes are half
     the f32 path. Same impl discipline as paged_gather: 'auto' → the
-    jitted jnp reference; the Pallas form is interpret-validated."""
+    jitted jnp reference."""
     impl = _paged_impl(impl)
     fidx = fidx.astype(jnp.int32)
     if impl == "xla":
@@ -496,65 +498,11 @@ def paged_page_search(bound, pstart, npages, rbits, iters: int):
 
 
 # ---------------------------------------------------------------------------
-# Retrieval scoring kernel (embedding top-K serving lane)
+# Retrieval scoring (embedding top-K serving lane)
 # ---------------------------------------------------------------------------
 
-# lane rows streamed per grid step in the score kernel. Unlike the
-# gather kernels above, the corpus scan is data-INdependent (every page
-# is read exactly once, in order), so BlockSpec grid streaming stages
-# HBM -> VMEM and Mosaic's automatic pipelining double-buffers it — no
-# manual DMA/semaphore choreography needed.
-SCORE_TILE = 8
 
-
-def _topk_score_kernel(dp, rows_per, x_ref, q_ref, out_ref):
-    # one lane-row tile holds SCORE_TILE * rows_per packed dp-vectors
-    # (row-major flat layout, dp | PAGE_LANES so no vector straddles a
-    # lane row). The d-loop is a STATIC unroll: the same left-to-right
-    # f32 (mul, add) chain as the jitted reference, so scores are
-    # bit-identical across impls by construction.
-    rows = x_ref.shape[0] * rows_per
-    x = x_ref[:].reshape(rows, dp)
-    acc = jnp.zeros((q_ref.shape[0], rows), jnp.float32)
-    for d in range(dp):
-        acc = acc + q_ref[:, d][:, None] * x[:, d][None, :]
-    out_ref[:] = acc
-
-
-def _paged_topk_score_pallas(table2d, q, dp, interpret: bool):
-    rows_per = PAGE_LANES // dp
-    b = q.shape[0]
-    pad = (-table2d.shape[0]) % SCORE_TILE
-    if pad:
-        table2d = jnp.pad(table2d, ((0, pad), (0, 0)))
-    mt = table2d.shape[0]
-    # query lane-padded to the register width; the kernel only reads the
-    # first dp lanes, and padding with zeros keeps the pad inert
-    qp = jnp.pad(q, ((0, 0), (0, PAGE_LANES - dp)))
-    return pl.pallas_call(
-        functools.partial(_topk_score_kernel, dp, rows_per),
-        grid=(mt // SCORE_TILE,),
-        in_specs=[
-            pl.BlockSpec(
-                (SCORE_TILE, PAGE_LANES),
-                lambda i: (i, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (b, PAGE_LANES), lambda i: (0, 0), memory_space=pltpu.VMEM
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (b, SCORE_TILE * rows_per),
-            lambda i: (0, i),
-            memory_space=pltpu.VMEM,
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, mt * rows_per), jnp.float32),
-        interpret=interpret,
-    )(table2d, qp)
-
-
-def paged_topk_score(table2d, q, nrows: int, dp: int, impl: str = "auto"):
+def paged_topk_score(table2d, q, nrows: int, dp: int):
     """scores[b, i] = <flat(table2d)[i*dp : (i+1)*dp], q[b, :dp]> — the
     brute-force retrieval scorer over a paged corpus.
 
@@ -564,37 +512,29 @@ def paged_topk_score(table2d, q, nrows: int, dp: int, impl: str = "auto"):
 
     Bit-reproducibility contract (the retrieval parity oracle leans on
     it): the dot product accumulates STRICTLY left-to-right in f32 —
-    acc = f32(acc + x[d] * q[d]) for d = 0..dp-1 — in every impl and in
-    the NumPy oracle (retrieval/topk.py), so scores are bit-identical
-    across 'xla'/'pallas'/'interpret'/NumPy rather than at the mercy of
-    a reduction order XLA is free to pick. The contract additionally
-    REQUIRES operands with 12-bit-truncated significands
-    (retrieval/corpus.py quantize_sig12): LLVM contracts the mul+add
-    into FMA non-uniformly on CPU (no HLO barrier or XLA flag stops
-    it), and only exact products — which 12x12-bit significands
-    guarantee — make fma(x, q, acc) == f32(x*q) + acc identically.
-    Same impl discipline as paged_gather: 'auto' routes to the jitted
-    reference until a measured on-chip win; the Pallas form ('pallas',
-    dp | 128 only) is interpret-validated in tests/test_pallas.py.
+    acc = f32(acc + x[d] * q[d]) for d = 0..dp-1 — here and in the
+    NumPy oracle (retrieval/topk.py), so scores are bit-identical to
+    NumPy rather than at the mercy of a reduction order XLA is free to
+    pick. The contract additionally REQUIRES operands with
+    12-bit-truncated significands (retrieval/corpus.py quantize_sig12):
+    LLVM contracts the mul+add into FMA non-uniformly on CPU (no HLO
+    barrier or XLA flag stops it), and only exact products — which
+    12x12-bit significands guarantee — make fma(x, q, acc) ==
+    f32(x*q) + acc identically.
+
+    Plain XLA only. The Pallas form this entry point used to carry was
+    refused by Mosaic on the v5e (output block (B, 8*128/dp), in-kernel
+    lane-splitting reshape) and was deleted rather than rewritten:
+    ROADMAP S4 replaces this rank-1-update scan with one matmul.
     """
-    impl = _paged_impl(impl)
     q = q.astype(jnp.float32)
-    if impl == "xla":
-        flat = table2d.reshape(-1)[: nrows * dp]
-        x = flat.astype(jnp.float32).reshape(nrows, dp)
+    flat = table2d.reshape(-1)[: nrows * dp]
+    x = flat.astype(jnp.float32).reshape(nrows, dp)
 
-        def body(d, acc):
-            xcol = jax.lax.dynamic_index_in_dim(x, d, 1, keepdims=False)
-            qcol = jax.lax.dynamic_index_in_dim(q, d, 1, keepdims=False)
-            return acc + qcol[:, None] * xcol[None, :]
+    def body(d, acc):
+        xcol = jax.lax.dynamic_index_in_dim(x, d, 1, keepdims=False)
+        qcol = jax.lax.dynamic_index_in_dim(q, d, 1, keepdims=False)
+        return acc + qcol[:, None] * xcol[None, :]
 
-        acc = jnp.zeros((q.shape[0], nrows), jnp.float32)
-        return jax.lax.fori_loop(0, dp, body, acc)
-    if dp < 1 or PAGE_LANES % dp:
-        raise ValueError(
-            f"paged_topk_score pallas impl needs dp | {PAGE_LANES}, got {dp}"
-        )
-    out = _paged_topk_score_pallas(
-        table2d.astype(jnp.float32), q, dp, interpret=(impl == "interpret")
-    )
-    return out[:, :nrows]
+    acc = jnp.zeros((q.shape[0], nrows), jnp.float32)
+    return jax.lax.fori_loop(0, dp, body, acc)

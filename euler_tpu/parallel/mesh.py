@@ -20,13 +20,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
 
-# jax moved shard_map to the top level in 0.5; this image's 0.4.x still
-# has it under jax.experimental only — resolve once here so every sp/
-# embedding call site works on both
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - exercised on jax<0.5 images (like this one)
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+shard_map = jax.shard_map
 
 
 def make_mesh(

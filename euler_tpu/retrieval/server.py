@@ -58,9 +58,9 @@ class _CorpusEngine:
 
     MASK_CACHE = 64
 
-    def __init__(self, corpus: EmbeddingCorpus, impl: str = "auto"):
+    def __init__(self, corpus: EmbeddingCorpus):
         self.corpus = corpus
-        self.index = TopKIndex(corpus, impl=impl)
+        self.index = TopKIndex(corpus)
         self._masks: collections.OrderedDict = collections.OrderedDict()
         self._mask_lock = threading.Lock()
 
@@ -100,7 +100,6 @@ class RetrievalServer:
         port: int = 0,
         workers: int | None = None,
         registry=None,
-        impl: str = "auto",
         warm_k: int = 16,
         tenant_quota: TenantQuota | None = None,
     ):
@@ -113,7 +112,6 @@ class RetrievalServer:
             raise ValueError("need a corpus or a loader")
         self._loader = loader
         self.part, self.num_parts = int(part), int(num_parts)
-        self.impl = impl
         self.warm_k = int(warm_k)
         full = corpus if corpus is not None else loader(None)
         self._engine = self._build_engine(full)
@@ -147,7 +145,7 @@ class RetrievalServer:
             if self.num_parts > 1
             else full
         )
-        return _CorpusEngine(shard, impl=self.impl).warm(self.warm_k)
+        return _CorpusEngine(shard).warm(self.warm_k)
 
     # -- lifecycle -------------------------------------------------------
 

@@ -4,9 +4,7 @@ retrieval serving.
 `TopKIndex` stages one (immutable) corpus's lane-row table on device and
 answers masked dot/cosine top-K through jitted bucket-padded programs:
 one compiled program per (query-bucket, k) pair, reused across requests,
-with the score kernel behind the `paged_topk_score` impl discipline
-(ops/pallas_kernels.py — 'xla' jitted reference is the `auto` fallback
-and A/B oracle, the Pallas form is interpret-validated).
+scored by `paged_topk_score` (ops/pallas_kernels.py, plain XLA).
 
 Bit-determinism contract (PARITY.md "Retrieval scoring"):
 
@@ -15,7 +13,7 @@ Bit-determinism contract (PARITY.md "Retrieval scoring"):
     every q*x product is EXACT in f32 and FMA contraction cannot
     perturb it;
   * scores accumulate strictly left-to-right in f32 (the kernel's
-    contract), so they are bit-identical across impls and vs NumPy;
+    contract), so they are bit-identical to NumPy;
   * ties break (score desc, id asc): corpus rows are sorted by id
     ascending and `lax.top_k` prefers the lower index on equal values;
   * filtered retrieval masks scores to -inf BEFORE selection, so a
@@ -59,12 +57,10 @@ def bucket_for(b: int, buckets=BUCKETS) -> int:
 class TopKIndex:
     """Jitted bucket-padded top-K over one staged EmbeddingCorpus."""
 
-    def __init__(self, corpus: EmbeddingCorpus, impl: str = "auto",
-                 buckets=BUCKETS):
+    def __init__(self, corpus: EmbeddingCorpus, buckets=BUCKETS):
         import jax.numpy as jnp
 
         self.corpus = corpus
-        self.impl = impl
         self.buckets = tuple(buckets)
         self._n = corpus.num_rows
         self._dp = corpus.dim_padded
@@ -83,11 +79,11 @@ class TopKIndex:
 
             from euler_tpu.ops.pallas_kernels import paged_topk_score
 
-            n, dp, impl = self._n, self._dp, self.impl
+            n, dp = self._n, self._dp
 
             @jax.jit
             def run(table2d, q, mask):
-                scores = paged_topk_score(table2d, q, n, dp, impl=impl)
+                scores = paged_topk_score(table2d, q, n, dp)
                 scores = jnp.where(mask[None, :], scores, -jnp.inf)
                 return jax.lax.top_k(scores, keff)
 

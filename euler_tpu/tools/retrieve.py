@@ -88,7 +88,6 @@ def serve(args) -> int:
             host=args.host,
             port=port,
             registry=registry,
-            impl=args.impl,
             warm_k=args.warm_k,
         ).start()
         servers.append(srv)
@@ -209,6 +208,9 @@ def selftest(seed: int = 0, verbose: bool = True) -> int:
 
 
 def main(argv=None) -> int:
+    from euler_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--selftest", action="store_true",
                     help="in-process fleet smoke test vs the NumPy oracle")
@@ -230,8 +232,6 @@ def main(argv=None) -> int:
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=0)
     ap.add_argument("--registry", default=None)
-    ap.add_argument("--impl", default="auto",
-                    choices=("auto", "xla", "pallas", "interpret"))
     ap.add_argument("--warm-k", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)

@@ -400,6 +400,9 @@ def selftest(
 
 
 def main(argv=None) -> int:
+    from euler_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--selftest", action="store_true",
                     help="in-process server+client smoke; exit 0 on parity")

@@ -171,6 +171,9 @@ def apply_remote_mutation(graph, spec: dict) -> dict:
 
 
 def main(argv=None) -> int:
+    from euler_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--data", help="local graph directory (Graph.load)")
     ap.add_argument("--cluster", default=None,

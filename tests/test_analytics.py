@@ -133,6 +133,23 @@ def test_device_frontier_parity():
     assert np.array_equal(_bits(host.by_id()[1]), _bits(dev.by_id()[1]))
 
 
+def test_stage_frontier_is_f64_device_array():
+    """The device lane really stages on the device: under the installed
+    JAX the scoped x64 context yields an f64 jax.Array (an ImportError
+    fallback used to hand this lane back to NumPy silently)."""
+    import jax
+
+    from euler_tpu.dataflow.device import frontier_contrib, stage_frontier
+
+    arr = stage_frontier(np.arange(5, dtype=np.float32))
+    assert isinstance(arr, jax.Array) and arr.dtype == np.float64
+    out = frontier_contrib(
+        np.array([0.1, 0.2]), np.array([3.0, 7.0, 11.0]), np.array([2, 0])
+    )
+    assert out.dtype == np.float64
+    assert np.array_equal(out, np.array([0.1, 0.2]) * np.array([11.0, 3.0]))
+
+
 # ---------------------------------------------------------------------------
 # wire lane: remote parity + old-server degrade
 # ---------------------------------------------------------------------------

@@ -6,42 +6,28 @@ import json
 import os
 import subprocess
 import sys
-import time
+
+import pytest
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def test_probe_cache_round_trip(tmp_path, monkeypatch):
-    """The accelerator-probe cache (ISSUE 6 satellite): a cached negative
-    is honored only within its TTL, on the same boot, with the opt-out
-    respected — anything else must re-probe."""
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-
-    monkeypatch.setattr(
-        bench, "PROBE_CACHE_PATH", str(tmp_path / "probe_cache.json")
+@pytest.mark.parametrize("script", ["bench.py", "chip_smoke.py"])
+def test_device_entry_points_refuse_cpu(script):
+    """No CPU fallback: on a host without a TPU the device bench (no
+    --smoke) and chip_smoke.py exit non-zero in seconds, name the platform
+    they found, and print no result line."""
+    r = subprocess.run(
+        [sys.executable, script],
+        cwd=_ROOT,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True,
+        text=True,
+        timeout=120,
     )
-    monkeypatch.setattr(bench, "_PROBE_FAILURES", [{"attempt": 1,
-                                                    "timeout": True}])
-    bench._write_probe_cache(False)
-    rec = bench._read_probe_cache()
-    assert rec is not None and rec["ok"] is False and rec["failures"]
-    # TTL expiry invalidates
-    stale = json.load(open(bench.PROBE_CACHE_PATH))
-    stale["ts"] = time.time() - bench.PROBE_CACHE_TTL_S - 1
-    json.dump(stale, open(bench.PROBE_CACHE_PATH, "w"))
-    assert bench._read_probe_cache() is None
-    # a reboot (different boot key) invalidates
-    stale["ts"] = time.time()
-    stale["boot_key"] = "some-other-boot"
-    json.dump(stale, open(bench.PROBE_CACHE_PATH, "w"))
-    assert bench._read_probe_cache() is None
-    # EULER_BENCH_PROBE_CACHE=0 opts out of reads AND writes
-    bench._write_probe_cache(False)
-    monkeypatch.setenv("EULER_BENCH_PROBE_CACHE", "0")
-    assert bench._read_probe_cache() is None
-    os.unlink(bench.PROBE_CACHE_PATH)
-    bench._write_probe_cache(False)
-    assert not os.path.exists(bench.PROBE_CACHE_PATH)
+    assert r.returncode != 0
+    assert "'cpu'" in r.stderr and "tpu" in r.stderr, r.stderr[-500:]
+    assert not [ln for ln in r.stdout.splitlines() if ln.startswith("{")]
 
 
 def test_bench_smoke_emits_final_json_line():
