@@ -46,6 +46,13 @@ SMOKE = "--smoke" in sys.argv
 BF16 = "--bf16" in sys.argv
 BASELINE_EDGES_PER_SEC = 2_000_000.0
 
+# the flagship configuration: the headline leg, the serving lane, the model
+# half of the remote leg and chip_smoke.py all size themselves from it
+FLAGSHIP = {
+    "num_nodes": 200_000, "out_degree": 15, "feat_dim": 64,
+    "dims": [128, 128], "batch_size": 1024, "fanouts": [10, 10],
+}
+
 # internal wall-clock budget for the remote leg: the remote leg must never
 # be the reason the artifact is empty. A watchdog thread force-emits
 # partial results and exits the process if this expires — os._exit works
@@ -309,7 +316,8 @@ def _paged_device_ab(smoke: bool) -> dict:
         "dense_sample_edges_per_sec": round(dense_eps, 1),
         "paged_over_dense": round(paged_eps / max(dense_eps, 1e-9), 3),
         "paged_bit_identical": bool(identical),
-        f"paged_{mode}_ok": bool(kernels_ok),
+        "paged_kernels_ok": bool(kernels_ok),
+        "paged_kernels_mode": mode,
         "paged_hub_degree": int(flows["paged"].max_deg),
         "page_size": int(flows["paged"].page_size),
     }
@@ -1588,16 +1596,21 @@ def run(platform: str) -> tuple[float, dict]:
         # sampling, not the prefetch queue's head start, dominates the
         # window. EULER_BENCH_FEAT_DIM / EULER_BENCH_DIMS override the
         # model widths for A/B runs.
-        num_nodes, out_degree = 200_000, 15
-        feat_dim = int(os.environ.get("EULER_BENCH_FEAT_DIM", 64))
-        dims = [
-            int(x)
-            for x in os.environ.get("EULER_BENCH_DIMS", "128,128").split(",")
-        ]
+        num_nodes, out_degree = FLAGSHIP["num_nodes"], FLAGSHIP["out_degree"]
+        feat_dim = int(
+            os.environ.get("EULER_BENCH_FEAT_DIM", FLAGSHIP["feat_dim"])
+        )
+        env_dims = os.environ.get("EULER_BENCH_DIMS")
+        dims = (
+            [int(x) for x in env_dims.split(",")]
+            if env_dims else FLAGSHIP["dims"]
+        )
         # batch 1024 is the round-comparable headline config;
         # EULER_BENCH_BATCH raises it for max-throughput rows
-        batch_size = int(os.environ.get("EULER_BENCH_BATCH", 1024))
-        fanouts = [10, 10]
+        batch_size = int(
+            os.environ.get("EULER_BENCH_BATCH", FLAGSHIP["batch_size"])
+        )
+        fanouts = FLAGSHIP["fanouts"]
         # EULER_BENCH_STEPS_PER_CALL: scan depth per dispatch. The
         # device-flow default is 64, the host path keeps 16 (its per-step
         # host sampling cost sits outside the scan, so depth buys nothing
@@ -1810,8 +1823,9 @@ def run_serving(platform: str) -> tuple[float, dict]:
         fanouts, bucket, ids_per_req = [5, 5], 32, 8
         clients, reqs_per_client = 8, 6
     else:
-        num_nodes, feat_dim, dims = 200_000, 64, [128, 128]
-        fanouts, bucket, ids_per_req = [10, 10], 128, 16
+        num_nodes, feat_dim = FLAGSHIP["num_nodes"], FLAGSHIP["feat_dim"]
+        dims, fanouts = FLAGSHIP["dims"], FLAGSHIP["fanouts"]
+        bucket, ids_per_req = 128, 16
         clients, reqs_per_client = 16, 50
     graph = random_graph(
         num_nodes=num_nodes, out_degree=10, feat_dim=feat_dim, seed=3
@@ -2344,8 +2358,9 @@ def run_remote(platform: str) -> tuple[float, dict]:
         # rule as the local leg: steady-state host/RPC sampling, not the
         # prefetch queue's head start, must dominate what is being
         # claimed.
-        num_nodes, out_degree, feat_dim = 1_000_000, 20, 64
-        batch_size, fanouts, dims = 1024, [10, 10], [128, 128]
+        num_nodes, out_degree = 1_000_000, 20
+        feat_dim, dims = FLAGSHIP["feat_dim"], FLAGSHIP["dims"]
+        batch_size, fanouts = FLAGSHIP["batch_size"], FLAGSHIP["fanouts"]
         warmup, steps, steps_per_call = 48, 480, 16
 
     leg_t0 = time.monotonic()

@@ -12,10 +12,16 @@ repo's main paths once through the entry points a user calls:
            16 steps per dispatch) — the device lane (DeviceSageFlow) and
            the host lane (SageDataFlow rows/lean + 4 prefetch workers
            that device_put)
+  server   the model the device lane trained, checkpointed, restored by an
+           InferenceRuntime over a sampled [10,10] flow on the same graph
+           with the default buckets, served by a ModelServer; a
+           ServingClient's predictions must equal offline Estimator.infer
+           bit for bit. Then tools.serve.selftest (concurrent clients,
+           coalescing and the durability probe, at toy width)
   kernels  every Pallas entry point, impl="pallas" (compiled, never the
            interpreter) against its impl="xla" reference
-  server   tools.serve.selftest: train -> ModelServer -> ServingClient
-           .predict -> parity with offline infer
+  frontier the analytics f64 multiply against numpy, within the bound
+           dataflow/device.py states for an emulated f64
   cache    the device-lane train step compiled a second time after
            jax.clear_caches() must come from the persistent compile cache
 
@@ -27,8 +33,8 @@ The seconds it reports are set-up times for the record, not metrics.
 
 from __future__ import annotations
 
-import collections
 import json
+import logging
 import os
 import sys
 import tempfile
@@ -37,10 +43,16 @@ import traceback
 
 import numpy as np
 
-# the flagship configuration, as bench.py sizes its headline leg
-NUM_NODES, OUT_DEGREE, FEAT_DIM = 200_000, 15, 64
-DIMS, BATCH, FANOUTS, STEPS_PER_CALL = [128, 128], 1024, [10, 10], 16
+from bench import FLAGSHIP
+
+NUM_NODES, OUT_DEGREE = FLAGSHIP["num_nodes"], FLAGSHIP["out_degree"]
+FEAT_DIM, DIMS = FLAGSHIP["feat_dim"], FLAGSHIP["dims"]
+BATCH, FANOUTS = FLAGSHIP["batch_size"], FLAGSHIP["fanouts"]
+STEPS_PER_CALL = 16
 WARM_DISPATCHES, MORE_DISPATCHES = 2, 3
+# served request sizes: one above the top bucket (chunked 128+128+44), one
+# in the middle bucket, one in the smallest
+SERVE_REQUESTS = (300, 20, 5)
 
 # gather_weighted_sum sums D products per output on the MXU while the
 # reference reduces on the VPU, so the two differ by f32 summation order
@@ -161,7 +173,7 @@ def phase_device_lane(graph, cache, workdir: str):
         graph, fanouts=FANOUTS, batch_size=BATCH, label_feature="label"
     )
     est = _estimator(flow, cache, os.path.join(workdir, "ckpt_device"))
-    return flow, _train_and_check(est, cache)
+    return flow, est, _train_and_check(est, cache)
 
 
 def phase_host_lane(graph, cache, workdir: str) -> dict:
@@ -194,26 +206,48 @@ def phase_host_lane(graph, cache, workdir: str) -> dict:
         prefetch.close()
 
 
-def phase_cache(flow, cache, workdir: str, events) -> dict:
+class _CacheLog(logging.Handler):
+    """Module names of jax's persistent-cache hits and misses, read from
+    its compiler log: jax.monitoring counts the events but does not say
+    which program each one was."""
+
+    def __init__(self):
+        super().__init__(logging.DEBUG)
+        self.hits: list[str] = []
+        self.misses: list[str] = []
+
+    def emit(self, record: logging.LogRecord) -> None:
+        msg = str(record.msg)
+        if msg.startswith("Persistent compilation cache hit"):
+            self.hits.append(record.args[0])
+        elif msg.startswith("PERSISTENT COMPILATION CACHE MISS"):
+            self.misses.append(record.args[0])
+
+
+def phase_cache(flow, cache, workdir: str, log: _CacheLog) -> dict:
     """The second identical program: with the in-memory executables
-    dropped, the device-lane train step must load from the persistent
-    cache instead of compiling again."""
+    dropped, the device-lane train step — by name — must load from the
+    persistent cache instead of compiling again."""
     import jax
 
+    step = "jit_multi_step"
+    n_hits, n_misses = len(log.hits), len(log.misses)
     jax.clear_caches()
-    hits0 = events["/jax/compilation_cache/cache_hits"]
     est = _estimator(flow, cache, os.path.join(workdir, "ckpt_cache"))
     t0 = time.perf_counter()
     losses = est.train(total_steps=STEPS_PER_CALL, log=False, save=False)
     jax.block_until_ready(est.params)
     again_s = time.perf_counter() - t0
     assert np.isfinite(losses).all(), losses
-    hits = events["/jax/compilation_cache/cache_hits"] - hits0
-    assert hits > 0, "second compile of the train step missed the cache"
+    hits, misses = log.hits[n_hits:], log.misses[n_misses:]
+    assert step in hits and step not in misses, (
+        f"second compile of {step} did not come from the cache", hits, misses
+    )
     return {
         "ok": True,
+        "second_program": step,
         "second_program_cache_hit": True,
-        "cache_hits": int(hits),
+        "programs_from_cache": hits,
         "setup_second_program_s": round(again_s, 2),
     }
 
@@ -338,7 +372,85 @@ def phase_kernels() -> dict:
     return {"ok": True, "kernels": status}
 
 
-def phase_server() -> dict:
+def phase_server(graph, cache, est) -> dict:
+    """Serve what the device lane trained, at full width. The flow samples,
+    so a prediction is replayable only from the same Generator state: the
+    served flow is re-seeded after warm-up, the requests go one at a time,
+    and the offline reference draws from an identically seeded flow in the
+    same order."""
+    import jax
+
+    from euler_tpu.dataflow import SageDataFlow
+    from euler_tpu.estimator import EstimatorConfig, id_batches
+    from euler_tpu.serving import InferenceRuntime, ModelServer, ServingClient
+
+    seed = 5
+
+    def sampled_flow():
+        return SageDataFlow(
+            graph, ["feat"], fanouts=FANOUTS, label_feature="label",
+            rng=np.random.default_rng(seed), feature_mode="rows",
+        )
+
+    rng = np.random.default_rng(11)
+    requests = [
+        rng.integers(1, NUM_NODES + 1, size=n).astype(np.uint64)
+        for n in SERVE_REQUESTS
+    ]
+    est.save()
+    t0 = time.perf_counter()
+    flow = sampled_flow()
+    runtime = InferenceRuntime(
+        _model(), flow, EstimatorConfig(model_dir=est.cfg.model_dir),
+        feature_cache=cache,
+    )
+    runtime.warmup()
+    setup_s = time.perf_counter() - t0
+    restored = jax.tree_util.tree_leaves(jax.device_get(runtime.params))
+    trained = jax.tree_util.tree_leaves(jax.device_get(est.params))
+    assert len(restored) == len(trained) and all(
+        np.array_equal(a, b) for a, b in zip(restored, trained)
+    ), "restored checkpoint differs from the trained params"
+    assert _on_tpu(runtime.params), "served params are not on a TPU device"
+
+    flow.rng = np.random.default_rng(seed)
+    server = ModelServer(runtime).start()
+    try:
+        client = ServingClient((server.host, server.port))
+        try:
+            t0 = time.perf_counter()
+            served = [client.predict(ids) for ids in requests]
+            answer_s = time.perf_counter() - t0
+            stats = client.stats()
+        finally:
+            client.close()
+    finally:
+        server.stop()
+
+    ref_flow = sampled_flow()
+    for ids, emb in zip(requests, served):
+        _, ref = est.infer(
+            *id_batches(ref_flow, ids, runtime.bucket_for(len(ids)))
+        )
+        assert emb.shape == (len(ids), DIMS[-1]), emb.shape
+        assert emb.dtype == ref.dtype, (emb.dtype, ref.dtype)
+        assert np.isfinite(emb).all()
+        assert np.array_equal(emb, ref), (
+            f"served prediction for {len(ids)} ids differs from offline infer"
+        )
+    assert stats["requests"] == len(requests), stats
+    return {
+        "ok": True,
+        "buckets": list(runtime.buckets),
+        "request_sizes": list(SERVE_REQUESTS),
+        "device_batches": int(runtime.device_batches),
+        "bit_parity_with_infer": True,
+        "setup_restore_and_warm_s": round(setup_s, 2),
+        "setup_answer_s": round(answer_s, 3),
+    }
+
+
+def phase_serve_selftest() -> dict:
     from euler_tpu.tools.serve import selftest
 
     t0 = time.perf_counter()
@@ -346,6 +458,22 @@ def phase_server() -> dict:
     if rc != 0:
         raise SystemExit(f"chip_smoke: serving selftest exited {rc}")
     return {"ok": True, "setup_total_s": round(time.perf_counter() - t0, 2)}
+
+
+def phase_frontier() -> dict:
+    """dataflow/device.py's contract for the analytics device lane: the
+    f64 gather-multiply stays within FRONTIER_F64_RTOL of numpy on a chip
+    that emulates f64."""
+    from euler_tpu.dataflow.device import FRONTIER_F64_RTOL, frontier_contrib
+
+    rng = np.random.default_rng(3)
+    vec, w = rng.random(5_000), rng.random(40_000) * 4.0
+    src = rng.integers(0, len(vec), len(w))
+    out, ref = frontier_contrib(w, vec, src), w * vec[src]
+    assert out.dtype == np.float64 and out.shape == ref.shape
+    err = float(np.max(np.abs(out - ref) / ref))
+    assert err <= FRONTIER_F64_RTOL, (err, FRONTIER_F64_RTOL)
+    return {"ok": True, "max_rel_err": err, "bound": FRONTIER_F64_RTOL}
 
 
 def main() -> int:
@@ -376,10 +504,13 @@ def main() -> int:
         f"jaxlib={jaxlib.__version__} compile_cache={cache_dir}",
         flush=True,
     )
-    events: collections.Counter = collections.Counter()
-    jax.monitoring.register_event_listener(
-        lambda name, **kw: events.update([name])
-    )
+    # jax logs cache hits and misses at DEBUG; keep them from its own
+    # stderr handler (that logger says nothing above DEBUG by default)
+    cache_log = _CacheLog()
+    compiler_log = logging.getLogger("jax._src.compiler")
+    compiler_log.addHandler(cache_log)
+    compiler_log.setLevel(logging.DEBUG)
+    compiler_log.propagate = False
 
     phases: dict = {}
 
@@ -393,12 +524,14 @@ def main() -> int:
         graph, report = phase_engine(os.path.join(workdir, "graph"))
         done("engine", report)
         cache = DeviceFeatureCache(graph, ["feat"])
-        flow, report = phase_device_lane(graph, cache, workdir)
+        flow, est, report = phase_device_lane(graph, cache, workdir)
         done("trainer_device_lane", report)
         done("trainer_host_lane", phase_host_lane(graph, cache, workdir))
+        done("server", phase_server(graph, cache, est))
+        done("serve_selftest", phase_serve_selftest())
         done("kernels", phase_kernels())
-        done("server", phase_server())
-        done("compile_cache", phase_cache(flow, cache, workdir, events))
+        done("frontier_f64", phase_frontier())
+        done("compile_cache", phase_cache(flow, cache, workdir, cache_log))
 
     print(json.dumps({
         "ok": True,
@@ -406,8 +539,10 @@ def main() -> int:
         "jax": jax.__version__,
         "jaxlib": jaxlib.__version__,
         "compile_cache_dir": cache_dir,
-        "compile_cache_hits": events["/jax/compilation_cache/cache_hits"],
-        "compile_cache_misses": events["/jax/compilation_cache/cache_misses"],
+        # lookups count every jitted program, hits only those that took
+        # jax's >= 1 s to compile and so were stored
+        "compile_cache_hits": len(cache_log.hits),
+        "compile_cache_lookups": len(cache_log.hits) + len(cache_log.misses),
         "total_s": round(time.perf_counter() - t_start, 1),
         "phases": phases,
     }))

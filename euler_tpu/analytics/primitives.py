@@ -10,8 +10,10 @@ layer for our per-shard CSR partitions:
       ``edges_by_rows`` verb over the wire), and repartitions the edge
       list by DESTINATION owner into reduction-ready parts.
   ``ShardedFrontier``    per-shard dense f64 vertex state, host- or
-      device-resident (f64 staged under jax's x64 context so device and
-      host paths stay bit-identical).
+      device-resident (f64 staged under jax's x64 context: bit-identical
+      to the host path on the CPU backend, within
+      dataflow.device.FRONTIER_F64_RTOL per multiply on a TPU, which
+      emulates f64).
   ``broadcast`` / ``map_shards`` / ``reduce_scatter_frontier``
       the BSP step: materialize the global frontier, run a per-part
       kernel producing (row, key, val) messages, reduce them per
@@ -538,8 +540,9 @@ class WholeGraphEngine:
     def contrib(self, p: int, edge_idx: np.ndarray, global_vec, weights):
         """Per-edge contribution weights[e] * frontier[src[e]] — the
         elementwise half of a BSP step. Host numpy by default; with
-        device=True the multiply runs as f64 jax ops (elementwise IEEE,
-        bit-identical to numpy) over the staged frontier."""
+        device=True the multiply runs as f64 jax ops over the staged
+        frontier (bit-identical to numpy on CPU; within
+        FRONTIER_F64_RTOL on a TPU — dataflow/device.py)."""
         src = self.parts[p]["src"][edge_idx]
         w = weights[edge_idx]
         if self.device:

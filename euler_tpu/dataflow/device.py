@@ -160,12 +160,14 @@ def _segment_arange(counts: np.ndarray) -> np.ndarray:
 # The whole-graph engine keeps per-shard dense f64 vertex state; staging
 # it in HBM needs jax's x64 mode, which this repo leaves OFF globally
 # (conftest runs f32). The scoped jax.enable_x64 context preserves f64 end
-# to end, so on the CPU backend the device path's gathers and elementwise
-# multiplies are IEEE-exact twins of the numpy host path. The v5e emulates
-# f64: there the multiply differs from numpy by up to 5.6e-15 relative
-# (PERF.md, PR 21), so bit-parity with the host lane is a CPU property.
-# The order-sensitive segment reductions stay on the host in
-# primitives.reduce_messages either way.
+# to end. The order-sensitive segment reductions stay on the host in
+# primitives.reduce_messages on every backend.
+
+# How far the device lane's f64 multiply may sit from numpy's, relative to
+# the product. The CPU backend is IEEE-exact (difference 0, and the tests
+# hold it to that); a TPU emulates f64 and rounds differently — 5.6e-15 on
+# the v5e (my chip run, PR 21). chip_smoke.py holds the chip to this bound.
+FRONTIER_F64_RTOL = 1e-13
 
 
 def stage_frontier(values: np.ndarray):
@@ -177,8 +179,10 @@ def stage_frontier(values: np.ndarray):
 
 def frontier_contrib(weights, global_vec, src_rows):
     """Per-edge w[e] * frontier[src[e]] on device (f64 gather + multiply);
-    returns a host f64 array. Bit-identical to the numpy host path on the
-    CPU backend only — see the note above."""
+    returns a host f64 array. Equal to the numpy host path bit for bit on
+    the CPU backend and within FRONTIER_F64_RTOL of it on a TPU, so an
+    analytics run with device=True on the chip is deterministic but not
+    bit-equal to a host run."""
     with jax.enable_x64(True):
         vec = jnp.asarray(np.asarray(global_vec, np.float64))
         w = jnp.asarray(np.asarray(weights, np.float64))

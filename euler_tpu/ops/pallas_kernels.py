@@ -121,11 +121,10 @@ def _reference_forward(x, slots, w):
     return jnp.einsum("nd,ndf->nf", w, gathered)
 
 
-# Where `auto` picks the DMA kernel over XLA's gather+einsum on TPU. The
-# boundary predates any run on the chip this repo now has: neither side of
-# it has a timing there (PERF.md), and choosing it from a measurement is
-# ROADMAP S5. f > 128 rides the chunked gather (k-fold DMA descriptors per
-# neighbor) via impl='pallas'.
+# Where `auto` picks the DMA kernel over XLA's gather+einsum on TPU.
+# Neither side of the boundary has a timing on the chip (PERF.md); choosing
+# it from a measurement is ROADMAP S5. f > 128 rides the chunked gather
+# (k-fold DMA descriptors per neighbor) via impl='pallas'.
 _PALLAS_AUTO_MAX_F = 128
 _PALLAS_MIN_DST = 4096
 
@@ -522,10 +521,10 @@ def paged_topk_score(table2d, q, nrows: int, dp: int):
     12x12-bit significands guarantee — make fma(x, q, acc) ==
     f32(x*q) + acc identically.
 
-    Plain XLA only. The Pallas form this entry point used to carry was
-    refused by Mosaic on the v5e (output block (B, 8*128/dp), in-kernel
-    lane-splitting reshape) and was deleted rather than rewritten:
-    ROADMAP S4 replaces this rank-1-update scan with one matmul.
+    Plain XLA: a Pallas kernel over this layout needs a (B, 8*128/dp)
+    output block, below Mosaic's (8, 128) tile, and a lane-splitting
+    reshape inside the kernel. ROADMAP S4 replaces this rank-1-update
+    scan with one matmul.
     """
     q = q.astype(jnp.float32)
     flat = table2d.reshape(-1)[: nrows * dp]

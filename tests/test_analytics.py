@@ -127,6 +127,12 @@ def test_tolerance_stop_is_deterministic():
 
 
 def test_device_frontier_parity():
+    """The device lane's contract (dataflow/device.py): bit-equal to the
+    host lane on the CPU backend these tests run on; on a TPU the bound is
+    FRONTIER_F64_RTOL, which chip_smoke.py checks on the chip."""
+    import jax
+
+    assert jax.default_backend() == "cpu"
     data = _graph_dict()
     host = pagerank(Graph.from_json(data, num_partitions=2))
     dev = pagerank(Graph.from_json(data, num_partitions=2), device=True)
@@ -135,8 +141,7 @@ def test_device_frontier_parity():
 
 def test_stage_frontier_is_f64_device_array():
     """The device lane really stages on the device: under the installed
-    JAX the scoped x64 context yields an f64 jax.Array (an ImportError
-    fallback used to hand this lane back to NumPy silently)."""
+    JAX the scoped x64 context yields an f64 jax.Array, not a NumPy one."""
     import jax
 
     from euler_tpu.dataflow.device import frontier_contrib, stage_frontier

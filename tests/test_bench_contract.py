@@ -61,7 +61,8 @@ def test_bench_smoke_emits_final_json_line():
     # validation, all on the artifact
     assert row["paged"] is True, row
     assert row["paged_bit_identical"] is True
-    assert row["paged_interpret_ok"] is True
+    assert row["paged_kernels_ok"] is True
+    assert row["paged_kernels_mode"] == "interpret"  # --smoke is the CPU run
     assert row["paged_sample_edges_per_sec"] > 0
     assert row["dense_sample_edges_per_sec"] > 0
     assert row["paged_over_dense"] > 0
