@@ -26,9 +26,13 @@ repo's main paths once through the entry points a user calls:
            jax.clear_caches() must come from the persistent compile cache
 
 Any phase that fails ends the run with a non-zero exit code and no result
-line. The last stdout line of a passing run is one JSON object whose
-first keys are {"ok": true, "device": {"platform", "kind", "count"}}.
-The seconds it reports are set-up times for the record, not metrics.
+line. A passing run prints one `report: {...}` line (per-phase status,
+per-kernel status, cache directory and hits; its seconds are set-up times
+for the record, not metrics) and then, as the last stdout line, exactly
+
+    {"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}
+
+with the device as JAX reports it.
 """
 
 from __future__ import annotations
@@ -476,6 +480,17 @@ def phase_frontier() -> dict:
     return {"ok": True, "max_rel_err": err, "bound": FRONTIER_F64_RTOL}
 
 
+def result_line(devices) -> str:
+    """The last stdout line of a passing run: these keys and no others,
+    the device as JAX reports it."""
+    device = {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+    return json.dumps({"ok": True, "device": device})
+
+
 def main() -> int:
     t_start = time.perf_counter()
     from euler_tpu.utils.compile_cache import configure_compile_cache
@@ -493,14 +508,9 @@ def main() -> int:
             file=sys.stderr,
         )
         return 2
-    device = {
-        "platform": platform,
-        "kind": devices[0].device_kind,
-        "count": len(devices),
-    }
     print(
-        f"device: platform={platform} device_kind={device['kind']} "
-        f"count={device['count']} jax={jax.__version__} "
+        f"device: platform={platform} device_kind={devices[0].device_kind} "
+        f"count={len(devices)} jax={jax.__version__} "
         f"jaxlib={jaxlib.__version__} compile_cache={cache_dir}",
         flush=True,
     )
@@ -533,9 +543,7 @@ def main() -> int:
         done("frontier_f64", phase_frontier())
         done("compile_cache", phase_cache(flow, cache, workdir, cache_log))
 
-    print(json.dumps({
-        "ok": True,
-        "device": device,
+    report = {
         "jax": jax.__version__,
         "jaxlib": jaxlib.__version__,
         "compile_cache_dir": cache_dir,
@@ -545,7 +553,9 @@ def main() -> int:
         "compile_cache_lookups": len(cache_log.hits) + len(cache_log.misses),
         "total_s": round(time.perf_counter() - t_start, 1),
         "phases": phases,
-    }))
+    }
+    print(f"report: {json.dumps(report)}", flush=True)
+    print(result_line(devices), flush=True)
     return 0
 
 

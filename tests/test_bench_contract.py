@@ -30,6 +30,29 @@ def test_device_entry_points_refuse_cpu(script):
     assert not [ln for ln in r.stdout.splitlines() if ln.startswith("{")]
 
 
+def test_chip_smoke_result_line_has_the_contract_keys_only():
+    """The driver reads chip_smoke.py's last stdout line and refuses any
+    key beyond ok / device{platform, kind, count}; the per-phase report
+    goes on the `report:` line before it."""
+    import jax
+
+    sys.path.insert(0, _ROOT)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(_ROOT)
+    devices = jax.devices()
+    row = json.loads(chip_smoke.result_line(devices))
+    assert row == {
+        "ok": True,
+        "device": {
+            "platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices),
+        },
+    }
+
+
 def test_bench_smoke_emits_final_json_line():
     env = dict(os.environ)
     env.update(
