@@ -71,6 +71,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from euler_tpu.utils import trace
+
 from .base import Block, MiniBatch
 
 _STAGE_CHUNK = 16384
@@ -327,26 +329,29 @@ class DeviceGraphTables:
                 "device flows stage the adjacency host-side and need "
                 "local shards or remote shards (wire staging)"
             )
-        ids, wn, nt = _node_table(graph)
-        # kept host-side for refresh_rows: the published-mutation restage
-        # resolves global rows back to ids and re-fetches their adjacency
-        self._ids_host = ids
-        self._edge_types = (
-            None if edge_types is None else [int(t) for t in edge_types]
-        )
-        self._stage_adjacency(
-            graph, ids, edge_types, max_degree, stage_types,
-            layout=layout, page_size=page_size,
-        )
-        self._stage_nodes(graph, ids, wn, nt, roots_pool, root_node_type)
+        with trace.span("stage.graph"):
+            ids, wn, nt = _node_table(graph)
+            # kept host-side for refresh_rows: the published-mutation
+            # restage resolves global rows back to ids and re-fetches
+            # their adjacency
+            self._ids_host = ids
+            self._edge_types = (
+                None if edge_types is None else [int(t) for t in edge_types]
+            )
+            self._stage_adjacency(
+                graph, ids, edge_types, max_degree, stage_types,
+                layout=layout, page_size=page_size,
+            )
+            self._stage_nodes(graph, ids, wn, nt, roots_pool, root_node_type)
 
     def _stage_degrees(self, graph, ids, edge_types) -> np.ndarray:
         """Per-node total degree, swept in chunks (one bounded RPC per
         chunk on remote graphs; degree_sum is ReadCache-deterministic)."""
         degs = np.zeros(len(ids), np.int64)
-        for lo in range(0, len(ids), _STAGE_CHUNK):
-            sub = ids[lo : lo + _STAGE_CHUNK]
-            degs[lo : lo + len(sub)] = graph.degree_sum(sub, edge_types)
+        with trace.span("stage.graph.degrees"):
+            for lo in range(0, len(ids), _STAGE_CHUNK):
+                sub = ids[lo : lo + _STAGE_CHUNK]
+                degs[lo : lo + len(sub)] = graph.degree_sum(sub, edge_types)
         return degs
 
     def _stage_adjacency(
@@ -393,52 +398,59 @@ class DeviceGraphTables:
             np.full((n + 1, dmax), -1, dtype=np.int32) if stage_types else None
         )
         unit_w = True
-        for lo in range(0, n, _STAGE_CHUNK):
-            sub = ids[lo : lo + _STAGE_CHUNK]
-            nbr, w, tt, mask, _ = graph.get_full_neighbor(
-                sub, edge_types, max_degree=dmax
-            )
-            unit_w = unit_w and bool(np.all(w[mask] == 1.0))
-            rows = graph.lookup_rows(nbr.ravel()).reshape(nbr.shape)
-            # row+1 encoding, 0 = padding (matches DeviceFeatureCache's
-            # zero row); masked or unknown neighbors collapse to padding
-            block = np.where(mask & (rows >= 0), rows + 1, 0).astype(np.int32)
-            # compact valid entries to the front so idx < deg hits them
-            order = np.argsort(block == 0, axis=1, kind="stable")
-            sl = slice(1 + lo, 1 + lo + len(sub))
-            adj[sl, : block.shape[1]] = np.take_along_axis(block, order, axis=1)
-            wtab[sl, : block.shape[1]] = np.take_along_axis(
-                np.where(block > 0, w, 0.0).astype(np.float32), order, axis=1
-            )
-            if ttab is not None:  # edge types of each slot (KG relations)
-                ttab[sl, : block.shape[1]] = np.take_along_axis(
-                    np.where(block > 0, tt, -1).astype(np.int32), order, axis=1
+        with trace.span("stage.graph.sweep"):
+            for lo in range(0, n, _STAGE_CHUNK):
+                sub = ids[lo : lo + _STAGE_CHUNK]
+                nbr, w, tt, mask, _ = graph.get_full_neighbor(
+                    sub, edge_types, max_degree=dmax
                 )
-            deg[sl] = (block > 0).sum(axis=1)
-        # a positive-degree row whose weights are all zero is unsampleable
-        # (host _WeightedSampler semantics: zero total → padding)
-        # per-node out-strength (edge-weight row sums): zero-strength rows
-        # are unsampleable, and DeviceGaeFlow draws edge sources ∝ it
-        strength = wtab.sum(axis=1, dtype=np.float64)
-        deg[strength <= 0.0] = 0
-        self._out_strength = strength
-        self.adj = jax.device_put(adj)
-        self.deg = jax.device_put(deg)
-        self.unit_w = unit_w
-        # weighted graphs stage the RAW weight rows (exact values for
-        # edge_w and bias math) plus the per-row quantized CDF — the ONE
-        # inversion table shared bit-for-bit with the paged layout
-        # (trailing f64 cumsum at staging; device keeps uint32)
-        self.wtab = None if unit_w else jax.device_put(wtab)
-        if unit_w:
-            self.qtab = None
-        else:
-            valid = (
-                np.arange(dmax)[None, :] < deg[:, None]
-            )
-            self.qtab = jax.device_put(_quantize_rows(wtab, valid))
-        self.ttab = jax.device_put(ttab) if ttab is not None else None
-        self.max_deg = dmax
+                unit_w = unit_w and bool(np.all(w[mask] == 1.0))
+                rows = graph.lookup_rows(nbr.ravel()).reshape(nbr.shape)
+                # row+1 encoding, 0 = padding (matches DeviceFeatureCache's
+                # zero row); masked or unknown neighbors collapse to padding
+                block = np.where(mask & (rows >= 0), rows + 1, 0)
+                block = block.astype(np.int32)
+                # compact valid entries to the front so idx < deg hits them
+                order = np.argsort(block == 0, axis=1, kind="stable")
+                sl = slice(1 + lo, 1 + lo + len(sub))
+                adj[sl, : block.shape[1]] = np.take_along_axis(
+                    block, order, axis=1
+                )
+                wtab[sl, : block.shape[1]] = np.take_along_axis(
+                    np.where(block > 0, w, 0.0).astype(np.float32),
+                    order, axis=1,
+                )
+                if ttab is not None:  # edge types of each slot (KG relations)
+                    ttab[sl, : block.shape[1]] = np.take_along_axis(
+                        np.where(block > 0, tt, -1).astype(np.int32),
+                        order, axis=1,
+                    )
+                deg[sl] = (block > 0).sum(axis=1)
+        with trace.span("stage.graph.planes"):
+            # a positive-degree row whose weights are all zero is unsampleable
+            # (host _WeightedSampler semantics: zero total → padding)
+            # per-node out-strength (edge-weight row sums): zero-strength rows
+            # are unsampleable, and DeviceGaeFlow draws edge sources ∝ it
+            strength = wtab.sum(axis=1, dtype=np.float64)
+            deg[strength <= 0.0] = 0
+            self._out_strength = strength
+            self.adj = jax.device_put(adj)
+            self.deg = jax.device_put(deg)
+            self.unit_w = unit_w
+            # weighted graphs stage the RAW weight rows (exact values for
+            # edge_w and bias math) plus the per-row quantized CDF — the ONE
+            # inversion table shared bit-for-bit with the paged layout
+            # (trailing f64 cumsum at staging; device keeps uint32)
+            self.wtab = None if unit_w else jax.device_put(wtab)
+            if unit_w:
+                self.qtab = None
+            else:
+                valid = (
+                    np.arange(dmax)[None, :] < deg[:, None]
+                )
+                self.qtab = jax.device_put(_quantize_rows(wtab, valid))
+            self.ttab = jax.device_put(ttab) if ttab is not None else None
+            self.max_deg = dmax
 
     def _stage_paged(self, graph, ids, degs, edge_types, page_size: int):
         """Ragged paged staging: compacted neighbor entries (same order
@@ -464,94 +476,99 @@ class DeviceGraphTables:
         unit_w = True
         vals_p, w_p, q_p = [], [], []
         lo = 0
-        while lo < n:
-            # temp budget: [chunk, cap] padded host arrays per sweep step
-            cap_hint = max(int(degs[lo : lo + _STAGE_CHUNK].max(initial=1)), 1)
-            chunk = max(
-                256, min(_STAGE_CHUNK, _STAGE_TEMP_BYTES // (cap_hint * 8))
+        with trace.span("stage.graph.sweep"):
+            while lo < n:
+                # temp budget: [chunk, cap] padded host arrays per sweep step
+                cap_hint = max(
+                    int(degs[lo : lo + _STAGE_CHUNK].max(initial=1)), 1
+                )
+                chunk = max(
+                    256, min(_STAGE_CHUNK, _STAGE_TEMP_BYTES // (cap_hint * 8))
+                )
+                sub = ids[lo : lo + chunk]
+                cap = max(int(degs[lo : lo + len(sub)].max(initial=0)), 1)
+                nbr, w, _, mask, _ = graph.get_full_neighbor(
+                    sub, edge_types, max_degree=cap
+                )
+                unit_w = unit_w and bool(np.all(w[mask] == 1.0))
+                rows = graph.lookup_rows(nbr.ravel()).reshape(nbr.shape)
+                blk0 = np.where(mask & (rows >= 0), rows + 1, 0)
+                blk0 = blk0.astype(np.int32)
+                order = np.argsort(blk0 == 0, axis=1, kind="stable")
+                block = np.take_along_axis(blk0, order, axis=1)
+                wblk = np.take_along_axis(
+                    np.where(blk0 > 0, w, 0.0).astype(np.float32), order, axis=1
+                )
+                d = (block > 0).sum(axis=1).astype(np.int32)
+                st = wblk.sum(axis=1, dtype=np.float64)
+                d[st <= 0.0] = 0  # zero-strength rows are unsampleable
+                sl = slice(1 + lo, 1 + lo + len(sub))
+                deg[sl] = d
+                strength[sl] = st
+                valid = np.arange(block.shape[1])[None, :] < d[:, None]
+                vals_p.append(block[valid])
+                w_p.append(wblk[valid])
+                q_p.append(_quantize_rows(wblk, valid)[valid])
+                lo += len(sub)
+        with trace.span("stage.graph.planes"):
+            self._out_strength = strength
+            npages = -(-deg.astype(np.int64) // P)  # ceil(deg/P); 0 for deg 0
+            ps = np.zeros(n + 2, dtype=np.int64)
+            ps[1:] = np.cumsum(npages)
+            total_pages = max(int(ps[-1]), 1)
+            flat = np.zeros(total_pages * P, dtype=np.int32)
+            flat_w = np.zeros(total_pages * P, dtype=np.float32)
+            flat_q = np.full(total_pages * P, _U32_MAX, dtype=np.uint32)
+            # entries of node r (row+1 space) land at ps[r]*P + [0, deg_r)
+            dest = np.repeat(ps[:-1] * P, deg) + _segment_arange(deg)
+            if len(dest):
+                flat[dest] = np.concatenate(vals_p)
+                flat_w[dest] = np.concatenate(w_p)
+                flat_q[dest] = np.concatenate(q_p)
+            self.pages2d = _as_lane_rows(jnp.asarray(flat))
+            self._ps_host = ps  # page table, host copy (refresh_rows spans)
+            self.page_start = jax.device_put(ps.astype(np.int32))
+            self.deg = jax.device_put(deg)
+            self.unit_w = unit_w
+            # EULER_TPU_PAGE_DTYPE=bf16 packs the weight plane two-bf16-per-
+            # u32 (half the HBM + DMA bytes) and dequantizes inside the
+            # gather. Emitted batches already ship bf16 edge weights, and
+            # bf16(bf16(x)) == bf16(x), so packed draws stay BIT-IDENTICAL
+            # to the f32 plane — this lane spends no accuracy budget. Odd
+            # page sizes would let a row's page span straddle a packed word
+            # at refresh time, so P=1 stays unpacked.
+            self._page_w_packed = (
+                not unit_w and page_dtype() == "bf16" and P % 2 == 0
             )
-            sub = ids[lo : lo + chunk]
-            cap = max(int(degs[lo : lo + len(sub)].max(initial=0)), 1)
-            nbr, w, _, mask, _ = graph.get_full_neighbor(
-                sub, edge_types, max_degree=cap
-            )
-            unit_w = unit_w and bool(np.all(w[mask] == 1.0))
-            rows = graph.lookup_rows(nbr.ravel()).reshape(nbr.shape)
-            blk0 = np.where(mask & (rows >= 0), rows + 1, 0).astype(np.int32)
-            order = np.argsort(blk0 == 0, axis=1, kind="stable")
-            block = np.take_along_axis(blk0, order, axis=1)
-            wblk = np.take_along_axis(
-                np.where(blk0 > 0, w, 0.0).astype(np.float32), order, axis=1
-            )
-            d = (block > 0).sum(axis=1).astype(np.int32)
-            st = wblk.sum(axis=1, dtype=np.float64)
-            d[st <= 0.0] = 0  # zero-strength rows are unsampleable
-            sl = slice(1 + lo, 1 + lo + len(sub))
-            deg[sl] = d
-            strength[sl] = st
-            valid = np.arange(block.shape[1])[None, :] < d[:, None]
-            vals_p.append(block[valid])
-            w_p.append(wblk[valid])
-            q_p.append(_quantize_rows(wblk, valid)[valid])
-            lo += len(sub)
-        self._out_strength = strength
-        npages = -(-deg.astype(np.int64) // P)  # ceil(deg/P); 0 for deg 0
-        ps = np.zeros(n + 2, dtype=np.int64)
-        ps[1:] = np.cumsum(npages)
-        total_pages = max(int(ps[-1]), 1)
-        flat = np.zeros(total_pages * P, dtype=np.int32)
-        flat_w = np.zeros(total_pages * P, dtype=np.float32)
-        flat_q = np.full(total_pages * P, _U32_MAX, dtype=np.uint32)
-        # entries of node r (row+1 space) land at ps[r]*P + [0, deg_r)
-        dest = np.repeat(ps[:-1] * P, deg) + _segment_arange(deg)
-        if len(dest):
-            flat[dest] = np.concatenate(vals_p)
-            flat_w[dest] = np.concatenate(w_p)
-            flat_q[dest] = np.concatenate(q_p)
-        self.pages2d = _as_lane_rows(jnp.asarray(flat))
-        self._ps_host = ps  # page table, host copy (refresh_rows spans)
-        self.page_start = jax.device_put(ps.astype(np.int32))
-        self.deg = jax.device_put(deg)
-        self.unit_w = unit_w
-        # EULER_TPU_PAGE_DTYPE=bf16 packs the weight plane two-bf16-per-
-        # u32 (half the HBM + DMA bytes) and dequantizes inside the
-        # gather. Emitted batches already ship bf16 edge weights, and
-        # bf16(bf16(x)) == bf16(x), so packed draws stay BIT-IDENTICAL
-        # to the f32 plane — this lane spends no accuracy budget. Odd
-        # page sizes would let a row's page span straddle a packed word
-        # at refresh time, so P=1 stays unpacked.
-        self._page_w_packed = (
-            not unit_w and page_dtype() == "bf16" and P % 2 == 0
-        )
-        if unit_w:
-            self.page_w2d = self.page_q2d = self.page_bound = None
-        else:
-            self.page_w2d = _as_lane_rows(
-                pack_bf16_words(flat_w)
-                if self._page_w_packed
-                else jnp.asarray(flat_w)
-            )
-            self.page_q2d = _as_lane_rows(jnp.asarray(flat_q))
-            # per-page boundary = the page's last valid quantized-CDF
-            # value (pads are U32_MAX, and a node's final page ends at
-            # U32_MAX anyway, so a plain per-page max is exact)
-            self.page_bound = jax.device_put(
-                flat_q.reshape(total_pages, P).max(axis=1)
-            )
-        self.page_size = P
-        # clamp caps for masked draws: a trailing degree-0 node's
-        # page_start equals total_pages, and its (deg>0-masked) gather
-        # index must still stay inside the buffers — XLA clips gathers,
-        # but the kernel DMAs must never be handed an OOB row
-        self._page_cap = total_pages - 1
-        self._slot_cap = total_pages * P - 1
-        self.max_pages = int(npages.max(initial=0))
-        # binary-search depth over a node's page range (static at trace)
-        self._search_iters = max(1, int(self.max_pages).bit_length() + 1)
-        self.max_deg = max(int(deg.max(initial=0)), 1)
-        # dense planes absent on purpose: flows that need them are gated
-        # by _PAGED_OK at staging time
-        self.adj = self.wtab = self.qtab = self.ttab = None
+            if unit_w:
+                self.page_w2d = self.page_q2d = self.page_bound = None
+            else:
+                self.page_w2d = _as_lane_rows(
+                    pack_bf16_words(flat_w)
+                    if self._page_w_packed
+                    else jnp.asarray(flat_w)
+                )
+                self.page_q2d = _as_lane_rows(jnp.asarray(flat_q))
+                # per-page boundary = the page's last valid quantized-CDF
+                # value (pads are U32_MAX, and a node's final page ends at
+                # U32_MAX anyway, so a plain per-page max is exact)
+                self.page_bound = jax.device_put(
+                    flat_q.reshape(total_pages, P).max(axis=1)
+                )
+            self.page_size = P
+            # clamp caps for masked draws: a trailing degree-0 node's
+            # page_start equals total_pages, and its (deg>0-masked) gather
+            # index must still stay inside the buffers — XLA clips gathers,
+            # but the kernel DMAs must never be handed an OOB row
+            self._page_cap = total_pages - 1
+            self._slot_cap = total_pages * P - 1
+            self.max_pages = int(npages.max(initial=0))
+            # binary-search depth over a node's page range (static at trace)
+            self._search_iters = max(1, int(self.max_pages).bit_length() + 1)
+            self.max_deg = max(int(deg.max(initial=0)), 1)
+            # dense planes absent on purpose: flows that need them are gated
+            # by _PAGED_OK at staging time
+            self.adj = self.wtab = self.qtab = self.ttab = None
 
     # -- published-mutation restage --------------------------------------
 

@@ -11,6 +11,7 @@ base_estimator.py:157-179.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import os
@@ -23,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+
+from euler_tpu.utils import trace
 
 
 @dataclasses.dataclass
@@ -43,8 +46,9 @@ class EstimatorConfig:
     keep_checkpoints: int = 3
     seed: int = 0
     # profiling (BaseEstimator(profiling=True) parity, base_estimator.py:
-    # 130-133): when set, a jax.profiler trace of `profile_steps` steps is
-    # written there once, starting at `profile_start_step`
+    # 130-133): when set, a profiler trace of `profile_steps` steps is
+    # written there once, starting at `profile_start_step`; it shows the
+    # `euler.*` scopes and spans (OPERATIONS.md, "Reading a training trace")
     profile_dir: str = ""
     profile_start_step: int = 10
     profile_steps: int = 5
@@ -240,14 +244,15 @@ def _flow_probe(flow):
 def _hydrate_batch(feature_cache, batch: tuple) -> tuple:
     from euler_tpu.dataflow.base import MiniBatch, hydrate_blocks
 
-    batch = tuple(
-        hydrate_blocks(b) if isinstance(b, MiniBatch) else b for b in batch
-    )
-    return (
-        feature_cache.hydrate_args(batch)
-        if feature_cache is not None
-        else batch
-    )
+    with trace.scope("hydrate"):
+        batch = tuple(
+            hydrate_blocks(b) if isinstance(b, MiniBatch) else b for b in batch
+        )
+        return (
+            feature_cache.hydrate_args(batch)
+            if feature_cache is not None
+            else batch
+        )
 
 
 def _apply_update(model, tx, feature_cache, params, opt_state, step_rngs, batch):
@@ -258,9 +263,18 @@ def _apply_update(model, tx, feature_cache, params, opt_state, step_rngs, batch)
         _, loss, _, metric = model.apply(p, *batch, rngs=step_rngs)
         return loss, metric
 
+    # a function of the lowered module (XLA inlines it) as well as a scope:
+    # the persistent compile cache keys on the module WITHOUT its metadata,
+    # so a step that differed from an older one by scope names alone would
+    # be handed the older executable, and its op names, on a hit
+    @jax.jit
+    def optimizer(grads, opt_state, params):
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state
+
     (loss, metric), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-    updates, opt_state = tx.update(grads, opt_state, params)
-    params = optax.apply_updates(params, updates)
+    with trace.scope("optimizer"):
+        params, opt_state = optimizer(grads, opt_state, params)
     return params, opt_state, loss, metric
 
 
@@ -270,7 +284,8 @@ def _step_args(device_flow, xs):
     returning a tuple supplies multiple model args (e.g. the unsupervised
     (src, pos, negs) triple)."""
     if device_flow is not None:
-        out = device_flow.sample(xs[0])
+        with trace.scope("sample"):
+            out = device_flow.sample(xs[0])
         return out if isinstance(out, tuple) else (out,)
     return xs
 
@@ -307,6 +322,89 @@ def _build_train_steps(model, tx, device_flow, feature_cache):
         return params, opt_state, losses, metrics[-1]
 
     return train_step, multi_step
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+# jax.monitoring's durations of one program's way from Python to the
+# device, as child spans of `step.first_call`. `compile` holds the
+# persistent cache's lookup (`cache_fetch`, on a hit) or XLA's compile.
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_fetch",
+}
+
+
+@contextlib.contextmanager
+def _first_call(program: str):
+    """The set-up span `step.first_call` around the first execution of a
+    step program (the caller waits for the result inside it): tracing,
+    lowering, cache fetch or compile — each one child span, from the
+    first start to the last end of its kind, since traces nest — and
+    what remains is the first run."""
+    parts: dict = {}
+
+    def on_duration(event, seconds, **_kw):
+        kind = _COMPILE_EVENTS.get(event)
+        if kind is not None:
+            end = time.perf_counter_ns()
+            lo, hi = parts.get(kind, (end, end))
+            parts[kind] = (min(lo, end - int(seconds * 1e9)), max(hi, end))
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        with trace.span("step.first_call", program=program) as call:
+            yield
+            for kind, (lo, hi) in parts.items():
+                call.child(f"step.first_call.{kind}", lo, hi, program=program)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+
+
+class _ProfileWindow:
+    """`cfg.profile_dir`: one profiler trace per Estimator, of
+    `profile_steps` steps (at least `min_steps`) from `profile_start_step`
+    on. Every dispatch of the traced stretch runs inside a
+    `StepTraceAnnotation("euler.step")`, so the trace has steps; the
+    `euler.*` scopes and spans show in it with no further code."""
+
+    def __init__(self, est: "Estimator", min_steps: int = 1):
+        self._est = est
+        self._min_steps = min_steps
+        self._live = False
+        self._stop_at = 0
+
+    def step(self):
+        """Before a dispatch: starts the trace when it is due; returns
+        the context the dispatch runs in."""
+        est, cfg = self._est, self._est.cfg
+        if (
+            cfg.profile_dir
+            and not est._profiled
+            and est.step >= cfg.profile_start_step
+        ):
+            jax.profiler.start_trace(cfg.profile_dir)
+            est._profiled = self._live = True
+            self._stop_at = est.step + max(cfg.profile_steps, self._min_steps)
+        if not self._live:
+            return _NO_SPAN
+        return jax.profiler.StepTraceAnnotation("euler.step", step_num=est.step)
+
+    def stop_if_done(self, result) -> None:
+        if self._live and self._est.step >= self._stop_at:
+            self.stop(result)
+
+    def stop(self, result) -> None:
+        """Waits for `result`, so the trace holds the device's part of the
+        last step, and writes the trace out."""
+        if self._live:
+            self._live = False
+            try:
+                jax.block_until_ready(result)
+            finally:
+                jax.profiler.stop_trace()
 
 
 class Estimator:
@@ -372,6 +470,8 @@ class Estimator:
         self._jit_train_scan = None
         self._jit_eval = None
         self._jit_embed = None
+        self._called: set = set()  # step programs this Estimator has run
+        self._profiled = False  # cfg.profile_dir's one trace was taken
 
     # -- state -----------------------------------------------------------
 
@@ -546,11 +646,42 @@ class Estimator:
     def train(
         self, total_steps: int | None = None, log: bool = True, save: bool = True
     ):
-        self._ensure_init()
         steps = total_steps if total_steps is not None else self.cfg.total_steps
-        k = max(int(self.cfg.steps_per_call), 1)
-        if k > 1:
-            return self._train_scan(steps, k, log=log, save=save)
+        with trace.span("train", steps=steps):
+            self._ensure_init()
+            k = max(int(self.cfg.steps_per_call), 1)
+            if k > 1:
+                return self._train_scan(steps, k, log=log, save=save)
+            return self._train_steps(steps, log=log, save=save)
+
+    def _dispatch(self, step_fn, rngs, batch):
+        """One call of a step program on the Estimator's state; returns
+        its (loss or losses, metric). The first call of each program is
+        waited for and recorded as a set-up span (`_first_call`)."""
+        with trace.span("train.dispatch", step=self.step):
+            name = step_fn.__name__
+            first = name not in self._called
+            with _first_call(name) if first else _NO_SPAN:
+                self.params, self.opt_state, loss, metric = step_fn(
+                    self.params, self.opt_state, rngs, *batch
+                )
+                if first:
+                    self._called.add(name)
+                    jax.block_until_ready(loss)
+        return loss, metric
+
+    def _drain(self, history: list, fetched: list, concat: bool) -> None:
+        """Fetches the on-device losses of `history` into `fetched`."""
+        with trace.span("train.drain", step=self.step):
+            joined = jnp.concatenate(history) if concat else jnp.stack(history)
+            fetched.extend(np.asarray(joined).tolist())
+        history.clear()
+
+    def _checkpoint(self) -> None:
+        with trace.span("train.save", step=self.step):
+            self.save()
+
+    def _train_steps(self, steps: int, log: bool, save: bool):
         step_fn = self._train_step()
         t0 = time.time()
         history = []  # on-device losses not yet drained to the host
@@ -558,27 +689,16 @@ class Estimator:
         # drain in chunks: keeping one live device scalar per step for a
         # long run pins an unbounded number of small device buffers
         drain_every = 4096
-        profiling = False
+        profile = _ProfileWindow(self)
         try:
             for _ in range(steps):
-                if (
-                    self.cfg.profile_dir
-                    and not getattr(self, "_profiled", False)
-                    and self.step >= self.cfg.profile_start_step
-                ):
-                    jax.profiler.start_trace(self.cfg.profile_dir)
-                    profiling = True
-                    profile_stop = self.step + self.cfg.profile_steps
-                    self._profiled = True
-                batch = self._next_batch(1)
-                self.params, self.opt_state, loss, metric = step_fn(
-                    self.params, self.opt_state, self._rngs(self.step), *batch
-                )
+                with trace.span("train.next_batch", step=self.step):
+                    batch = self._next_batch(1)
+                    rngs = self._rngs(self.step)
+                with profile.step():
+                    loss, metric = self._dispatch(step_fn, rngs, batch)
                 self.step += 1
-                if profiling and self.step >= profile_stop:
-                    jax.block_until_ready(loss)
-                    jax.profiler.stop_trace()
-                    profiling = False
+                profile.stop_if_done(loss)
                 if log and self.step % self.cfg.log_steps == 0:
                     loss_v = float(loss)
                     dt = time.time() - t0
@@ -591,23 +711,20 @@ class Estimator:
                 # serialize the pipeline
                 history.append(loss)
                 if len(history) >= drain_every:
-                    fetched.extend(np.asarray(jnp.stack(history)).tolist())
-                    history = []
+                    self._drain(history, fetched, concat=False)
                 if (
                     self.cfg.checkpoint_steps
                     and self.step % self.cfg.checkpoint_steps == 0
                 ):
-                    self.save()
+                    self._checkpoint()
         finally:
             # a raising loop (dead shard, OOM, poisoned batch) must still
             # surface the losses fetched so far and leave a best-effort
             # checkpoint — previously both were silently dropped
-            history, fetched = self._finish_train(
-                history, fetched, profiling, save
-            )
+            self._finish_train(history, fetched, profile, save)
         return fetched
 
-    def _finish_train(self, history, fetched, profiling, save, concat=False):
+    def _finish_train(self, history, fetched, profile, save, concat=False):
         """Shared train-loop epilogue, run from a `finally`: stop a live
         profiler trace, drain the on-device loss history, publish the
         losses fetched so far on `self.last_losses`, and save. When an
@@ -617,19 +734,13 @@ class Estimator:
         import sys as _sys
 
         exc_live = _sys.exc_info()[0] is not None
-        if profiling:
-            try:
-                jax.block_until_ready(self.params)
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+        try:
+            profile.stop(self.params)
+        except Exception:
+            pass
         if history:
             try:
-                joined = jnp.concatenate(history) if concat else jnp.stack(
-                    history
-                )
-                fetched.extend(np.asarray(joined).tolist())
-                history = []
+                self._drain(history, fetched, concat)
             except Exception:
                 if not exc_live:
                     raise
@@ -637,7 +748,7 @@ class Estimator:
         if save and self.params is not None:
             if exc_live:
                 try:
-                    self.save()
+                    self._checkpoint()
                 except Exception as e:
                     print(
                         f"# estimator: best-effort checkpoint after a "
@@ -645,8 +756,7 @@ class Estimator:
                         file=_sys.stderr,
                     )
             else:
-                self.save()
-        return history, fetched
+                self._checkpoint()
 
     def _train_scan(self, steps: int, k: int, log: bool, save: bool):
         """Driver for steps_per_call>1: each batch_fn() item is a K-stacked
@@ -659,28 +769,16 @@ class Estimator:
         fetched: list[float] = []
         drain_every = max(4096 // k, 1)
         calls, remainder = divmod(steps, k)
-        profiling = False
+        profile = _ProfileWindow(self, min_steps=k)
         try:
             for _ in range(calls):
-                if (
-                    self.cfg.profile_dir
-                    and not getattr(self, "_profiled", False)
-                    and self.step >= self.cfg.profile_start_step
-                ):
-                    jax.profiler.start_trace(self.cfg.profile_dir)
-                    profiling = True
-                    profile_stop = self.step + max(self.cfg.profile_steps, k)
-                    self._profiled = True
-                batch = self._next_batch(k)
-                rngs = self._rngs_stacked(self.step, k)
-                self.params, self.opt_state, losses, metric = step_fn(
-                    self.params, self.opt_state, rngs, *batch
-                )
+                with trace.span("train.next_batch", step=self.step):
+                    batch = self._next_batch(k)
+                    rngs = self._rngs_stacked(self.step, k)
+                with profile.step():
+                    losses, metric = self._dispatch(step_fn, rngs, batch)
                 self.step += k
-                if profiling and self.step >= profile_stop:
-                    jax.block_until_ready(losses)
-                    jax.profiler.stop_trace()
-                    profiling = False
+                profile.stop_if_done(losses)
                 if log and self.step % max(self.cfg.log_steps, 1) < k:
                     dt = time.time() - t0
                     print(
@@ -690,40 +788,32 @@ class Estimator:
                     )
                 history.append(losses)
                 if len(history) >= drain_every:
-                    fetched.extend(
-                        np.asarray(jnp.concatenate(history)).tolist()
-                    )
-                    history = []
+                    self._drain(history, fetched, concat=True)
                 if (
                     self.cfg.checkpoint_steps
                     and self.step % self.cfg.checkpoint_steps < k
                 ):
-                    self.save()
-            if profiling:
-                jax.block_until_ready(self.params)
-                jax.profiler.stop_trace()
-                profiling = False
+                    self._checkpoint()
+            profile.stop(self.params)
             if remainder:
                 single = self._train_step()
-                item = (
-                    (self._flow_keys(self.step, remainder),)
-                    if self._device_flow is not None
-                    else self._put(self.batch_fn(), stacked=True)
-                )
+                with trace.span("train.next_batch", step=self.step):
+                    item = (
+                        (self._flow_keys(self.step, remainder),)
+                        if self._device_flow is not None
+                        else self._put(self.batch_fn(), stacked=True)
+                    )
                 for i in range(remainder):
                     batch = jax.tree_util.tree_map(lambda x: x[i], item)
-                    self.params, self.opt_state, loss, _ = single(
-                        self.params, self.opt_state, self._rngs(self.step),
-                        *batch,
+                    loss, _ = self._dispatch(
+                        single, self._rngs(self.step), batch
                     )
                     self.step += 1
                     history.append(loss[None])
         finally:
             # same contract as train(): a raising loop still drains the
             # fetched losses and leaves a best-effort checkpoint
-            history, fetched = self._finish_train(
-                history, fetched, profiling, save, concat=True
-            )
+            self._finish_train(history, fetched, profile, save, concat=True)
         return fetched[:steps]
 
     def _shared_apply_jit(self, kind: str, build):

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from euler_tpu.dataflow.base import MiniBatch
+from euler_tpu.utils import trace
 
 
 def _is_rows(x) -> bool:
@@ -51,28 +52,29 @@ class DeviceFeatureCache:
         from euler_tpu.distributed.codec import page_dtype, quantize
 
         self.feature_names = list(feature_names)
-        host = graph.dense_feature_table(self.feature_names)
-        self.dim = host.shape[1]
-        table = np.concatenate(
-            [np.zeros((1, self.dim), np.float32), host], axis=0
-        )
         self.quant = (
             (quant if quant is not None else page_dtype())
             if np.dtype(dtype) == np.float32
             else "f32"
         )
-        if self.quant == "int8":
-            q, scale, zero = quantize("int8", table)
-            # padding row 0 dequantizes to exact zeros: q=0, zero=0
-            zero[0] = 0.0
-            self._scale = jax.device_put(scale)
-            self._zero = jax.device_put(zero)
-            table = q
-        elif self.quant == "bf16":
-            table = table.astype(jnp.bfloat16)
-        else:
-            table = table.astype(np.dtype(dtype))
-        self.table = jax.device_put(table, sharding)
+        with trace.span("stage.features"):
+            host = graph.dense_feature_table(self.feature_names)
+            self.dim = host.shape[1]
+            table = np.concatenate(
+                [np.zeros((1, self.dim), np.float32), host], axis=0
+            )
+            if self.quant == "int8":
+                q, scale, zero = quantize("int8", table)
+                # padding row 0 dequantizes to exact zeros: q=0, zero=0
+                zero[0] = 0.0
+                self._scale = jax.device_put(scale)
+                self._zero = jax.device_put(zero)
+                table = q
+            elif self.quant == "bf16":
+                table = table.astype(jnp.bfloat16)
+            else:
+                table = table.astype(np.dtype(dtype))
+            self.table = jax.device_put(table, sharding)
 
     def gather(self, rows) -> jnp.ndarray:
         """int32 rows (0 = padding) → dense [n, F]; jit-safe. Quantized

@@ -17,6 +17,7 @@ import optax
 from euler_tpu.dataflow.walk import gen_pair
 from euler_tpu.nn.encoders import Embedding
 from euler_tpu.nn.metrics import mrr
+from euler_tpu.utils import trace
 
 
 class SkipGramModel(nn.Module):
@@ -46,13 +47,16 @@ class SkipGramModel(nn.Module):
         e_src = self.target(src)  # [B, D]
         e_pos = self._ctx(pos)  # [B, D]
         e_neg = self._ctx(negs)  # [B, N, D]
-        pos_logit = jnp.sum(e_src * e_pos, axis=-1)
-        neg_logit = jnp.einsum("bd,bnd->bn", e_src, e_neg)
-        logits = jnp.concatenate([pos_logit[:, None], neg_logit], axis=1)
-        labels = jnp.zeros(src.shape[0], dtype=jnp.int32)
-        per = optax.softmax_cross_entropy_with_integer_labels(logits, labels)
-        loss = jnp.sum(per * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-        return e_src, loss, "mrr", mrr(pos_logit, neg_logit)
+        with trace.scope("loss"):
+            pos_logit = jnp.sum(e_src * e_pos, axis=-1)
+            neg_logit = jnp.einsum("bd,bnd->bn", e_src, e_neg)
+            logits = jnp.concatenate([pos_logit[:, None], neg_logit], axis=1)
+            labels = jnp.zeros(src.shape[0], dtype=jnp.int32)
+            per = optax.softmax_cross_entropy_with_integer_labels(
+                logits, labels
+            )
+            loss = jnp.sum(per * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+            return e_src, loss, "mrr", mrr(pos_logit, neg_logit)
 
 
 def deepwalk_batches(

@@ -19,6 +19,7 @@ from euler_tpu.dataflow.base import MiniBatch
 from euler_tpu.nn.base_gnn import GNNNet
 from euler_tpu.nn.encoders import ShallowEncoder
 from euler_tpu.nn.metrics import micro_f1, mrr
+from euler_tpu.utils import trace
 
 
 class _EncodedGNN(nn.Module):
@@ -79,10 +80,11 @@ class GraphSAGESupervised(nn.Module):
 
     def __call__(self, batch: MiniBatch):
         emb = self.embed(batch)
-        logits = self.out(emb)
-        loss = optax.sigmoid_binary_cross_entropy(logits, batch.labels)
-        loss = jnp.mean(jnp.sum(loss, axis=-1))
-        return emb, loss, "f1", micro_f1(batch.labels, logits)
+        with trace.scope("loss"):
+            logits = self.out(emb)
+            loss = optax.sigmoid_binary_cross_entropy(logits, batch.labels)
+            loss = jnp.mean(jnp.sum(loss, axis=-1))
+            return emb, loss, "f1", micro_f1(batch.labels, logits)
 
 
 class GraphSAGEUnsupervised(nn.Module):
@@ -110,13 +112,14 @@ class GraphSAGEUnsupervised(nn.Module):
         e_src = self.embed(src)
         e_pos = self.embed(pos)
         e_neg = self.embed(negs)
-        b, d = e_src.shape
-        e_neg = e_neg.reshape(b, -1, d)
-        pos_logit = jnp.sum(e_src * e_pos, axis=-1)
-        neg_logit = jnp.einsum("bd,bnd->bn", e_src, e_neg)
-        logits = jnp.concatenate([pos_logit[:, None], neg_logit], axis=1)
-        labels = jnp.zeros(b, dtype=jnp.int32)
-        loss = jnp.mean(
-            optax.softmax_cross_entropy_with_integer_labels(logits, labels)
-        )
-        return e_src, loss, "mrr", mrr(pos_logit, neg_logit)
+        with trace.scope("loss"):
+            b, d = e_src.shape
+            e_neg = e_neg.reshape(b, -1, d)
+            pos_logit = jnp.sum(e_src * e_pos, axis=-1)
+            neg_logit = jnp.einsum("bd,bnd->bn", e_src, e_neg)
+            logits = jnp.concatenate([pos_logit[:, None], neg_logit], axis=1)
+            labels = jnp.zeros(b, dtype=jnp.int32)
+            loss = jnp.mean(
+                optax.softmax_cross_entropy_with_integer_labels(logits, labels)
+            )
+            return e_src, loss, "mrr", mrr(pos_logit, neg_logit)

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from euler_tpu.dataflow.base import MiniBatch
 from euler_tpu.layers import get_conv
+from euler_tpu.utils import trace
 
 
 class GNNNet(nn.Module):
@@ -51,18 +52,19 @@ class GNNNet(nn.Module):
         )
         act = getattr(nn, self.activation)
         xs = list(batch.feats)
-        for layer in range(num_hops):
-            conv = self.convs[layer]
-            last = layer == num_hops - 1
-            new_xs = []
-            for hop in range(num_hops - layer):
-                h = conv(xs[hop], xs[hop + 1], batch.blocks[hop])
-                if not last:
-                    h = act(h)
-                # zero out padded node slots so garbage never propagates
-                h = h * batch.masks[hop][: h.shape[0], None]
-                new_xs.append(h)
-            xs = new_xs
+        with trace.scope("conv"):
+            for layer in range(num_hops):
+                conv = self.convs[layer]
+                last = layer == num_hops - 1
+                new_xs = []
+                for hop in range(num_hops - layer):
+                    h = conv(xs[hop], xs[hop + 1], batch.blocks[hop])
+                    if not last:
+                        h = act(h)
+                    # zero out padded node slots so garbage never propagates
+                    h = h * batch.masks[hop][: h.shape[0], None]
+                    new_xs.append(h)
+                xs = new_xs
         return xs[0]
 
 
@@ -85,14 +87,15 @@ class JKGNNNet(nn.Module):
         act = getattr(nn, self.activation)
         xs = list(batch.feats)
         collected = []
-        for layer in range(num_hops):
-            conv = self.convs[layer]
-            new_xs = []
-            for hop in range(num_hops - layer):
-                h = conv(xs[hop], xs[hop + 1], batch.blocks[hop])
-                h = act(h)
-                h = h * batch.masks[hop][: h.shape[0], None]
-                new_xs.append(h)
-            xs = new_xs
-            collected.append(xs[0])
-        return self.proj(jnp.concatenate(collected, axis=-1))
+        with trace.scope("conv"):
+            for layer in range(num_hops):
+                conv = self.convs[layer]
+                new_xs = []
+                for hop in range(num_hops - layer):
+                    h = conv(xs[hop], xs[hop + 1], batch.blocks[hop])
+                    h = act(h)
+                    h = h * batch.masks[hop][: h.shape[0], None]
+                    new_xs.append(h)
+                xs = new_xs
+                collected.append(xs[0])
+            return self.proj(jnp.concatenate(collected, axis=-1))

@@ -17,6 +17,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from euler_tpu.ops import gather
+from euler_tpu.utils import trace
 
 
 class Embedding(nn.Module):
@@ -41,7 +42,8 @@ class Embedding(nn.Module):
         # size and aligned to the TPU lane tile
         rows = -(-self.vocab // 128) * 128
         table = self.param("table", init, (rows, self.dim), jnp.float32)
-        return gather(jnp.asarray(table), jnp.clip(ids, 0, self.vocab - 1))
+        with trace.scope("embed"):
+            return gather(jnp.asarray(table), jnp.clip(ids, 0, self.vocab - 1))
 
 
 class SparseEmbedding(nn.Module):

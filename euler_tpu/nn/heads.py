@@ -18,6 +18,7 @@ import optax
 from euler_tpu.dataflow.base import MiniBatch
 from euler_tpu.nn.base_gnn import GNNNet
 from euler_tpu.nn.metrics import micro_f1, mrr
+from euler_tpu.utils import trace
 
 
 class SuperviseModel(nn.Module):
@@ -42,11 +43,12 @@ class SuperviseModel(nn.Module):
         if batch.target_idx is not None:
             # whole-graph flows: only the target rows carry loss/metric
             emb = emb[batch.target_idx]
-        logits = self.out(emb)
-        labels = batch.labels
-        loss = optax.sigmoid_binary_cross_entropy(logits, labels)
-        loss = jnp.mean(jnp.sum(loss, axis=-1))
-        return emb, loss, "f1", micro_f1(labels, logits)
+        with trace.scope("loss"):
+            logits = self.out(emb)
+            labels = batch.labels
+            loss = optax.sigmoid_binary_cross_entropy(logits, labels)
+            loss = jnp.mean(jnp.sum(loss, axis=-1))
+            return emb, loss, "f1", micro_f1(labels, logits)
 
 
 class UnsuperviseModel(nn.Module):
@@ -72,15 +74,16 @@ class UnsuperviseModel(nn.Module):
         e_src = self.embed(src)  # [B, D]
         e_pos = self.embed(pos)  # [B, D]
         e_neg = self.embed(negs)  # [B*N, D]
-        b, d = e_src.shape
-        e_neg = e_neg.reshape(b, -1, d)
-        pos_logit = jnp.sum(e_src * e_pos, axis=-1) / self.temperature  # [B]
-        neg_logit = (
-            jnp.einsum("bd,bnd->bn", e_src, e_neg) / self.temperature
-        )  # [B, N]
-        logits = jnp.concatenate([pos_logit[:, None], neg_logit], axis=1)
-        labels = jnp.zeros(b, dtype=jnp.int32)  # positive is column 0
-        loss = jnp.mean(
-            optax.softmax_cross_entropy_with_integer_labels(logits, labels)
-        )
-        return e_src, loss, "mrr", mrr(pos_logit, neg_logit)
+        with trace.scope("loss"):
+            b, d = e_src.shape
+            e_neg = e_neg.reshape(b, -1, d)
+            pos_logit = jnp.sum(e_src * e_pos, axis=-1) / self.temperature
+            neg_logit = (
+                jnp.einsum("bd,bnd->bn", e_src, e_neg) / self.temperature
+            )  # [B], [B, N]
+            logits = jnp.concatenate([pos_logit[:, None], neg_logit], axis=1)
+            labels = jnp.zeros(b, dtype=jnp.int32)  # positive is column 0
+            loss = jnp.mean(
+                optax.softmax_cross_entropy_with_integer_labels(logits, labels)
+            )
+            return e_src, loss, "mrr", mrr(pos_logit, neg_logit)
