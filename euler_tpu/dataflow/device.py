@@ -72,6 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from euler_tpu.utils import trace
+from euler_tpu.utils.staged import StagedTables
 
 from .base import Block, MiniBatch
 
@@ -194,7 +195,7 @@ def frontier_contrib(weights, global_vec, src_rows):
         return np.asarray(out, np.float64)
 
 
-class DeviceGraphTables:
+class DeviceGraphTables(StagedTables):
     """HBM-resident graph tables + traced draw primitives.
 
     Stages (once, host-side) the padded adjacency, degree vector, raw
@@ -202,7 +203,10 @@ class DeviceGraphTables:
     on the gathered rows at draw time), a quantized node-weight CDF
     (non-uniform node weights only), and the id↔row maps. Subclasses
     compose `_draw_roots` / `_draw_neighbors` into batch shapes; all
-    draws are jit-traceable.
+    draws are jit-traceable. The Estimator's programs take the staged
+    arrays as an argument (`StagedTables`: `tables()` / `bind()`), so a
+    `refresh_rows` is read by the next dispatch; `jax.jit(flow.sample)`
+    on the flow itself compiles them in as constants instead.
     """
 
     is_device_flow = True

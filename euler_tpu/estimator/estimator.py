@@ -228,16 +228,47 @@ def _jit_cache_put(cache: dict, key, value):
     cache[key] = value
 
 
+def _live_tables(mesh, device_flow, feature_cache) -> dict:
+    """The table argument of a program: the owners' staged arrays as they
+    are NOW, so a `refresh_rows` / `commit()` since the last dispatch is
+    what the next one reads. Under a mesh the tables are replicated in
+    place the first time they pass here and found so afterwards."""
+    tables = {}
+    for name, owner in (("flow", device_flow), ("features", feature_cache)):
+        if owner is not None:
+            if mesh is not None:
+                owner.replicate(mesh)
+            tables[name] = owner.tables()
+    return tables
+
+
+def _bound(owner, tables: dict, name: str):
+    """The owner as the traced code reads it: a view whose arrays are
+    its part of the program's `tables` argument."""
+    return None if owner is None else owner.bind(tables[name])
+
+
+def _bind_tables(device_flow, feature_cache, tables: dict) -> tuple:
+    return (
+        _bound(device_flow, tables, "flow"),
+        _bound(feature_cache, tables, "features"),
+    )
+
+
 def _flow_probe(flow):
-    """Jitted flow.sample for the init-shape probe, memoized on the flow
-    (a fresh jax.jit wrapper would re-trace for every Estimator sharing
-    the flow)."""
+    """Jitted `(tables, key) -> flow.sample(key)` for the init-shape
+    probe, memoized on the flow (a fresh jax.jit wrapper would re-trace
+    for every Estimator sharing the flow)."""
+
+    def sample(tables, key):
+        return flow.bind(tables).sample(key)
+
     cache = _jit_cache(flow)
     if cache is None:
-        return jax.jit(flow.sample)
+        return jax.jit(sample)
     with _JIT_CACHE_LOCK:
         if "probe" not in cache:
-            _jit_cache_put(cache, "probe", jax.jit(flow.sample))
+            _jit_cache_put(cache, "probe", jax.jit(sample))
         return cache["probe"]
 
 
@@ -291,28 +322,37 @@ def _step_args(device_flow, xs):
 
 
 def _build_train_steps(model, tx, device_flow, feature_cache):
-    """The two jitted update programs, closing over ONLY the objects the
-    trace reads — shareable across Estimator instances via _jit_cache
-    without pinning any instance's params."""
+    """The two jitted update programs. They close over the model, the
+    optimizer and the flow / feature-cache OBJECTS, for what those fix at
+    trace time (fanouts, batch size, layout, quantization) — never over
+    an instance's params, so they are shareable across Estimators via
+    _jit_cache. The objects' device tables are not read from the closure:
+    they are the `tables` argument (`_live_tables`, not donated), bound
+    to views of the owners inside the trace."""
 
     # donate params+opt_state: without it the update keeps both old and
     # new buffers alive across the step — 2x the HBM for model state
     # (the big cost for sharded embedding tables)
     @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def train_step(params, opt_state, rngs, *batch):
+    def train_step(params, opt_state, tables, rngs, *batch):
+        flow, cache = _bind_tables(device_flow, feature_cache, tables)
         return _apply_update(
-            model, tx, feature_cache,
-            params, opt_state, rngs, _step_args(device_flow, batch),
+            model, tx, cache,
+            params, opt_state, rngs, _step_args(flow, batch),
         )
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def multi_step(params, opt_state, rngs, *stacked_batch):
+    def multi_step(params, opt_state, tables, rngs, *stacked_batch):
+        # bound outside the body: the tables are loop invariants of the
+        # scan, not scanned inputs
+        flow, cache = _bind_tables(device_flow, feature_cache, tables)
+
         def body(carry, xs):
             params, opt_state = carry
             step_rngs, batch = xs
             params, opt_state, loss, metric = _apply_update(
-                model, tx, feature_cache,
-                params, opt_state, step_rngs, _step_args(device_flow, batch),
+                model, tx, cache,
+                params, opt_state, step_rngs, _step_args(flow, batch),
             )
             return (params, opt_state), (loss, metric)
 
@@ -338,12 +378,16 @@ _COMPILE_EVENTS = {
 
 
 @contextlib.contextmanager
-def _first_call(program: str):
+def _first_call(program: str, tables: dict):
     """The set-up span `step.first_call` around the first execution of a
     step program (the caller waits for the result inside it): tracing,
     lowering, cache fetch or compile — each one child span, from the
     first start to the last end of its kind, since traces nest — and
-    what remains is the first run."""
+    what remains is the first run. `table_arg_bytes` is what went in as
+    the `tables` argument rather than as constants of the executable."""
+    table_arg_bytes = sum(
+        leaf.nbytes for leaf in jax.tree_util.tree_leaves(tables)
+    )
     parts: dict = {}
 
     def on_duration(event, seconds, **_kw):
@@ -355,7 +399,9 @@ def _first_call(program: str):
 
     jax.monitoring.register_event_duration_secs_listener(on_duration)
     try:
-        with trace.span("step.first_call", program=program) as call:
+        with trace.span(
+            "step.first_call", program=program, table_arg_bytes=table_arg_bytes
+        ) as call:
             yield
             for kind, (lo, hi) in parts.items():
                 call.child(f"step.first_call.{kind}", lo, hi, program=program)
@@ -487,6 +533,13 @@ class Estimator:
     def _hydrate(self, batch: tuple) -> tuple:
         return _hydrate_batch(self.feature_cache, batch)
 
+    def _tables(self, flow: bool = True) -> dict:
+        """This dispatch's `tables` argument; `flow=False` for the eval
+        and embed programs, which read the feature cache only."""
+        return _live_tables(
+            self.mesh, self._device_flow if flow else None, self.feature_cache
+        )
+
     def _ensure_init(self):
         if self.params is not None:
             if self.opt_state is None:
@@ -506,7 +559,9 @@ class Estimator:
             self.opt_state = self.tx.init(self.params)
             return
         if self._device_flow is not None:
-            out = _flow_probe(self._device_flow)(self._flow_keys(0, 1)[0])
+            out = _flow_probe(self._device_flow)(
+                self._tables()["flow"], self._flow_keys(0, 1)[0]
+            )
             batch = out if isinstance(out, tuple) else (out,)
         else:
             batch = self._put(
@@ -562,9 +617,10 @@ class Estimator:
         cache when possible (see _jit_cache above)."""
         if self._jit_train is not None:
             return
-        # root on the flow when there is one (the closure pins both flow
-        # and cache; the flow outliving the cache is the unusual case),
-        # else on the feature cache
+        # root on the flow when there is one, else on the feature cache:
+        # the programs hold both objects (for their static configuration;
+        # the tables come in as arguments), so an entry lives as long as
+        # its root, and the flow outliving the cache is the unusual case
         root = (
             self._device_flow
             if self._device_flow is not None
@@ -661,9 +717,10 @@ class Estimator:
         with trace.span("train.dispatch", step=self.step):
             name = step_fn.__name__
             first = name not in self._called
-            with _first_call(name) if first else _NO_SPAN:
+            tables = self._tables()
+            with _first_call(name, tables) if first else _NO_SPAN:
                 self.params, self.opt_state, loss, metric = step_fn(
-                    self.params, self.opt_state, rngs, *batch
+                    self.params, self.opt_state, tables, rngs, *batch
                 )
                 if first:
                     self._called.add(name)
@@ -819,7 +876,7 @@ class Estimator:
     def _shared_apply_jit(self, kind: str, build):
         """Get-or-build an eval/embed program, rooted on the feature
         cache (the only instance object those programs read besides the
-        model)."""
+        model; its table is their `tables` argument)."""
         cache = _jit_cache(self.feature_cache)
         if cache is None:
             return build()
@@ -836,8 +893,10 @@ class Estimator:
             self._jit_eval = self._shared_apply_jit(
                 "eval",
                 lambda: jax.jit(
-                    lambda p, rngs, *b: model.apply(
-                        p, *_hydrate_batch(fc, b), rngs=rngs
+                    lambda p, tables, rngs, *b: model.apply(
+                        p,
+                        *_hydrate_batch(_bound(fc, tables, "features"), b),
+                        rngs=rngs,
                     )[1:4:2]
                 ),
             )  # (loss, metric)
@@ -845,7 +904,9 @@ class Estimator:
         losses, metrics = [], []
         for batch in batches:
             batch = self._put(batch)
-            loss, metric = self._jit_eval(self.params, self._rngs(0), *batch)
+            loss, metric = self._jit_eval(
+                self.params, self._tables(flow=False), self._rngs(0), *batch
+            )
             if name is None:
                 # the metric NAME is a static python string the jitted
                 # program can't return; one eager forward fetches it, once
@@ -861,20 +922,33 @@ class Estimator:
         }
 
     def embed_program(self):
-        """The jitted `(params, batch) -> embeddings` program `infer` runs —
+        """The `(params, batch) -> embeddings` program `infer` runs —
         shared across instances via the feature-cache-rooted jit cache, and
         the program the serving runtime executes so served predictions are
-        bit-identical to offline `infer` on the same checkpoint."""
+        bit-identical to offline `infer` on the same checkpoint. Each call
+        hands the jitted program (`.jitted`) the feature cache's table as
+        it is then."""
         if self._jit_embed is None:
             model, fc = self.model, self.feature_cache
-            self._jit_embed = self._shared_apply_jit(
-                "embed",
-                lambda: jax.jit(
-                    lambda p, b: model.apply(
-                        p, *_hydrate_batch(fc, (b,)), method=model.embed
+
+            def build():
+                @jax.jit
+                def embed(p, tables, b):
+                    cache = _bound(fc, tables, "features")
+                    return model.apply(
+                        p, *_hydrate_batch(cache, (b,)), method=model.embed
                     )
-                ),
-            )
+
+                def program(params, batch):
+                    return embed(params, _live_tables(None, None, fc), batch)
+
+                program.jitted = embed
+                return program
+
+            if self.mesh is not None and fc is not None:
+                # the shared program reads the table wherever it lies
+                fc.replicate(self.mesh)
+            self._jit_embed = self._shared_apply_jit("embed", build)
         return self._jit_embed
 
     def infer(

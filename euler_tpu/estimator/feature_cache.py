@@ -22,6 +22,7 @@ import numpy as np
 
 from euler_tpu.dataflow.base import MiniBatch
 from euler_tpu.utils import trace
+from euler_tpu.utils.staged import StagedTables
 
 
 def _is_rows(x) -> bool:
@@ -30,8 +31,10 @@ def _is_rows(x) -> bool:
     )
 
 
-class DeviceFeatureCache:
-    """Device copy of a graph's dense feature table, +1 zero padding row."""
+class DeviceFeatureCache(StagedTables):
+    """Device copy of a graph's dense feature table, +1 zero padding row.
+    The Estimator's programs take the table (and an int8 table's scale
+    and zero point) as an argument, `tables()`, read at every dispatch."""
 
     def __init__(
         self,

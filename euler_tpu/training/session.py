@@ -476,16 +476,19 @@ class TrainingSession:
 
                 from euler_tpu.estimator.estimator import (
                     _apply_update,
+                    _bind_tables,
                     _step_args,
                 )
 
                 est = self.est
 
-                def step(params, opt_state, rngs, *batch):
+                def step(params, opt_state, tables, rngs, *batch):
+                    flow, cache = _bind_tables(
+                        est._device_flow, est.feature_cache, tables
+                    )
                     return _apply_update(
-                        est.model, est.tx, est.feature_cache,
-                        params, opt_state, rngs,
-                        _step_args(est._device_flow, batch),
+                        est.model, est.tx, cache,
+                        params, opt_state, rngs, _step_args(flow, batch),
                     )
 
                 self._step = jax.jit(step)
@@ -644,7 +647,8 @@ class TrainingSession:
                 def one_step():
                     batch = est._next_batch(1)
                     p, o, loss, metric = step_fn(
-                        est.params, est.opt_state, est._rngs(est.step), *batch
+                        est.params, est.opt_state, est._tables(),
+                        est._rngs(est.step), *batch,
                     )
                     ok = True
                     if guard is not None and (

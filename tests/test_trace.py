@@ -55,11 +55,9 @@ def op_names(graph, tmp_path_factory):
     for kind in SCOPES:
         est, _ = _estimator(kind, graph, tmp_path_factory.mktemp(kind))
         est._ensure_init()
-        single = (est.params, est.opt_state, est._rngs(0), *est._next_batch(1))
-        stacked = (
-            est.params, est.opt_state, est._rngs_stacked(0, 2),
-            *est._next_batch(2),
-        )
+        state = (est.params, est.opt_state, est._tables())
+        single = (*state, est._rngs(0), *est._next_batch(1))
+        stacked = (*state, est._rngs_stacked(0, 2), *est._next_batch(2))
         for program, fn, args in (
             ("train_step", est._train_step(), single),
             ("multi_step", est._train_step_scan(), stacked),
