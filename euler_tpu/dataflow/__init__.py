@@ -8,6 +8,7 @@ from euler_tpu.dataflow.device import (  # noqa: F401
     DeviceLayerwiseFlow,
     DeviceRelationFlow,
     DeviceSageFlow,
+    DeviceSequenceFlow,
     DeviceUnsupSageFlow,
     DeviceWalkFlow,
     DeviceWholeGraphFlow,

@@ -923,6 +923,35 @@ class DeviceGraphTables(StagedTables):
             )
         return nbr, ew, idx
 
+    def _walk_rows(self, cur, key, length: int, step=None, scan: bool = False):
+        """[W] start rows -> [W, length + 1] rows (0 = dead): a chain of
+        single-neighbour draws, transition i under `split(key, length)[i]`;
+        `step(cur, prev, key)` in place of the uniform draw where a flow
+        biases its walk.
+        `scan=False` traces the L draws one after another, which suits a
+        few tens of them; `scan=True` is one `lax.scan` over the keys,
+        for walks of hundreds (the draws are the same ones)."""
+
+        def move(carry, key):
+            cur, prev = carry
+            if step is not None:
+                nxt = step(cur, prev, key)
+            else:
+                nxt, _, _ = self._draw_neighbors(cur, key, 1)
+            nxt = self._dp(nxt)
+            return (nxt, cur), nxt
+
+        carry = (cur, jnp.zeros_like(cur))
+        keys = jax.random.split(key, length)
+        if scan:
+            _, rest = jax.lax.scan(move, carry, keys)
+            return jnp.concatenate([cur[:, None], rest.T], axis=1)
+        walk = [cur]
+        for key in keys:
+            carry, nxt = move(carry, key)
+            walk.append(nxt)
+        return jnp.stack(walk, axis=1)
+
     def _draw_neighbors_paged(self, cur, key, k: int):
         """Paged twin of _draw_neighbors: two-level quantized-CDF
         inversion (page-boundary binary search + in-page count) and
@@ -1231,16 +1260,10 @@ class DeviceWalkFlow(DeviceGraphTables):
         """key → SkipGramModel batch dict, jit-traceable."""
         kroot, kneg, kwalk = jax.random.split(key, 3)
         cur = self._dp(self._draw_roots(kroot, self.batch_size))
-        walk = [cur]
-        prev = jnp.zeros_like(cur)
-        for sk in jax.random.split(kwalk, self.walk_len):
-            if self.biased:
-                nxt = self._walk_step(cur, prev, sk)
-            else:
-                nxt, _, _ = self._draw_neighbors(cur, sk, 1)
-            prev, cur = cur, self._dp(nxt)
-            walk.append(cur)
-        walks = jnp.stack(walk, axis=1)  # [B, L+1] rows (0 = dead)
+        walks = self._walk_rows(  # [B, L+1] rows (0 = dead)
+            cur, kwalk, self.walk_len,
+            step=self._walk_step if self.biased else None,
+        )
         src = walks[:, self._src_cols] * self._col_valid  # [B, M]
         ctx = walks[:, self._ctx_cols] * self._col_valid
         mask = (src > 0) & (ctx > 0)
@@ -1257,6 +1280,62 @@ class DeviceWalkFlow(DeviceGraphTables):
             "mask": self._dp(mask.reshape(-1)),
         }
 
+
+
+class DeviceSequenceFlow(DeviceGraphTables):
+    """Token sequences drawn on the device: the graph is a transition
+    graph over a vocabulary (node = token), a document is a uniform random
+    walk of `doc_len` nodes, and `docs_per_seq` documents are packed end
+    to end into one sequence, unmasked from one another. The framework's
+    own sampler is the corpus of a sequence model (`models/sequence_lm.py`).
+
+    `sample(key)` returns int32 token ids [batch_size, seq_len + 1]: the
+    inputs and, shifted by one, the next-token targets; the further id is
+    the node the sequence's last walk moves to next. A token is its
+    node's id less 1 (ids count from 1). `split(key, 2)` gives the root
+    key and the walk key; transition i draws under `split(walk_key,
+    doc_len)[i]` as `DeviceWalkFlow`'s walks do.
+    """
+
+    def __init__(
+        self,
+        graph,
+        batch_size: int,
+        seq_len: int,
+        doc_len: int,
+        edge_types=None,
+        max_degree: int = 512,
+        mesh=None,
+        layout: str = "auto",
+    ):
+        if seq_len % doc_len:
+            raise ValueError(
+                f"seq_len {seq_len} is not a whole number of documents of "
+                f"{doc_len} tokens"
+            )
+        super().__init__(
+            graph, edge_types, max_degree, mesh=mesh, layout=layout
+        )
+        self.batch_size = int(batch_size)
+        self.seq_len = int(seq_len)
+        self.doc_len = int(doc_len)
+        self.docs_per_seq = self.seq_len // self.doc_len
+
+    def sample(self, key):
+        """key -> int32 [batch_size, seq_len + 1] token ids, jit-traceable."""
+        kroot, kwalk = jax.random.split(key)
+        walks = self.batch_size * self.docs_per_seq
+        cur = self._dp(self._draw_roots(kroot, walks))
+        rows = self._walk_rows(  # [walks, doc_len + 1]
+            cur, kwalk, self.doc_len, scan=True
+        )
+        ids = self.node_id[rows].reshape(
+            self.batch_size, self.docs_per_seq, self.doc_len + 1
+        )
+        packed = ids[:, :, : self.doc_len].reshape(self.batch_size, -1)
+        return self._dp(
+            jnp.concatenate([packed, ids[:, -1, self.doc_len :]], axis=1) - 1
+        )
 
 
 class _FlatEdgeFlow(DeviceGraphTables):

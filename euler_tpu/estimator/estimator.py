@@ -714,7 +714,7 @@ class Estimator:
         """One call of a step program on the Estimator's state; returns
         its (loss or losses, metric). The first call of each program is
         waited for and recorded as a set-up span (`_first_call`)."""
-        with trace.span("train.dispatch", step=self.step):
+        with trace.span("train.dispatch", step=self.step) as span:
             name = step_fn.__name__
             first = name not in self._called
             tables = self._tables()
@@ -725,6 +725,10 @@ class Estimator:
                 if first:
                     self._called.add(name)
                     jax.block_until_ready(loss)
+            if trace.profiling():
+                # the model's metric of a profiled step, left on the
+                # device: whoever reads the record fetches it
+                span.args["metric"] = metric
         return loss, metric
 
     def _drain(self, history: list, fetched: list, concat: bool) -> None:

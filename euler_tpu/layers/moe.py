@@ -1,0 +1,184 @@
+"""`SparseMoE`: a mixture-of-experts feed-forward layer that is told which
+of the routed experts it holds.
+
+Expert parallelism divides a layer's experts over chips; every chip
+routes every token over ALL experts (router width and top-k as
+published), and computes its own experts' part of the result for the
+tokens routed to them. This layer is one chip's part: `held = (first,
+count)` of `num_experts`. What the absent experts would have added is
+left out and the partial result goes on; across a group the exchange
+(all-to-all of rows, sum of partial results) belongs around this layer,
+and on one chip there is none. The shares of all `num_experts / count`
+chips, the shared expert counted once, add up to the whole layer
+(tests/test_sequence_lm.py).
+
+Dispatch and combine are the repo's message-passing primitives: the
+token-expert assignments are sorted by expert, the tokens' rows gathered
+in that order (`ops.gather`), multiplied group by group
+(`seq_ops.grouped_matmul`), weighted, and summed back per token
+(`ops.scatter_add`).
+
+No assignment to a held expert is dropped, and no bound is guessed. A
+grouped matmul needs a static number of rows and the number routed here
+is the router's to decide, between none and the worst case (every token
+routed to `min(top_k, count)` held experts). So the sorted assignments
+are taken in tiles of as many rows as there are tokens, `min(top_k,
+count)` tiles at the worst, and only the tiles that hold a routed row
+are run: a loop whose trip count the step's own routing sets, forward
+and backward (`held_experts`). An even router fills
+`top_k * count / num_experts` of a tile; a router that has learned to
+prefer the experts held here costs the tiles it fills and nothing
+overflows.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from euler_tpu.ops import gather, scatter_add, seq_ops
+from euler_tpu.utils import trace
+
+_MATRIX = nn.initializers.normal(stddev=0.02)
+
+
+def _swiglu(x, w_gate, w_up, w_down, matmul):
+    return matmul(jax.nn.silu(matmul(x, w_gate)) * matmul(x, w_up), w_down)
+
+
+def _tile(top_k, start, order, ends, x, weight, w_gate, w_up, w_down):
+    """What the held experts add to every token from the sorted
+    assignments `start .. start + N` (N = the number of tokens): [N, H]."""
+    tokens = x.shape[0]
+    with trace.scope("moe.dispatch"):
+        mine = jax.lax.dynamic_slice(order, (start,), (tokens,))
+        token = mine // top_k
+        rows = gather(x, token)
+        # each expert's rows inside this tile; the rows past the last
+        # routed one belong to no group, and the grouped matmul takes and
+        # leaves them as zeros, forward and transposed
+        sizes = jnp.diff(jnp.clip(ends, start, start + tokens), prepend=start)
+    with trace.scope("moe.experts"):
+        out = _swiglu(
+            rows, w_gate, w_up, w_down,
+            lambda a, w: seq_ops.grouped_matmul(a, w, sizes),
+        )
+    with trace.scope("moe.combine"):
+        return scatter_add(out * gather(weight, mine)[:, None], token, tokens)
+
+
+def _tiles(x, ends):
+    """How many tiles of `len(x)` sorted assignments hold a routed row."""
+    return (ends[-1] + x.shape[0] - 1) // x.shape[0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def held_experts(top_k, order, ends, x, weight, w_gate, w_up, w_down):
+    """sum over the assignments (token n, expert e held here) of
+    `weight[n, e] E_e(x[n])`, per token: [N, H].
+
+    `order` [N * top_k] lists the flat assignments sorted by held expert,
+    those to absent experts last; `ends` [count] is where each held
+    expert's run of them ends; `weight` [N * top_k] the routing weights.
+    The tiles are run by a loop of `_tiles` trips, which reverse-mode
+    differentiation cannot pass through: the backward pass is the same
+    loop over each tile's own vjp."""
+    step = x.shape[0]
+    return jax.lax.fori_loop(
+        0, _tiles(x, ends),
+        lambda t, y: y + _tile(top_k, t * step, order, ends, x, weight, w_gate, w_up, w_down),
+        jnp.zeros_like(x),
+    )
+
+
+def _held_experts_fwd(top_k, order, ends, *inputs):
+    return held_experts(top_k, order, ends, *inputs), (order, ends, inputs)
+
+
+def _held_experts_bwd(top_k, kept, dy):
+    order, ends, inputs = kept
+    step = inputs[0].shape[0]
+
+    def tile_grads(t, grads):
+        _, pull = jax.vjp(
+            functools.partial(_tile, top_k, t * step, order, ends), *inputs
+        )
+        return jax.tree_util.tree_map(jnp.add, grads, pull(dy))
+
+    grads = jax.lax.fori_loop(
+        0, _tiles(inputs[0], ends), tile_grads,
+        jax.tree_util.tree_map(jnp.zeros_like, inputs),
+    )
+    return (None, None, *grads)
+
+
+held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+class SparseMoE(nn.Module):
+    """x [N, H] -> (y [N, H], the number of token-expert assignments that
+    landed on held experts).
+
+    `p = softmax(x W_r)` over all experts in float32; the `top_k` largest
+    are kept and, with `norm_topk`, divided by their sum;
+    `y = sum_{e in top_k, e held} p_e E_e(x) + sigmoid(x . w_s) E_shared(x)`,
+    `E(x) = W_down (SiLU(W_gate x) * W_up x)`. Every assignment to a held
+    expert is computed, however many there are (`held_experts`).
+    """
+
+    num_experts: int
+    top_k: int
+    expert_dim: int
+    shared_dim: int
+    held: tuple = (0, 0)  # (first, count); count 0 = all of them
+    norm_topk: bool = True
+
+    @nn.compact
+    def __call__(self, x):
+        hidden = x.shape[1]
+        first, count = self.held
+        count = count or self.num_experts
+        k = self.top_k
+        w_router = self.param(
+            "router", _MATRIX, (hidden, self.num_experts), jnp.float32
+        )
+        shape = (count, hidden, self.expert_dim)
+        w_gate = self.param("experts_gate", _MATRIX, shape, jnp.float32)
+        w_up = self.param("experts_up", _MATRIX, shape, jnp.float32)
+        w_down = self.param(
+            "experts_down", _MATRIX, (count, self.expert_dim, hidden), jnp.float32
+        )
+        s_gate = self.param(
+            "shared_gate", _MATRIX, (hidden, self.shared_dim), jnp.float32
+        )
+        s_up = self.param("shared_up", _MATRIX, (hidden, self.shared_dim), jnp.float32)
+        s_down = self.param(
+            "shared_down", _MATRIX, (self.shared_dim, hidden), jnp.float32
+        )
+        s_mix = self.param("shared_mix", _MATRIX, (hidden, 1), jnp.float32)
+
+        with trace.scope("moe.route"):
+            # float32 for real: a top-k pick that flips on a bf16-rounded
+            # logit would send a token to other experts
+            logits = jnp.matmul(
+                x.astype(jnp.float32), w_router,
+                precision=jax.lax.Precision.HIGHEST,
+            )
+            top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+            if self.norm_topk:
+                top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        with trace.scope("moe.dispatch"):
+            here = (top_e >= first) & (top_e < first + count)
+            # assignments sorted by held expert, those to absent experts last
+            slot = jnp.where(here, top_e - first, count).reshape(-1)
+            order = jnp.argsort(slot, stable=True).astype(jnp.int32)
+            ends = jnp.cumsum(jnp.bincount(slot, length=count + 1)[:count])
+            ends = ends.astype(jnp.int32)
+        y = held_experts(k, order, ends, x, top_p.reshape(-1), w_gate, w_up, w_down)
+        with trace.scope("moe.shared"):
+            mix = jax.nn.sigmoid((x @ s_mix).astype(jnp.float32))
+            y = y + mix * _swiglu(x, s_gate, s_up, s_down, jnp.matmul)
+        return y, ends[-1]

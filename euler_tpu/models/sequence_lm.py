@@ -1,0 +1,153 @@
+"""`Qwen3NextLM`: a decoder-only language model of the Qwen3-Next family
+(HF `modeling_qwen3_next.py`) as an `Estimator` model.
+
+Decoder layer l: `h += Mixer_l(norm(h))`, then `h += MoE(norm(h))`;
+`Mixer_l` is `GatedAttention` when `(l + 1) % full_attention_interval ==
+0` and `GatedDeltaNet` otherwise (layers/sequence.py), the feed-forward a
+`SparseMoE` that holds `experts_here` of the routed experts
+(layers/moe.py). Embedding (`nn/encoders.py:Embedding`, so `euler.embed`
+and the table's scatter-add gradient are the ones every embedding model
+here has), the layers, a final norm, an untied head and the mean
+next-token cross-entropy in float32. Every layer is rematerialised in the
+backward pass: what is kept of the forward is each layer's input.
+
+The batch is what `DeviceSequenceFlow.sample` returns: int32 ids
+[B, T + 1]; positions 0..T-1 are the inputs and 1..T the targets. The
+vocabulary may be a slice (`vocab_size` rows of the published table): ids,
+logits and loss are over the slice.
+"""
+
+from __future__ import annotations
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import optax
+
+from euler_tpu.layers.moe import SparseMoE
+from euler_tpu.layers.sequence import GatedAttention, GatedDeltaNet, RMSNorm
+from euler_tpu.nn.encoders import Embedding
+from euler_tpu.utils import trace
+
+
+class DecoderLayer(nn.Module):
+    mixer: nn.Module
+    moe: nn.Module
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, h):
+        h = h + self.mixer(RMSNorm(self.eps, name="input_norm")(h))
+        x = RMSNorm(self.eps, name="post_norm")(h)
+        y, routed = self.moe(x.reshape(-1, x.shape[-1]))
+        return h + y.reshape(h.shape), routed
+
+
+class Qwen3NextLM(nn.Module):
+    """Returns `(emb, loss, "routed_share", share)`: the final hidden
+    states [B, T, H], the loss, and the share of the step's token-expert
+    assignments that landed on experts held here (`experts_here[1] /
+    num_experts` when the router is even)."""
+
+    vocab_size: int
+    hidden_size: int
+    num_layers: int
+    full_attention_interval: int = 4
+    # gated attention
+    num_heads: int = 16
+    num_kv_heads: int = 2
+    head_dim: int = 256
+    rope_theta: float = 1e7
+    partial_rotary_factor: float = 0.25
+    attention_block: int = 512
+    # gated DeltaNet
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_conv_kernel_dim: int = 4
+    chunk: int = 64
+    # experts
+    num_experts: int = 512
+    num_experts_per_tok: int = 10
+    moe_intermediate_size: int = 512
+    shared_expert_intermediate_size: int = 512
+    norm_topk_prob: bool = True
+    experts_here: tuple = (0, 0)  # (first, count); count 0 = all
+    rms_norm_eps: float = 1e-6
+    loss_chunks: int = 1  # the head and loss run over T in this many parts
+
+    def _layer(self, index: int):
+        if (index + 1) % self.full_attention_interval == 0:
+            mixer = GatedAttention(
+                num_heads=self.num_heads,
+                num_kv_heads=self.num_kv_heads,
+                head_dim=self.head_dim,
+                rope_theta=self.rope_theta,
+                rotary_dim=int(self.head_dim * self.partial_rotary_factor),
+                block=self.attention_block,
+                eps=self.rms_norm_eps,
+                parent=None,  # adopted by the layer, as its `mixer`
+            )
+        else:
+            mixer = GatedDeltaNet(
+                num_k_heads=self.linear_num_key_heads,
+                num_v_heads=self.linear_num_value_heads,
+                head_k_dim=self.linear_key_head_dim,
+                head_v_dim=self.linear_value_head_dim,
+                conv_kernel=self.linear_conv_kernel_dim,
+                chunk=self.chunk,
+                eps=self.rms_norm_eps,
+                parent=None,  # adopted by the layer, as its `mixer`
+            )
+        moe = SparseMoE(
+            num_experts=self.num_experts,
+            top_k=self.num_experts_per_tok,
+            expert_dim=self.moe_intermediate_size,
+            shared_dim=self.shared_expert_intermediate_size,
+            held=tuple(self.experts_here),
+            norm_topk=self.norm_topk_prob,
+            parent=None,
+        )
+        return nn.remat(DecoderLayer)(
+            mixer, moe, self.rms_norm_eps, name=f"layer_{index}",
+        )
+
+    @nn.compact
+    def __call__(self, ids):
+        tokens, targets = ids[:, :-1], ids[:, 1:]
+        h = Embedding(self.vocab_size, self.hidden_size, name="embed")(tokens)
+        routed = jnp.zeros((), jnp.int32)
+        for index in range(self.num_layers):
+            h, here = self._layer(index)(h)
+            routed = routed + here
+        emb = RMSNorm(self.rms_norm_eps, name="final_norm")(h)
+        w_head = self.param(
+            "head", nn.initializers.normal(stddev=0.02),
+            (self.hidden_size, self.vocab_size), jnp.float32,
+        )
+
+        @jax.checkpoint
+        def part_loss(x, y, w):
+            with trace.scope("head"):
+                logits = (x @ w).astype(jnp.float32)
+            with trace.scope("loss"):
+                return jnp.sum(
+                    optax.softmax_cross_entropy_with_integer_labels(logits, y)
+                )
+
+        # the logits of all T positions at once would be the step's largest
+        # tensor: each part's are made, used and made again in the backward
+        total = sum(
+            part_loss(x, y, w_head)
+            for x, y in zip(
+                jnp.split(emb, self.loss_chunks, axis=1),
+                jnp.split(targets, self.loss_chunks, axis=1),
+            )
+        )
+        with trace.scope("loss"):
+            loss = total / targets.size
+        assignments = tokens.size * self.num_experts_per_tok * self.num_layers
+        share = routed.astype(jnp.float32) / assignments
+        return emb, loss, "routed_share", share
+

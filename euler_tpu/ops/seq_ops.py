@@ -1,0 +1,211 @@
+"""Device-side primitives of sequence layers (layers/sequence.py,
+layers/moe.py): a causal depthwise convolution over time, the chunked
+gated delta rule, causal softmax attention by query blocks, and the
+grouped matmul over rows sorted by expert.
+
+All plain XLA over static shapes; matmuls run at the backend's default
+precision (bf16 inputs, float32 accumulation on the TPU) unless said.
+Inputs and results are float32; so are the delta rule's state and gates
+and the attention's softmax.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+Array = jax.Array
+
+# the chunk-local inverse multiplies a matrix by itself log2(chunk) times:
+# rounding its inputs to bf16 at every level would compound
+_INVERSE_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def causal_conv1d(x: Array, weight: Array) -> Array:
+    """Depthwise convolution over time that sees no later step, no bias.
+
+    x [B, T, C], weight [C, K]: y_t = sum_j weight[:, j] * x_{t-(K-1)+j},
+    with zeros before the sequence's start (torch `Conv1d(groups=C,
+    padding=K-1)` cut to T).
+    """
+    length, taps = x.shape[1], weight.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    return sum(
+        padded[:, j : j + length, :] * weight[:, j] for j in range(taps)
+    )
+
+
+@jax.custom_vjp
+def _unit_lower_inverse(lower: Array) -> Array:
+    """(I + L)^-1 for strictly lower-triangular L [..., C, C]. L is
+    nilpotent, so the Neumann series ends and factors into
+    (I - L)(I + L^2)(I + L^4)... — log2(C) squarings on the MXU where a
+    forward substitution would take C dependent steps. Its gradient is
+    taken from the result alone (`d(A^-1) = -A^-1 dA A^-1`), not through
+    the squarings."""
+    size = lower.shape[-1]
+    eye = jnp.eye(size, dtype=lower.dtype)
+    power = -lower
+    inverse = eye + power
+    reach = 2  # `inverse` holds the series up to power reach - 1
+    while reach < size:
+        power = jnp.matmul(power, power, precision=_INVERSE_PRECISION)
+        inverse = inverse + jnp.matmul(
+            inverse, power, precision=_INVERSE_PRECISION
+        )
+        reach *= 2
+    return inverse
+
+
+def _unit_lower_inverse_fwd(lower):
+    inverse = _unit_lower_inverse(lower)
+    return inverse, inverse
+
+
+def _unit_lower_inverse_bwd(inverse, cotangent):
+    t = jnp.swapaxes(inverse, -1, -2)
+    inner = jnp.matmul(cotangent, t, precision=_INVERSE_PRECISION)
+    return (-jnp.matmul(t, inner, precision=_INVERSE_PRECISION),)
+
+
+_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+def _chunk_local(q, k, v, g, beta):
+    """What a chunk needs that does not depend on the state: for chunks
+    [n, B, H, C, ...] the scan's inputs (U, W, masked Q K^T, exp(G) Q,
+    exp(G_end - G) K, G_end)."""
+    chunk = q.shape[-2]
+    run = jnp.cumsum(g, axis=-1)  # G: [n, B, H, C]
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    # exp(G_i - G_j) for j <= i, where the exponent is <= 0; 0 above
+    gap = run[..., :, None] - run[..., None, :]
+    decay = jnp.where(lower, jnp.exp(jnp.where(lower, gap, 0.0)), 0.0)
+    k_beta = k * beta[..., None]
+    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    kk = jnp.einsum("...id,...jd->...ij", k_beta, k)
+    solve = _unit_lower_inverse(jnp.where(strict, kk * decay, 0.0))
+    u = jnp.matmul(solve, v * beta[..., None])  # (I+L)^-1 beta V
+    w = jnp.matmul(solve, k_beta * jnp.exp(run)[..., None])
+    qk = jnp.einsum("...id,...jd->...ij", q, k) * decay
+    q_run = q * jnp.exp(run)[..., None]
+    end = run[..., -1]  # G at the chunk's end: [n, B, H]
+    k_end = k * jnp.exp(end[..., None] - run)[..., None]
+    return u, w, qk, q_run, k_end, end
+
+
+def _chunk_step(state, xs):
+    """One chunk against the state [B, H, dk, dv] at its start."""
+    u, w, qk, q_run, k_end, end = xs
+    d = u - jnp.matmul(w, state)
+    out = jnp.matmul(q_run, state) + jnp.matmul(qk, d)
+    state = state * jnp.exp(end)[..., None, None] + jnp.einsum(
+        "...cd,...ce->...de", k_end, d
+    )
+    return state, out
+
+
+def chunk_gated_delta_rule(
+    q: Array, k: Array, v: Array, g: Array, beta: Array,
+    chunk: int = 64, group: int = 16,
+) -> Array:
+    """The gated delta rule, chunk by chunk (Yang et al., Gated Delta
+    Networks; the WY form of HF `torch_chunk_gated_delta_rule`).
+
+    q, k [B, H, T, dk] (already normalised and scaled), v [B, H, T, dv],
+    g [B, H, T] the log of the decay (<= 0), beta [B, H, T]. Per head,
+    from S_0 = 0:
+
+        S~ = exp(g_t) S_{t-1};  d_t = beta_t (v_t - S~^T k_t)
+        S_t = S~ + k_t d_t^T;   o_t = S_t^T q_t
+
+    Inside a chunk, with G the running sum of g and S the state at the
+    chunk's start, the d_t solve (I + L) D = beta V - (beta exp(G) K) S,
+    L_ij = beta_i exp(G_i - G_j) k_i.k_j below the diagonal; then
+    O = (exp(G) Q) S + tril(Q K^T exp(G_i - G_j)) D and the state moves
+    on by exp(G_end) S + (exp(G_end - G) K)^T D. Only that last part is
+    sequential: a `lax.scan` over the chunks' states [dk, dv], in float32.
+
+    The chunks are taken `group` at a time: a group's chunk-local
+    matrices are made together (batched matmuls), its states scanned, and
+    the group is rematerialised in the backward pass, so only one group's
+    [C, C] matrices and states are ever alive — they are several times
+    the inputs. A length that is not a whole number of groups is padded
+    with steps that leave the state as it is (beta = 0, g = 0).
+    Returns o [B, H, T, dv].
+    """
+    length = q.shape[2]
+    group = min(group, -(-length // chunk))
+    pad = -length % (chunk * group)
+    if pad:
+        q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0))) for a in (q, k, v))
+        g, beta = (jnp.pad(a, ((0, 0), (0, 0), (0, pad))) for a in (g, beta))
+    groups = (length + pad) // (chunk * group)
+
+    def split(a):  # [B, H, T, ...] -> [groups, group, B, H, C, ...]
+        a = a.reshape(a.shape[:2] + (groups, group, chunk) + a.shape[3:])
+        return jnp.moveaxis(a, (2, 3), (0, 1))
+
+    @jax.checkpoint
+    def one_group(state, xs):
+        return jax.lax.scan(_chunk_step, state, _chunk_local(*xs))
+
+    state = jnp.zeros(q.shape[:2] + (q.shape[-1], v.shape[-1]), jnp.float32)
+    _, out = jax.lax.scan(
+        one_group, state, tuple(split(a) for a in (q, k, v, g, beta))
+    )
+    # [groups, group, B, H, C, dv] -> [B, H, T, dv]
+    out = jnp.moveaxis(out, (0, 1), (2, 3))
+    out = out.reshape(out.shape[:2] + (-1, out.shape[-1]))
+    return out[:, :, :length]
+
+
+def blockwise_causal_attention(
+    q: Array, k: Array, v: Array, scale: float, block: int = 512
+) -> Array:
+    """Causal softmax attention, grouped queries, one block of queries at
+    a time: a block's scores against the keys up to its end are the
+    largest tensor there is ([B, G, R, block, end] float32), never the
+    whole [T, T] square, and the upper half beyond a block's own diagonal
+    square is not computed at all. Each block is rematerialised in the
+    backward pass, so no block's scores outlive it.
+
+    q [B, G, R, T, d] (G key/value heads, R query heads to each),
+    k, v [B, G, T, d]. Returns [B, G, R, T, d].
+    """
+    length = q.shape[3]
+    block = min(block, length)
+
+    @functools.partial(jax.checkpoint, static_argnums=(3,))
+    def one(q_b, k_b, v_b, first):
+        scores = jnp.einsum("bgrtd,bgsd->bgrts", q_b, k_b) * scale
+        rows = first + jnp.arange(q_b.shape[3])[:, None]
+        seen = jnp.arange(k_b.shape[2])[None, :] <= rows
+        scores = jnp.where(seen, scores.astype(jnp.float32), -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1)
+        return jnp.einsum("bgrts,bgsd->bgrtd", probs, v_b)
+
+    outs = []
+    for first in range(0, length, block):
+        end = min(first + block, length)
+        outs.append(
+            one(q[:, :, :, first:end], k[:, :, :end], v[:, :, :end], first)
+        )
+    return jnp.concatenate(outs, axis=3)
+
+
+def grouped_matmul(rows: Array, weights: Array, group_sizes: Array) -> Array:
+    """rows [M, K] sorted by group, weights [G, K, N], group_sizes [G]:
+    row i of group e is multiplied by weights[e]. Rows past
+    sum(group_sizes) belong to no group: they are read as zeros and come
+    out as zeros, and so do their cotangents. The TPU's grouped-matmul
+    kernel leaves such rows unwritten, forward and transposed, and what
+    lies there may be NaN: masked on the way in and on the way out,
+    nothing of it reaches a result or a gradient, not even times zero.
+    `jax.lax.ragged_dot` is a native grouped matmul on the TPU, forward
+    and both transposes."""
+    live = (jnp.arange(rows.shape[0]) < jnp.sum(group_sizes))[:, None]
+    out = jax.lax.ragged_dot(jnp.where(live, rows, 0.0), weights, group_sizes)
+    return jnp.where(live, out, 0.0)

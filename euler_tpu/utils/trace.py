@@ -56,6 +56,11 @@ def scope(name: str):
     return jax.named_scope(PREFIX + name)
 
 
+def profiling() -> bool:
+    """Whether a profiler session is live, whoever started it."""
+    return jax.profiler.TraceAnnotation.is_enabled()
+
+
 def _record(name, start_ns, end_ns, parent, ident, args) -> None:
     # plain tuples here, `Span`s when read: building the named tuple
     # would be a fifth of a span's cost. deque.append is atomic under

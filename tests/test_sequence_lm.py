@@ -1,0 +1,372 @@
+"""Qwen3-Next on the CPU at a small size, against the benchmark's plain
+reference (`benchmarks/reference/qwen3_next.py`, loaded by path — there is
+no second copy): the chunked delta rule against the token-by-token
+recurrence, gated attention and its partial rotary, the share test of the
+expert layer, three `Estimator.train` steps against the reference's loop,
+and the device flow's sequences against the reference's walks."""
+
+import importlib.util
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmarks")
+
+
+def _load(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.fixture(scope="module")
+def bench():
+    """The benchmark's modules, importable as its own files import one
+    another; taken off the path again afterwards."""
+    sys.path.insert(0, BENCH)
+    try:
+        import graphs
+        import program_graph
+        import weights
+
+        yield {
+            "ref": _load(os.path.join(BENCH, "reference", "qwen3_next.py"), "ref_qwen3_next"),
+            "train": _load(os.path.join(BENCH, "reference", "train.py"), "ref_train"),
+            "family": _load(os.path.join(BENCH, "families", "qwen3_next.py"), "fam_qwen3_next"),
+            "graphs": graphs,
+            "program_graph": program_graph.program_graph,
+            "weights": weights,
+        }
+    finally:
+        sys.path.remove(BENCH)
+
+
+@pytest.fixture(scope="module")
+def config():
+    with open(os.path.join(BENCH, "configs", "qwen3-next-80b-a3b-ep16.json")) as f:
+        full = json.load(f)
+
+    def merge(base, over):
+        out = dict(base)
+        for k, v in over.items():
+            out[k] = merge(base[k], v) if isinstance(v, dict) and isinstance(base.get(k), dict) else v
+        return out
+
+    return merge(full, full["rehearse"])
+
+
+def _highest(fn):
+    def run(*args):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args)
+
+    return run
+
+
+def _value_and_grads(fn, weigh, argnums):
+    """One compiled call: fn's result and the gradients of a scalar of it."""
+
+    def scalar(*args):
+        out = fn(*args)
+        return jnp.sum(weigh(out)), out
+
+    return _highest(jax.jit(jax.value_and_grad(scalar, argnums=argnums, has_aux=True)))
+
+
+# -- (a) the chunked delta rule -------------------------------------------
+
+
+def _rule_inputs(length, seed=0, batch=2, heads=3, dk=8, dv=8):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q = jax.random.normal(ks[0], (batch, heads, length, dk))
+    k = jax.random.normal(ks[1], (batch, heads, length, dk))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * dk**-0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (batch, heads, length, dv))
+    g = -jax.nn.softplus(jax.random.normal(ks[3], (batch, heads, length)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (batch, heads, length)))
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize("length,chunk", [(32, 8), (30, 8), (19, 16), (7, 16)])
+def test_chunked_delta_rule_matches_the_recurrence(bench, length, chunk):
+    from euler_tpu.ops import seq_ops
+
+    args = _rule_inputs(length)
+    to_ref = lambda a: jnp.moveaxis(a, 1, 2)  # noqa: E731  [B,H,T,..] -> [B,T,H,..]
+
+    def program(q, k, v, g, beta):
+        return seq_ops.chunk_gated_delta_rule(q, k, v, g, beta, chunk=chunk, group=2)
+
+    def reference(q, k, v, g, beta):
+        o = bench["ref"].delta_rule(
+            to_ref(q), to_ref(k), to_ref(v), to_ref(jnp.exp(g)), to_ref(beta), 4
+        )
+        return jnp.moveaxis(o, 2, 1)
+
+    weight = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    (_, got), g_got = _value_and_grads(program, lambda o: o * weight, (0, 1, 2, 3, 4))(*args)
+    (_, want), g_want = _value_and_grads(reference, lambda o: o * weight, (0, 1, 2, 3, 4))(*args)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5)
+
+
+# -- (b) gated attention and the partial rotary ----------------------------
+
+
+@pytest.mark.parametrize("length,block", [(16, 4), (12, 8), (8, 64)])
+def test_gated_attention_matches_the_reference(bench, config, length, block):
+    from euler_tpu.layers.sequence import GatedAttention
+
+    layer = GatedAttention(
+        num_heads=config["num_attention_heads"],
+        num_kv_heads=config["num_key_value_heads"],
+        head_dim=config["head_dim"],
+        rope_theta=float(config["rope_theta"]),
+        rotary_dim=int(config["head_dim"] * config["partial_rotary_factor"]),
+        block=block,
+    )
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, length, config["hidden_size"]))
+    params = layer.init(jax.random.PRNGKey(1), x)
+    # norm weights off zero, so that (1 + w) is tested
+    params = jax.tree_util.tree_map(
+        lambda p: p + 0.1 * jax.random.normal(jax.random.PRNGKey(2), p.shape), params
+    )
+    flat = bench["weights"].flatten(params["params"])
+
+    def program(params, x):
+        return layer.apply(params, x)
+
+    def reference(params, x):
+        flat = bench["weights"].flatten(params["params"])
+        return bench["ref"].gated_attention(flat, x, config, 4)
+
+    assert set(flat) == {"q_proj", "k_proj", "v_proj", "o_proj", "q_norm/w", "k_norm/w"}
+    (_, got), g_got = _value_and_grads(program, jnp.sin, 0)(params, x)
+    (_, want), g_want = _value_and_grads(reference, jnp.sin, 0)(params, x)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-6)
+    for a, b in zip(*map(jax.tree_util.tree_leaves, (g_got, g_want))):
+        np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-6)
+
+
+def test_rotary_turns_only_the_first_part_of_the_head(bench):
+    from euler_tpu.layers.sequence import rotary
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 6, 2, 16))
+    out = rotary(x, 1e7, 4)
+    np.testing.assert_array_equal(out[..., 4:], x[..., 4:])
+    np.testing.assert_allclose(out[:, 0], x[:, 0], atol=1e-6)  # position 0
+    assert not np.allclose(out[:, 1:, :, :4], x[:, 1:, :, :4])
+    np.testing.assert_allclose(out, bench["ref"].rotate(x, 1e7, 4), atol=1e-6)
+
+
+# -- (c) the share test -----------------------------------------------------
+
+
+def _moe_layers(config):
+    from euler_tpu.layers.moe import SparseMoE
+
+    experts, top_k = config["model"]["router_experts"], config["num_experts_per_tok"]
+    common = dict(
+        num_experts=experts, top_k=top_k,
+        expert_dim=config["moe_intermediate_size"],
+        shared_dim=config["shared_expert_intermediate_size"],
+    )
+    return experts, top_k, lambda first, count: SparseMoE(held=(first, count), **common)
+
+
+@pytest.mark.parametrize("router_scale", [1.0, 40.0])
+def test_expert_shares_add_up_to_the_whole_layer(bench, config, router_scale):
+    """The parts all `num_experts / count` chips compute, the shared
+    expert counted once, are the uncut layer: under an even router and
+    under one far from even, whose shares see unequal loads."""
+    experts, top_k, layer = _moe_layers(config)
+    hidden, count = config["hidden_size"], config["model"]["experts_here"][1]
+    x = jax.random.normal(jax.random.PRNGKey(0), (96, hidden))
+    params = layer(0, experts).init(jax.random.PRNGKey(1), x)["params"]
+    params["router"] = params["router"] * router_scale
+
+    uncut = dict(config, model=dict(config["model"], experts_here=[0, experts]))
+    want = _highest(bench["ref"].mixture)(params, x, uncut, "")
+    shared_only = _highest(bench["ref"].mixture)(params, x, uncut, "no_routed")
+
+    total, rows = jnp.zeros_like(x), 0
+    for first in range(0, experts, count):
+        mine = dict(params)
+        for name in ("experts_gate", "experts_up", "experts_down"):
+            mine[name] = params[name][first : first + count]
+        y, routed = _highest(layer(first, count).apply)({"params": mine}, x)
+        # this chip's result against the reference given the same share
+        cut = dict(config, model=dict(config["model"], experts_here=[first, count]))
+        np.testing.assert_allclose(
+            y, _highest(bench["ref"].mixture)(mine, x, cut, ""), rtol=1e-4, atol=1e-6
+        )
+        total, rows = total + (y - shared_only), rows + int(routed)
+    assert rows == x.shape[0] * top_k  # every assignment landed on one chip
+    np.testing.assert_allclose(total + shared_only, want, rtol=1e-4, atol=1e-6)
+    assert float(jnp.max(jnp.abs(want - shared_only))) > 1e-4  # the experts matter
+
+
+@pytest.mark.parametrize("prefers_held", [0.0, 8.0])
+def test_expert_layer_drops_nothing_however_the_router_leans(bench, config, prefers_held):
+    """Result and gradients of one chip's share against the reference,
+    under an even router (less than one tile of rows lands here) and
+    under one that sends every token's whole top-k here (all the tiles):
+    the loop over tiles, forward and backward, leaves no row out."""
+    _, top_k, layer = _moe_layers(config)
+    first, count = config["model"]["experts_here"]
+    part = layer(first, count)
+    x = jax.random.normal(jax.random.PRNGKey(2), (96, config["hidden_size"]))
+    params = part.init(jax.random.PRNGKey(3), x)["params"]
+    params["router"] = params["router"].at[:, first : first + count].add(prefers_held)
+    x = jnp.abs(x)  # so that the lean has one sign for every token
+
+    def program(params, x):
+        y, routed = part.apply({"params": params}, x)
+        return jnp.sum(jnp.sin(y)), routed
+
+    def reference(params, x):
+        return jnp.sum(jnp.sin(bench["ref"].mixture(params, x, config, ""))), None
+
+    with jax.default_matmul_precision("highest"):
+        (got, routed), g_got = jax.value_and_grad(program, (0, 1), has_aux=True)(params, x)
+        (want, _), g_want = jax.value_and_grad(reference, (0, 1), has_aux=True)(params, x)
+    tiles = -(-int(routed) // x.shape[0])
+    assert tiles == (min(top_k, count) if prefers_held else 1), int(routed)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for (path, a), b in zip(
+        jax.tree_util.tree_leaves_with_path(g_got), jax.tree_util.tree_leaves(g_want)
+    ):
+        np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-6, err_msg=str(path))
+
+
+def test_grouped_matmul_keeps_rows_past_the_groups_out():
+    """Whatever lies in the rows that belong to no group (the TPU's kernel
+    leaves them unwritten; here they are NaN on the way in) reaches no
+    result and no gradient."""
+    from euler_tpu.ops import seq_ops
+
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
+    rows = jax.random.normal(k1, (12, 8)).at[7:].set(jnp.nan)
+    weights = jax.random.normal(k2, (3, 8, 5))
+    sizes = jnp.array([4, 0, 3], jnp.int32)
+    seen = jax.random.normal(k3, (12, 5))  # a cotangent that is not zero anywhere
+
+    def total(rows, weights):
+        return jnp.sum(seq_ops.grouped_matmul(rows, weights, sizes) * seen)
+
+    out = seq_ops.grouped_matmul(rows, weights, sizes)
+    want = jnp.concatenate([rows[:4] @ weights[0], rows[4:7] @ weights[2]])
+    np.testing.assert_allclose(out[:7], want, rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(out[7:], 0.0)
+    d_rows, d_weights = jax.grad(total, (0, 1))(rows, weights)
+    np.testing.assert_array_equal(d_rows[7:], 0.0)
+    assert bool(jnp.all(jnp.isfinite(d_rows))) and bool(jnp.all(jnp.isfinite(d_weights)))
+    np.testing.assert_allclose(d_weights[0], rows[:4].T @ seen[:4], rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(d_weights[1], 0.0)
+
+
+# -- (d) three Estimator.train steps against the reference's loop -----------
+
+
+def _built(bench, config):
+    graph = bench["graphs"].build(config["graph"])
+    built = bench["family"].build(config, {}, graph)
+    return graph, built
+
+
+@pytest.mark.parametrize("seed", [3000000019])
+def test_three_train_steps_match_the_reference(bench, config, seed, tmp_path):
+    from euler_tpu.estimator import Estimator, EstimatorConfig
+
+    weights, train = bench["weights"], bench["train"]
+    graph, built = _built(bench, config)
+    spec = bench["ref"].param_spec(config, graph)
+    lr = config["optimizer"]["learning_rate"]
+    cfg = EstimatorConfig(
+        model_dir="/tmp/never_saved", learning_rate=lr, optimizer="adam",
+        log_steps=10**9, seed=weights.key_seed(seed), steps_per_call=1,
+    )
+    est = Estimator(built["model"], built["flow"], cfg)
+    est.params = weights.nest(weights.make_params(spec, seed))
+    with jax.default_matmul_precision("highest"):
+        losses = est.train(1, log=False, save=False)
+        adam = next(s for s in est.opt_state if hasattr(s, "mu"))
+        grad = {
+            k: float(v) / 0.1
+            for k, v in weights.leaf_norms(weights.flatten(adam.mu)).items()
+        }
+        # a profiler session, whoever started it: each step dispatched
+        # under it keeps the model's metric, on the device, in its span
+        with jax.profiler.trace(str(tmp_path)):
+            losses += est.train(2, log=False, save=False)
+    from euler_tpu.utils import trace
+
+    mine = [s for s in trace.spans() if s.name == "train.dispatch"][-3:]
+    assert "metric" not in mine[0].args  # step 0 ran under no session
+    kept = [s for s in mine if "metric" in s.args]
+    assert [(s.name, s.step) for s in kept] == [("train.dispatch", 1), ("train.dispatch", 2)]
+    held = config["model"]["experts_here"][1] / config["model"]["router_experts"]
+    for s in kept:
+        assert 0.5 * held < float(s.args["metric"]) < 2.0 * held
+    change = weights.change_norms(
+        weights.flatten(est.params), weights.make_params(spec, seed)
+    )
+    got = {
+        "loss": losses, "grad_norm": grad,
+        "change_norm": {k: float(v) for k, v in change.items()},
+    }
+    tables, loss_fn = bench["ref"].make(config, {}, graph)
+    want = train.first_steps(loss_fn, tables, spec, seed, lr)
+    assert set(got["grad_norm"]) == set(want["grad_norm"])  # one tree, leaf for leaf
+    compared = train.compare(got, want)
+    assert all(v < 1e-4 for v in compared.values()), compared
+    # the experts' sum left out is another model: the comparison sees it
+    broken = train.first_steps(loss_fn, tables, spec, seed, lr, fault="no_routed")
+    assert max(train.compare(broken, want).values()) > 1e-2
+
+
+# -- (e) the device flow ------------------------------------------------------
+
+
+def test_device_sequence_flow_draws_the_reference_walks(bench, config):
+    m = config["model"]
+    graph, built = _built(bench, config)
+    flow = built["flow"]
+    tables, _ = bench["ref"].make(config, {}, graph)
+    sample = jax.jit(flow.sample)
+    drawn = []
+    for step in (0, 1):
+        key = bench["train"].step_key(7, step)
+        ids = np.asarray(sample(key))
+        want = bench["ref"].sequences(
+            tables, key, graph["num_nodes"], m["batch_size"], m["seq_len"], m["doc_len"]
+        )
+        assert ids.shape == (m["batch_size"], m["seq_len"] + 1) and ids.dtype == np.int32
+        np.testing.assert_array_equal(ids, np.asarray(want))
+        assert ids.min() >= 0 and ids.max() < config["vocab_size"]
+        drawn.append(ids)
+    assert not np.array_equal(drawn[0], drawn[1])
+    # a document is a walk: each token is an out-neighbour of the one before
+    ids, doc = drawn[0], m["doc_len"]
+    for t in range(1, doc):
+        nbrs = graph["dst"][graph["indptr"][ids[0, t - 1]] : graph["indptr"][ids[0, t - 1] + 1]]
+        assert ids[0, t] in nbrs
+
+
+def test_sequence_length_must_hold_whole_documents(bench, config):
+    from euler_tpu.dataflow import DeviceSequenceFlow
+
+    graph = bench["graphs"].build(config["graph"])
+    with pytest.raises(ValueError, match="whole number of documents"):
+        DeviceSequenceFlow(
+            bench["program_graph"](graph, {}), batch_size=2, seq_len=60, doc_len=16
+        )
