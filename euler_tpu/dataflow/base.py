@@ -4,8 +4,8 @@ The reference's `DataFlow`/`Block` abstraction (tf_euler/python/dataflow/
 base_dataflow.py:23-52) builds *dynamic* subgraphs with `tf.unique`; XLA needs
 static shapes, so the TPU design pads instead (SURVEY.md §7): hop i holds
 exactly batch * prod(fanouts[:i]) node slots, invalid slots carry a mask, and
-every downstream op is a fixed-shape gather/segment op — fusable by XLA and
-trivially shardable along the batch axis of a device mesh.
+every downstream op is a fixed-shape gather, segment or window reduce —
+fusable by XLA and trivially shardable along the batch axis of a device mesh.
 
 A `Block` is the bipartite edge set between hop i+1 ("src", the sampled
 neighbors) and hop i ("dst"); node tables are per-hop feature matrices.
@@ -30,9 +30,13 @@ class Block:
     mask: Array  # bool[E] valid-edge mask
     n_src: int = flax.struct.field(pytree_node=False)
     n_dst: int = flax.struct.field(pytree_node=False)
-    # >0 when edges are grid-structured (dst row i owns slots [i*g, (i+1)*g));
-    # unlocks the fused Pallas gather+reduce path
+    # >0 when edges are grid-structured (dst row i owns slots [i*g, (i+1)*g),
+    # i.e. edge_dst[e] == e // g): convs then aggregate by summing each run
+    # of g consecutive message rows (ops.grid_add), with no index traffic
     grid: int = flax.struct.field(pytree_node=False, default=0)
+    # True when edge_src is arange(n_src): src row e IS edge e's message
+    # (sampled fanout), so a conv's message step is x_src itself, not a gather
+    src_in_order: bool = flax.struct.field(pytree_node=False, default=False)
     # optional TRUE graph degrees (f32, self-loop not included): full-graph
     # degrees of the src/dst hop's nodes, for exact GCN symmetric
     # normalization in full-neighbor/whole-graph flows (the reference
@@ -215,6 +219,7 @@ def fanout_block(
         n_src=e,
         n_dst=batch,
         grid=fanout,
+        src_in_order=True,
     )
 
 
@@ -288,6 +293,7 @@ def hydrate_blocks(batch: MiniBatch) -> MiniBatch:
                 edge_dst=jnp.repeat(
                     jnp.arange(b.n_dst, dtype=jnp.int32), b.grid
                 ),
+                src_in_order=True,
             )
         blocks.append(b)
     if masks is batch.masks and all(

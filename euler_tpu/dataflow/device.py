@@ -1072,7 +1072,7 @@ class DeviceSageFlow(DeviceGraphTables):
             blocks.append(
                 Block(
                     edge_src=None, edge_dst=None, edge_w=ew, mask=None,
-                    n_src=width * k, n_dst=width, grid=k,
+                    n_src=width * k, n_dst=width, grid=k, src_in_order=True,
                 )
             )
             feats.append(nbr)

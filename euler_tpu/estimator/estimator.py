@@ -384,7 +384,9 @@ def _first_call(program: str, tables: dict):
     lowering, cache fetch or compile — each one child span, from the
     first start to the last end of its kind, since traces nest — and
     what remains is the first run. `table_arg_bytes` is what went in as
-    the `tables` argument rather than as constants of the executable."""
+    the `tables` argument rather than as constants of the executable;
+    `agg_grid` / `agg_scatter` count the aggregations the program's convs
+    traced in each form (`layers/conv.py:Conv.agg_add`)."""
     table_arg_bytes = sum(
         leaf.nbytes for leaf in jax.tree_util.tree_leaves(tables)
     )
@@ -402,7 +404,11 @@ def _first_call(program: str, tables: dict):
         with trace.span(
             "step.first_call", program=program, table_arg_bytes=table_arg_bytes
         ) as call:
+            before = trace.counts()
             yield
+            after = trace.counts()
+            for form in ("agg_grid", "agg_scatter"):
+                call.args[form] = after.get(form, 0) - before.get(form, 0)
             for kind, (lo, hi) in parts.items():
                 call.child(f"step.first_call.{kind}", lo, hi, program=program)
     finally:

@@ -2,8 +2,12 @@
 
 PyG-style conv contract from the reference (tf_euler/python/convolution/
 conv.py:27-53): a conv consumes (x_dst, x_src, block) and produces new dst
-embeddings. All aggregation is masked segment ops (euler_tpu.ops), which XLA
-fuses with the layer matmuls on the MXU; shapes are static.
+embeddings. Shapes are static and aggregation is a masked sum over each dst
+row's edges (euler_tpu.ops), in the cheapest form the block allows: a grid
+block (fixed fanout) sums each run of `grid` consecutive message rows, and
+where its sources are in order the messages are x_src itself — no gather,
+no scatter; any other block (relation, COO) gathers by edge_src and
+segment-sums by edge_dst.
 
 Layers mirror tf_euler/python/convolution/: GCNConv (gcn_conv.py:32-54),
 SAGEConv, GATConv, GINConv, GraphConv, APPNPConv, SGCNConv, TAGConv,
@@ -17,13 +21,21 @@ import jax
 import jax.numpy as jnp
 
 from euler_tpu.dataflow.base import Block
-from euler_tpu.ops import gather, scatter_add, scatter_softmax
+from euler_tpu.ops import gather, grid_add, scatter_add, scatter_softmax
+from euler_tpu.utils import trace
 
 
-def degrees(block: Block, with_self: bool = True) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """(deg_dst, deg_src_per_edge) computed from the block mask."""
-    ones = block.mask.astype(jnp.float32)
-    deg_dst = scatter_add(ones, block.edge_dst, block.n_dst)
+def edge_count(block: Block) -> jnp.ndarray:
+    """f32[n_dst]: each dst row's valid edges, from the block mask."""
+    ones = jnp.asarray(block.mask, jnp.float32)
+    if block.grid:
+        return grid_add(ones, block.grid)
+    return scatter_add(ones, block.edge_dst, block.n_dst)
+
+
+def degrees(block: Block, with_self: bool = True) -> jnp.ndarray:
+    """In-batch degree of each dst row (+1 for the implicit self loop)."""
+    deg_dst = edge_count(block)
     if with_self:
         deg_dst = deg_dst + 1.0
     return deg_dst
@@ -40,9 +52,18 @@ class Conv(nn.Module):
     dtype: object = None
 
     def msg(self, x_src, block: Block):
+        if block.src_in_order:
+            return x_src
         return gather(x_src, block.edge_src)
 
     def agg_add(self, msgs, block: Block):
+        """Masked sum of each dst row's messages. The form is chosen from
+        what the block says of itself and tallied (`agg_grid` /
+        `agg_scatter`; the program's `step.first_call` span carries both)."""
+        if block.grid:
+            trace.count("agg_grid")
+            return grid_add(msgs, block.grid, mask=block.mask)
+        trace.count("agg_scatter")
         return scatter_add(msgs, block.edge_dst, block.n_dst, mask=block.mask)
 
 
@@ -82,9 +103,11 @@ class GCNConv(Conv):
 class SAGEConv(Conv):
     """GraphSAGE mean aggregator: W·[x_dst ‖ mean(x_src)] (sage_conv.py).
 
-    Grid-structured blocks can use the fused Pallas gather+reduce kernel
-    (mean = gather_weighted_sum with w = mask/deg), skipping the [E, F]
-    message tensor entirely.
+    By default the mean is the base class's masked sum over the block's
+    valid-edge count, which on grid blocks is a reduce over each row's
+    slots. With EULER_TPU_PALLAS set, grid blocks go through the fused
+    Pallas gather+reduce kernel instead (mean = gather_weighted_sum with
+    w = mask/deg).
     """
 
     use_bias: bool = True
@@ -106,15 +129,8 @@ class SAGEConv(Conv):
             mean = gather_weighted_sum(x_src, slots, w, impl)
             mean = mean.astype(x_dst.dtype)
         else:
-            msgs = self.msg(x_src, block)
-            total = self.agg_add(msgs, block)
-            count = scatter_add(
-                jnp.ones(block.edge_src.shape[0], jnp.float32),
-                block.edge_dst,
-                block.n_dst,
-                mask=block.mask,
-            )
-            mean = total / jnp.maximum(count, 1.0)[:, None]
+            total = self.agg_add(self.msg(x_src, block), block)
+            mean = total / jnp.maximum(edge_count(block), 1.0)[:, None]
         h = jnp.concatenate([x_dst, mean], axis=-1)
         return nn.Dense(dtype=self.dtype, features=self.out_dim, use_bias=self.use_bias)(h)
 
@@ -380,13 +396,7 @@ class RelationConv(Conv):
         for r, block in enumerate(rel_blocks):
             msgs = self.msg(x_src, block) @ weights[r]
             total = self.agg_add(msgs, block)
-            cnt = scatter_add(
-                jnp.ones(block.edge_src.shape[0], jnp.float32),
-                block.edge_dst,
-                block.n_dst,
-                mask=block.mask,
-            )
-            out = out + total / jnp.maximum(cnt, 1.0)[:, None]
+            out = out + total / jnp.maximum(edge_count(block), 1.0)[:, None]
         return out
 
 

@@ -3,6 +3,7 @@ import os
 from euler_tpu.ops import mp_ops  # noqa: F401
 from euler_tpu.ops.mp_ops import (  # noqa: F401
     gather,
+    grid_add,
     scatter,
     scatter_add,
     scatter_max,

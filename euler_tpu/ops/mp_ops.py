@@ -2,8 +2,10 @@
 
 The TPU equivalent of the reference's MPGather/MPScatter* TF custom ops
 (tf_euler/python/euler_ops/mp_ops.py:27-79, tf_euler/kernels/scatter_op.cc).
-Everything is expressed over *static-shape* segment operations so XLA can fuse
-the gather → elementwise → segment-reduce chain into the surrounding matmuls.
+Everything is expressed over *static-shape* operations: segment ops driven by
+index vectors for irregular edge sets, and `grid_add` — a strided window
+reduce, no indices at all — where every segment is a run of equally many
+consecutive rows (fixed-fanout blocks).
 
 Padding convention: dataflows route padded edges to valid-looking indices and
 pass `mask`; masked lanes contribute the reduction identity (0 for add/mean,
@@ -17,8 +19,11 @@ Gradient parity with the reference:
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Array = jax.Array
 
@@ -45,6 +50,65 @@ def scatter_add(
     return jax.ops.segment_sum(
         _masked(data, mask, 0), segment_ids, num_segments=num_segments
     )
+
+
+_SUBLANES = 8  # rows of a float32 tile on the TPU
+
+
+def _repeat_rows(x: Array, k: int) -> Array:
+    """`jnp.repeat(x, k, axis=0)`, bit for bit. Where the rows come in
+    whole 8-row tiles it is written as a product with a 0/1 matrix over
+    blocks of 8 rows -> 8k rows: both reshapes are then free on the TPU
+    and the copy runs on the MXU (each output row has one term, and at
+    `highest` a float32 times 1.0 is exact), where broadcast + reshape
+    goes through a padded relayout that takes twice as long (PERF.md,
+    PR 29)."""
+    n = x.shape[0]
+    if n % _SUBLANES:
+        return jnp.repeat(x, k, axis=0)
+    rows = np.arange(_SUBLANES * k)
+    spread = np.zeros((_SUBLANES * k, _SUBLANES), x.dtype)
+    spread[rows, rows // k] = 1
+    out = jnp.einsum(
+        "rs,bsf->brf",
+        spread,
+        x.reshape(n // _SUBLANES, _SUBLANES, -1),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    return out.reshape((n * k,) + x.shape[1:])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _run_sum(data: Array, grid: int) -> Array:
+    window = (grid,) + (1,) * (data.ndim - 1)
+    zero = np.zeros((), data.dtype)
+    return jax.lax.reduce_window(data, zero, jax.lax.add, window, window, "VALID")
+
+
+def _run_sum_fwd(data, grid):
+    return _run_sum(data, grid), None
+
+
+def _run_sum_bwd(grid, _, g):
+    return (_repeat_rows(g, grid),)
+
+
+# The transpose JAX derives for this window is a base-dilated reduce-window,
+# which XLA's TPU backend gets wrong at f32[153600 x 5, 128] (gradients off
+# by 1.5 times their largest entry against segment_sum; right on the CPU and
+# at the smaller blocks: my chip run, PR 29). The transpose of "sum each run
+# of k rows" is "repeat each row k times", so say that.
+_run_sum.defvjp(_run_sum_fwd, _run_sum_bwd)
+
+
+def grid_add(data: Array, grid: int, mask: Array | None = None) -> Array:
+    """`scatter_add` for segment ids `arange(len(data)) // grid`: row i of
+    the result sums rows [i*grid, (i+1)*grid) of `data`, in `data`'s dtype
+    like the scatter it stands in for. A strided window over the row axis
+    rather than `reshape(-1, grid, F).sum(1)`: the same sum, but the
+    reshape puts `grid` (5, 10, 15) on the TPU's 8-row tiles and XLA then
+    copies the whole operand into a padded layout first."""
+    return _run_sum(_masked(data, mask, 0), grid)
 
 
 def scatter_mean(

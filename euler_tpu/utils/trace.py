@@ -1,6 +1,6 @@
 """Names for the program's layers, in a profiler trace and in memory.
 
-Two things, used at the layer boundaries of the train path:
+Three things, used at the layer boundaries of the train path:
 
 - `scope(name)` for code that runs under `jit`: a `jax.named_scope`, so
   every HLO op traced inside carries `euler.<name>` in its `op_name`.
@@ -11,6 +11,8 @@ Two things, used at the layer boundaries of the train path:
   process-wide in-memory record, which is what sees set-up — that
   happens before any profiler session exists. `spans()` returns the
   record; whoever asks writes it out.
+- `count(name)` for a choice made at trace time (which form an
+  aggregation took): a tally, which `step.first_call` turns into args.
 
 The record is bounded: per-step spans evict the oldest of their kind,
 set-up spans (`stage.*`, `step.first_call*`) are kept apart so a long
@@ -46,6 +48,7 @@ class Span(NamedTuple):
 
 _steps: collections.deque = collections.deque(maxlen=MAX_SPANS)
 _setup: collections.deque = collections.deque(maxlen=MAX_SETUP_SPANS)
+_counts: collections.Counter = collections.Counter()
 _ids = itertools.count(1)
 _local = threading.local()
 
@@ -108,6 +111,19 @@ class span:
         """A finished stretch inside this span that was timed by someone
         else (a `jax.monitoring` duration): record only."""
         _record(name, start_ns, end_ns, self.id, next(_ids), args)
+
+
+def count(name: str) -> None:
+    """One more of `name` in the process-wide tally: for a choice code
+    makes while it is traced (once a program, not once a step), where a
+    span has nothing to time. Whoever opened the span around the tracing
+    reads `counts()` before and after and keeps the difference."""
+    _counts[name] += 1
+
+
+def counts() -> dict:
+    """The tally so far."""
+    return dict(_counts)
 
 
 def spans() -> list:
