@@ -118,8 +118,8 @@ def _measure_training(
     bf16,
     model_dir,
 ):
-    """Shared GraphSAGE measurement harness for both bench legs: pallas
-    auto, optional bf16 convs, prefetched K-step scan dispatch, timed
+    """Shared GraphSAGE measurement harness for both bench legs:
+    optional bf16 convs, prefetched K-step scan dispatch, timed
     steady-state window. Returns (edges_per_sec, edges_per_step)."""
     import jax
 
@@ -128,10 +128,6 @@ def _measure_training(
     from euler_tpu.estimator.prefetch import Prefetcher
     from euler_tpu.models import GraphSAGESupervised
 
-    if "EULER_TPU_PALLAS" not in os.environ:
-        from euler_tpu.ops import set_pallas
-
-        set_pallas("auto")
     conv_kwargs = None
     if bf16:
         import jax.numpy as jnp
@@ -226,9 +222,7 @@ def _paged_device_ab(smoke: bool) -> dict:
     graph (EULER_BENCH_PAGED=0 skips). Measures pure traced-sampling
     throughput — the quantity the layouts differ on — plus the standing
     bit-identity oracle (paged and dense draw the same batch from the
-    same key) and one check that the Pallas kernel entry points draw what
-    the jnp reference draws — compiled on the device, interpreted only
-    under --smoke."""
+    same key)."""
     import jax
 
     from euler_tpu.dataflow import DeviceSageFlow
@@ -277,47 +271,12 @@ def _paged_device_ab(smoke: bool) -> dict:
     dense_eps = max(measure(flows["dense"]), measure(flows["dense"]))
     paged_eps = max(measure(flows["paged"]), measure(flows["paged"]))
 
-    # the Pallas entry points must draw the same batch as the jnp
-    # reference. On the device they are COMPILED at the A/B's own shapes;
-    # only --smoke (CPU) runs them through the interpreter, at micro size
-    # (it emulates each DMA in Python — keep the draw count tiny)
-    from euler_tpu.ops import pallas_mode, set_pallas
-
-    key = jax.random.PRNGKey(3)
-    if smoke:
-        mode = "interpret"
-        kflow = DeviceSageFlow(
-            g, fanouts=[2], batch_size=8, layout="paged", max_degree=4096
-        )
-        draw = kflow.sample
-    else:
-        mode = "pallas"
-        kflow = flows["paged"]
-        # a fresh function: the pallas mode is read at trace time and is
-        # not part of jit's cache key, so the reference's trace of
-        # kflow.sample must not be reused
-        draw = jax.jit(lambda k: kflow.sample(k))
-    ref = jax.jit(kflow.sample)(key)
-    prev = pallas_mode()
-    set_pallas(mode)
-    try:
-        ker = draw(key)
-    finally:
-        set_pallas(prev)
-    kernels_ok = all(
-        np.array_equal(np.asarray(a), np.asarray(b))
-        for a, b in zip(
-            jax.tree_util.tree_leaves(ref), jax.tree_util.tree_leaves(ker)
-        )
-    )
     return {
         "paged": True,
         "paged_sample_edges_per_sec": round(paged_eps, 1),
         "dense_sample_edges_per_sec": round(dense_eps, 1),
         "paged_over_dense": round(paged_eps / max(dense_eps, 1e-9), 3),
         "paged_bit_identical": bool(identical),
-        "paged_kernels_ok": bool(kernels_ok),
-        "paged_kernels_mode": mode,
         "paged_hub_degree": int(flows["paged"].max_deg),
         "page_size": int(flows["paged"].page_size),
     }

@@ -18,8 +18,6 @@ repo's main paths once through the entry points a user calls:
            ServingClient's predictions must equal offline Estimator.infer
            bit for bit. Then tools.serve.selftest (concurrent clients,
            coalescing and the durability probe, at toy width)
-  kernels  every Pallas entry point, impl="pallas" (compiled, never the
-           interpreter) against its impl="xla" reference
   frontier the analytics f64 multiply against numpy, within the bound
            dataflow/device.py states for an emulated f64
   cache    the device-lane train step compiled a second time after
@@ -27,8 +25,8 @@ repo's main paths once through the entry points a user calls:
 
 Any phase that fails ends the run with a non-zero exit code and no result
 line. A passing run prints one `report: {...}` line (per-phase status,
-per-kernel status, cache directory and hits; its seconds are set-up times
-for the record, not metrics) and then, as the last stdout line, exactly
+cache directory and hits; its seconds are set-up times for the record,
+not metrics) and then, as the last stdout line, exactly
 
     {"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}
 
@@ -43,7 +41,6 @@ import os
 import sys
 import tempfile
 import time
-import traceback
 
 import numpy as np
 
@@ -57,13 +54,6 @@ WARM_DISPATCHES, MORE_DISPATCHES = 2, 3
 # served request sizes: one above the top bucket (chunked 128+128+44), one
 # in the middle bucket, one in the smallest
 SERVE_REQUESTS = (300, 20, 5)
-
-# gather_weighted_sum sums D products per output on the MXU while the
-# reference reduces on the VPU, so the two differ by f32 summation order
-# only: the inputs are bf16-representable, which makes every product exact
-# in f32 at any matmul precision. Bound: max |pallas - xla| over the
-# largest |xla| value. A dropped term or a bf16 accumulator is >= 1e-3.
-GWS_REL_TOL = 1e-5
 
 
 def phase_engine(workdir: str):
@@ -256,126 +246,6 @@ def phase_cache(flow, cache, workdir: str, log: _CacheLog) -> dict:
     }
 
 
-# -- kernels ---------------------------------------------------------------
-
-
-def _bf16_exact(a: np.ndarray) -> np.ndarray:
-    import jax.numpy as jnp
-
-    return np.asarray(jnp.asarray(a, jnp.bfloat16).astype(jnp.float32))
-
-
-def _kernel_cases():
-    """(name, fn(impl) -> array, compare(out, ref) -> error-or-None) at the
-    shapes the trainers above and ROADMAP R1/R2 produce."""
-    import jax
-    import jax.numpy as jnp
-
-    from euler_tpu.ops import pallas_kernels as pk
-
-    rng = np.random.default_rng(7)
-
-    def bitwise(out, ref):
-        if out.shape != ref.shape or out.dtype != ref.dtype:
-            return f"shape/dtype {out.shape}/{out.dtype} vs {ref.shape}/{ref.dtype}"
-        bad = int((out != ref).sum())
-        return f"{bad} of {out.size} values differ" if bad else None
-
-    def close(out, ref):
-        if out.shape != ref.shape:
-            return f"shape {out.shape} vs {ref.shape}"
-        if not np.isfinite(out).all():
-            return "non-finite values"
-        err = float(np.abs(out - ref).max() / np.abs(ref).max())
-        return f"rel err {err:.2e} > {GWS_REL_TOL}" if err > GWS_REL_TOL else None
-
-    cases = []
-    # gather_weighted_sum: inside auto's region, lane-padded, chunked
-    for f, n_dst, d in ((128, 10_240, 10), (64, 10_240, 10), (602, 5_120, 25)):
-        n_src = 20_000
-        x = jnp.asarray(_bf16_exact(rng.normal(size=(n_src, f))))
-        slots = jnp.asarray(rng.integers(0, n_src, (n_dst, d)), jnp.int32)
-        w = jnp.asarray(_bf16_exact(rng.random((n_dst, d))))
-        cases.append((
-            f"gather_weighted_sum[F={f},n_dst={n_dst},D={d}]",
-            lambda impl, x=x, slots=slots, w=w: jax.jit(
-                lambda x, s, w: pk.gather_weighted_sum(x, s, w, impl)
-            )(x, slots, w),
-            close,
-        ))
-
-    # paged kernels: page size 16, k = 10 draws, a few thousand rows
-    page_size, k, rows, n_pages = 16, 10, 4096, 4096
-    n_flat = n_pages * page_size
-    fidx = jnp.asarray(rng.integers(0, n_flat, (rows, k)), jnp.int32)
-    for dtype in (np.int32, np.float32):
-        flat = jnp.asarray(rng.integers(0, 1 << 20, n_flat).astype(dtype))
-        t2d = pk._as_lane_rows(flat)
-        cases.append((
-            f"paged_gather[{np.dtype(dtype).name},rows={rows},k={k}]",
-            lambda impl, t2d=t2d: jax.jit(
-                lambda t, i: pk.paged_gather(t, i, impl)
-            )(t2d, fidx),
-            bitwise,
-        ))
-    packed = pk._as_lane_rows(
-        pk.pack_bf16_words(jnp.asarray(rng.normal(size=n_flat), jnp.float32))
-    )
-    cases.append((
-        f"paged_gather_dequant[rows={rows},k={k}]",
-        lambda impl: jax.jit(
-            lambda t, i: pk.paged_gather_dequant(t, i, impl)
-        )(packed, fidx),
-        bitwise,
-    ))
-    # per-page ascending quantized CDF, as DeviceGraphTables stages it
-    q = np.sort(
-        rng.integers(0, 1 << 32, (n_pages, page_size), dtype=np.uint64), axis=1
-    ).astype(np.uint32)
-    q2d = pk._as_lane_rows(jnp.asarray(q.reshape(-1)))
-    page = jnp.asarray(rng.integers(0, n_pages, (rows, k)), jnp.int32)
-    rbits = jnp.asarray(
-        rng.integers(0, 1 << 32, (rows, k), dtype=np.uint64).astype(np.uint32)
-    )
-    cases.append((
-        f"paged_cdf_count[page={page_size},rows={rows},k={k}]",
-        lambda impl: jax.jit(
-            lambda q, p, r: pk.paged_cdf_count(q, p, r, page_size, impl)
-        )(q2d, page, rbits),
-        bitwise,
-    ))
-
-    return cases
-
-
-def phase_kernels() -> dict:
-    """One line per kernel: compiled / refused (Mosaic's first line) /
-    mismatch. The per-kernel except exists so one run names every kernel
-    that fails; any failure still fails the phase, and with it the run."""
-    status = {}
-    for name, fn, compare in _kernel_cases():
-        ref = np.asarray(fn("xla"))
-        try:
-            t0 = time.perf_counter()
-            out = np.asarray(fn("pallas"))
-            compile_s = time.perf_counter() - t0
-        except Exception as e:  # report, finish the table, then fail
-            traceback.print_exc()
-            lines = [ln for ln in str(e).splitlines() if ln.strip()]
-            first = lines[0] if lines else ""
-            status[name] = f"refused: {type(e).__name__}: {first[:200]}"
-        else:
-            err = compare(out, ref)
-            status[name] = (
-                f"mismatch: {err}" if err else f"compiled ({compile_s:.1f}s)"
-            )
-        print(f"kernel {name}: {status[name]}", flush=True)
-    bad = [n for n, s in status.items() if not s.startswith("compiled")]
-    if bad:
-        raise SystemExit(f"chip_smoke: kernel phase failed: {bad}")
-    return {"ok": True, "kernels": status}
-
-
 def phase_server(graph, cache, est) -> dict:
     """Serve what the device lane trained, at full width. The flow samples,
     so a prediction is replayable only from the same Generator state: the
@@ -539,7 +409,6 @@ def main() -> int:
         done("trainer_host_lane", phase_host_lane(graph, cache, workdir))
         done("server", phase_server(graph, cache, est))
         done("serve_selftest", phase_serve_selftest())
-        done("kernels", phase_kernels())
         done("frontier_f64", phase_frontier())
         done("compile_cache", phase_cache(flow, cache, workdir, cache_log))
 

@@ -1,6 +1,6 @@
 """euler_tpu — a TPU-native graph learning framework.
 
-A brand-new JAX/XLA/Pallas implementation with the capabilities of Euler 2.0
+A brand-new JAX/XLA implementation with the capabilities of Euler 2.0
 (reference: /root/reference — see SURVEY.md). The host side is a columnar,
 shardable property-graph store with weighted sampling and batch query APIs
 (reference parity surface: euler/core/api/api.h:44-92 plus the tf_euler op set);

@@ -35,10 +35,8 @@ Memory — two layouts:
   (`page_start`), so a hub node spans ⌈deg/P⌉ pages instead of widening
   every row: HBM ∝ edges (+ N·4 B of page table), no `max_degree`
   failure mode. The access shape is the Ragged Paged Attention
-  indirection (PAPERS.md, arxiv 2604.15464); the page reads run through
-  the `paged_gather`/`paged_cdf_count` entry points in
-  ops/pallas_kernels.py (Pallas on request, jitted jnp reference as the
-  `auto` fallback and A/B oracle).
+  indirection (PAPERS.md, arxiv 2604.15464); the page reads are the
+  `paged_gather`/`paged_cdf_count` functions of ops/paged_ops.py.
 
 `layout="auto"` (the default) picks dense when the graph's max degree
 fits `max_degree` and paged otherwise, for the SAGE-family flows;
@@ -462,7 +460,7 @@ class DeviceGraphTables(StagedTables):
         into fixed-size pages in one flat buffer; per-node page table in
         `page_start`. HBM ∝ edges — no max_degree failure mode."""
         from euler_tpu.distributed.codec import page_dtype
-        from euler_tpu.ops.pallas_kernels import (
+        from euler_tpu.ops.paged_ops import (
             PAGE_LANES,
             _as_lane_rows,
             pack_bf16_words,
@@ -471,8 +469,8 @@ class DeviceGraphTables(StagedTables):
         P = int(page_size)
         if P <= 0 or PAGE_LANES % P:
             raise ValueError(
-                f"page_size must divide {PAGE_LANES} (one page per DMA "
-                f"lane row); got {P}"
+                f"page_size must divide {PAGE_LANES} (a page may not "
+                f"straddle a row of the staged buffer); got {P}"
             )
         n = len(ids)
         deg = np.zeros(n + 1, dtype=np.int32)
@@ -706,7 +704,7 @@ class DeviceGraphTables(StagedTables):
                 # start word-aligned with even length: pack the patch
                 # values pairwise and rewrite whole u32 words — no
                 # read-modify-write of half-covered words can occur
-                from euler_tpu.ops.pallas_kernels import pack_bf16_words
+                from euler_tpu.ops.paged_ops import pack_bf16_words
 
                 words = pack_bf16_words(wv)
                 wdest = dest[0::2] // 2
@@ -727,21 +725,6 @@ class DeviceGraphTables(StagedTables):
                 jnp.asarray(qv.reshape(-1, P).max(axis=1))
             )
         return len(rows)
-
-    @property
-    def _kimpl(self) -> str:
-        """Paged-kernel impl derived from the global pallas mode: 'off'
-        rides the jitted jnp reference, 'interpret'/'pallas' are the
-        explicit kernel forms, 'auto' defers to the kernels' own auto
-        (currently the reference — ops/pallas_kernels.py _paged_impl)."""
-        from euler_tpu.ops import pallas_mode
-
-        mode = pallas_mode()
-        if mode == "off":
-            return "xla"
-        if mode in ("interpret", "pallas"):
-            return mode
-        return "auto"
 
     def _stage_nodes(
         self, graph, ids, wn, nt, roots_pool, root_node_type: int
@@ -956,9 +939,8 @@ class DeviceGraphTables(StagedTables):
         """Paged twin of _draw_neighbors: two-level quantized-CDF
         inversion (page-boundary binary search + in-page count) and
         neighbor/weight gathers through the page indirection — identical
-        integers to the dense inversion, different memory layout. The
-        page reads route through ops/pallas_kernels entry points."""
-        from euler_tpu.ops.pallas_kernels import (
+        integers to the dense inversion, different memory layout."""
+        from euler_tpu.ops.paged_ops import (
             paged_cdf_count,
             paged_gather,
             paged_gather_dequant,
@@ -969,7 +951,6 @@ class DeviceGraphTables(StagedTables):
         deg = self.deg[cur]
         ps = self.page_start[cur]
         P = self.page_size
-        impl = self._kimpl
         if self.unit_w:
             u = jax.random.uniform(key, (width, k))
             idx = (u * deg[:, None]).astype(jnp.int32)
@@ -982,13 +963,13 @@ class DeviceGraphTables(StagedTables):
             )
             pgc = jnp.minimum(pg, jnp.maximum(npages[:, None] - 1, 0))
             page = jnp.minimum(ps[:, None] + pgc, self._page_cap)
-            cnt = paged_cdf_count(self.page_q2d, page, r, P, impl=impl)
+            cnt = paged_cdf_count(self.page_q2d, page, r, P)
             idx = pgc * P + cnt
         idx = jnp.minimum(idx, jnp.maximum(deg[:, None] - 1, 0))
         fidx = jnp.minimum(ps[:, None] * P + idx, self._slot_cap)
         nbr = jnp.where(
             deg[:, None] > 0,
-            paged_gather(self.pages2d, fidx, impl=impl),
+            paged_gather(self.pages2d, fidx),
             0,
         ).reshape(-1)
         if not self.unit_w:
@@ -996,9 +977,9 @@ class DeviceGraphTables(StagedTables):
             # DMA bytes); the trailing bf16 cast below makes the packed
             # and f32 planes emit bit-identical weights either way
             wvals = (
-                paged_gather_dequant(self.page_w2d, fidx, impl=impl)
+                paged_gather_dequant(self.page_w2d, fidx)
                 if getattr(self, "_page_w_packed", False)
-                else paged_gather(self.page_w2d, fidx, impl=impl)
+                else paged_gather(self.page_w2d, fidx)
             )
             ew = (
                 jnp.where(deg[:, None] > 0, wvals, 0.0)

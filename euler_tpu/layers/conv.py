@@ -103,34 +103,16 @@ class GCNConv(Conv):
 class SAGEConv(Conv):
     """GraphSAGE mean aggregator: W·[x_dst ‖ mean(x_src)] (sage_conv.py).
 
-    By default the mean is the base class's masked sum over the block's
-    valid-edge count, which on grid blocks is a reduce over each row's
-    slots. With EULER_TPU_PALLAS set, grid blocks go through the fused
-    Pallas gather+reduce kernel instead (mean = gather_weighted_sum with
-    w = mask/deg).
+    The mean is the base class's masked sum over the block's valid-edge
+    count, which on grid blocks is a reduce over each row's slots.
     """
 
     use_bias: bool = True
 
     @nn.compact
     def __call__(self, x_dst, x_src, block: Block):
-        from euler_tpu.ops import pallas_mode
-
-        mode = pallas_mode()
-        if block.grid and mode != "off":
-            d = block.grid
-            m = block.mask.reshape(-1, d).astype(jnp.float32)
-            w = m / jnp.maximum(m.sum(axis=1, keepdims=True), 1.0)
-            slots = block.edge_src.reshape(-1, d)
-            from euler_tpu.ops import gather_weighted_sum
-
-            # honor an explicit 'pallas' request (no silent XLA fallback)
-            impl = {"auto": "auto", "pallas": "pallas"}.get(mode, "interpret")
-            mean = gather_weighted_sum(x_src, slots, w, impl)
-            mean = mean.astype(x_dst.dtype)
-        else:
-            total = self.agg_add(self.msg(x_src, block), block)
-            mean = total / jnp.maximum(edge_count(block), 1.0)[:, None]
+        total = self.agg_add(self.msg(x_src, block), block)
+        mean = total / jnp.maximum(edge_count(block), 1.0)[:, None]
         h = jnp.concatenate([x_dst, mean], axis=-1)
         return nn.Dense(dtype=self.dtype, features=self.out_dim, use_bias=self.use_bias)(h)
 
@@ -178,40 +160,16 @@ class GATConv(Conv):
         a_dst = jnp.einsum("nhp,hp->nh", hd, att_d.astype(hd.dtype))
         e = gather(a_src, block.edge_src) + gather(a_dst, block.edge_dst)
         e = nn.leaky_relu(e, self.negative_slope)  # [E, heads]
-        from euler_tpu.ops import pallas_mode
-
-        mode = pallas_mode()
-        if block.grid and mode != "off" and self.heads == 1:
-            # fused segment-softmax family: attention logits are per-edge
-            # SCALARS (a_src·h per node, gathered), so the softmax is a
-            # cheap [n_dst, grid] op and the only [E, F]-sized work — the
-            # value gather + weighted reduce — runs in the fused DMA
-            # kernel. No [E, F] message tensor is ever materialized.
-            d = block.grid
-            e2 = e.reshape(-1, d)
-            m2 = block.mask.reshape(-1, d)
-            e2 = jnp.where(m2, e2, -1e9)
-            alpha = jax.nn.softmax(e2, axis=1) * m2.astype(e2.dtype)
-            from euler_tpu.ops import gather_weighted_sum
-
-            impl = {"auto": "auto", "pallas": "pallas"}.get(mode, "interpret")
-            out = gather_weighted_sum(
-                h_src.astype(jnp.float32),
-                block.edge_src.reshape(-1, d),
-                alpha.astype(jnp.float32),
-                impl,
-            ).astype(h_dst.dtype)
-        else:
-            alpha = scatter_softmax(
-                e, block.edge_dst, block.n_dst, mask=block.mask
-            )  # [E, heads]
-            msgs = gather(hs, block.edge_src) * alpha[:, :, None]
-            out = self.agg_add(
-                msgs.reshape(-1, total), block
-            ).reshape(-1, self.heads, per)
-            out = (
-                out.reshape(-1, total) if self.concat else out.mean(axis=1)
-            )
+        alpha = scatter_softmax(
+            e, block.edge_dst, block.n_dst, mask=block.mask
+        )  # [E, heads]
+        msgs = gather(hs, block.edge_src) * alpha[:, :, None]
+        out = self.agg_add(
+            msgs.reshape(-1, total), block
+        ).reshape(-1, self.heads, per)
+        out = (
+            out.reshape(-1, total) if self.concat else out.mean(axis=1)
+        )
         if not self.improved:
             return out
         skip = h_dst if self.concat else hd.mean(axis=1)

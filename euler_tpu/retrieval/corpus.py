@@ -5,14 +5,14 @@ A corpus is one embedding table snapshot, loaded from a retained trainer
 / KG checkpoint (training/checkpoint.py COMMIT discipline: only
 complete, fsync'd checkpoints are ever visible) and frozen: rows sorted
 by id ascending, the vector block padded to the paged lane-row layout
-the TPU kernels consume (ops/pallas_kernels.py PAGE_LANES), plus an
+the device scorer reads (ops/paged_ops.py PAGE_LANES), plus an
 optional per-row attribute column set so DNF conditions — the SAME
 condition algebra the graph shards serve (graph/index.py) — compile to
 candidate masks for filtered retrieval.
 
 Bit-reproducibility canon (PARITY.md "Retrieval scoring"): every float
-derived here is defined operation-by-operation so the NumPy oracle, the
-jitted scorer, and the Pallas kernel agree bitwise —
+derived here is defined operation-by-operation so the NumPy oracle and
+the jitted scorer agree bitwise —
 
   * cosine normalization: nrm2 accumulates x[d]*x[d] STRICTLY
     left-to-right in f32; rows scale by f32(1/sqrt(nrm2)) elementwise
@@ -57,7 +57,7 @@ from euler_tpu.graph.index import (
 # graph/store.py DEFAULT_ID — one invalid-id vocabulary repo-wide)
 INVALID_ID = np.uint64(0xFFFFFFFFFFFFFFFF)
 
-PAGE_LANES = 128  # ops/pallas_kernels.py lane-row width
+PAGE_LANES = 128  # ops/paged_ops.py lane-row width
 
 
 def pad_dim(d: int) -> int:
@@ -292,7 +292,7 @@ class EmbeddingCorpus:
 
     def lane_rows(self) -> np.ndarray:
         """[M, 128] lane-row view of the flat vector block — the paged
-        HBM staging shape (ops/pallas_kernels.py `_as_lane_rows` twin,
+        HBM staging shape (ops/paged_ops.py `_as_lane_rows` twin,
         host-side)."""
         flat = self.vectors.reshape(-1)
         pad = (-flat.shape[0]) % PAGE_LANES

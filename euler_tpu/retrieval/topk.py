@@ -4,7 +4,7 @@ retrieval serving.
 `TopKIndex` stages one (immutable) corpus's lane-row table on device and
 answers masked dot/cosine top-K through jitted bucket-padded programs:
 one compiled program per (query-bucket, k) pair, reused across requests,
-scored by `paged_topk_score` (ops/pallas_kernels.py, plain XLA).
+scored by `paged_topk_score` (ops/paged_ops.py).
 
 Bit-determinism contract (PARITY.md "Retrieval scoring"):
 
@@ -77,7 +77,7 @@ class TopKIndex:
             import jax
             import jax.numpy as jnp
 
-            from euler_tpu.ops.pallas_kernels import paged_topk_score
+            from euler_tpu.ops.paged_ops import paged_topk_score
 
             n, dp = self._n, self._dp
 

@@ -79,13 +79,10 @@ def test_bench_smoke_emits_final_json_line():
     assert "vs_baseline" in row and "backend" in row
     assert row["device_flow"] is True  # smoke covers the production default
     # the paged device-lane A/B (ISSUE 6) must not silently vanish: the
-    # skewed weighted graph records paged vs dense sampling throughput,
-    # the standing bit-identity oracle, and the interpret-mode kernel
-    # validation, all on the artifact
+    # skewed weighted graph records paged vs dense sampling throughput
+    # and the standing bit-identity oracle on the artifact
     assert row["paged"] is True, row
     assert row["paged_bit_identical"] is True
-    assert row["paged_kernels_ok"] is True
-    assert row["paged_kernels_mode"] == "interpret"  # --smoke is the CPU run
     assert row["paged_sample_edges_per_sec"] > 0
     assert row["dense_sample_edges_per_sec"] > 0
     assert row["paged_over_dense"] > 0

@@ -5,6 +5,7 @@ the conv stack lowers to, and the tally `step.first_call` carries."""
 
 import re
 import time
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -38,47 +39,47 @@ BLOCKS = ("lazy_fanout", "shipped_fanout", "whole_graph", "empty_row")
 REHEARSE = {"dims": [32, 32, 32], "fanouts": [4, 3, 2], "batch": 64, "enc": 16}
 
 
-def _mask(rng, empty_row=False):
-    mask = rng.random((N_DST, K)) > 0.3
+def _mask(rng, n_dst, empty_row=False):
+    mask = rng.random((n_dst, K)) > 0.3
     mask[0, :2] = True  # no row empties by chance
     if empty_row:
         mask[2] = False
     return mask
 
 
-def _block(kind, rng):
+def _block(kind, rng, n_dst=N_DST):
     """One grid block of each kind the repo makes, and its src width."""
-    e = N_DST * K
-    w = rng.random((N_DST, K)).astype(np.float32)
+    e = n_dst * K
+    w = rng.random((n_dst, K)).astype(np.float32)
     if kind == "lazy_fanout":
         # as the lean wire ships it: no ids, no mask; hydrated on device
-        mask = _mask(rng)
-        lazy = fanout_block(N_DST, K, w, None, lazy=True, ship_mask=False)
+        mask = _mask(rng, n_dst)
+        lazy = fanout_block(n_dst, K, w, None, lazy=True, ship_mask=False)
         batch = MiniBatch(
-            feats=(np.zeros((N_DST, F), np.float32), np.zeros((e, F), np.float32)),
-            masks=(np.ones(N_DST, bool), mask.reshape(-1)),
+            feats=(np.zeros((n_dst, F), np.float32), np.zeros((e, F), np.float32)),
+            masks=(np.ones(n_dst, bool), mask.reshape(-1)),
             blocks=(lazy,),
-            root_idx=np.zeros(N_DST, np.int32),
+            root_idx=np.zeros(n_dst, np.int32),
         )
         return hydrate_blocks(batch).blocks[0], e
     if kind == "shipped_fanout":
-        return fanout_block(N_DST, K, w, _mask(rng)), e
+        return fanout_block(n_dst, K, w, _mask(rng, n_dst)), e
     # whole-graph style (dataflow/whole.py): the src table is the dst
     # table, slots hold real neighbour rows, missing neighbours are masked
-    mask = _mask(rng, empty_row=kind == "empty_row").reshape(-1)
-    deg = rng.integers(1, 9, N_DST).astype(np.float32)
+    mask = _mask(rng, n_dst, empty_row=kind == "empty_row").reshape(-1)
+    deg = rng.integers(1, 9, n_dst).astype(np.float32)
     block = Block(
-        edge_src=np.where(mask, rng.integers(0, N_DST, e), 0).astype(np.int32),
-        edge_dst=np.repeat(np.arange(N_DST, dtype=np.int32), K),
+        edge_src=np.where(mask, rng.integers(0, n_dst, e), 0).astype(np.int32),
+        edge_dst=np.repeat(np.arange(n_dst, dtype=np.int32), K),
         edge_w=np.where(mask, w.reshape(-1), 0.0).astype(np.float32),
         mask=mask,
-        n_src=N_DST,
-        n_dst=N_DST,
+        n_src=n_dst,
+        n_dst=n_dst,
         grid=K,
         src_deg=deg,  # GCNConv's exact-normalisation branch
         dst_deg=deg,
     )
-    return block, N_DST
+    return block, n_dst
 
 
 def _scatter_oracle(block):
@@ -92,32 +93,93 @@ def _close(got, want, what):
     assert err <= 1e-6, f"{what}: {err:.3g} relative"
 
 
-@pytest.mark.parametrize("kind", BLOCKS)
-@pytest.mark.parametrize("conv", CONVS)
-def test_grid_form_equals_scatter_oracle(conv, kind):
-    rng = np.random.default_rng(100 * CONVS.index(conv) + BLOCKS.index(kind))
-    block, n_src = _block(kind, rng)
+def _vjp_on(layer, params, block, x_dst, x_src, cot):
+    def f(params, x_dst, x_src):
+        return layer.apply(params, x_dst, x_src, block)
+
+    out, vjp = jax.vjp(f, params, x_dst, x_src)
+    return out, vjp(cot)
+
+
+def _check_grid_equals_scatter(conv, kind, rng, n_dst=N_DST, f=F):
+    block, n_src = _block(kind, rng, n_dst)
     assert block.grid == K
     assert block.src_in_order == (kind in ("lazy_fanout", "shipped_fanout"))
-    x_dst = jnp.asarray(rng.normal(size=(N_DST, F)), jnp.float32)
-    x_src = jnp.asarray(rng.normal(size=(n_src, F)), jnp.float32)
-    layer = get_conv(conv)(out_dim=F)
+    x_dst = jnp.asarray(rng.normal(size=(n_dst, f)), jnp.float32)
+    x_src = jnp.asarray(rng.normal(size=(n_src, f)), jnp.float32)
+    layer = get_conv(conv)(out_dim=f)
     params = layer.init(jax.random.PRNGKey(1), x_dst, x_src, block)
-    cot = jnp.asarray(rng.normal(size=(N_DST, F)), jnp.float32)
-
-    def run(b):
-        def f(params, x_dst, x_src):
-            return layer.apply(params, x_dst, x_src, b)
-
-        out, vjp = jax.vjp(f, params, x_dst, x_src)
-        return out, vjp(cot)
-
-    out, grads = run(block)
-    want_out, want_grads = run(_scatter_oracle(block))
+    cot = jnp.asarray(rng.normal(size=(n_dst, f)), jnp.float32)
+    out, grads = _vjp_on(layer, params, block, x_dst, x_src, cot)
+    want_out, want_grads = _vjp_on(
+        layer, params, _scatter_oracle(block), x_dst, x_src, cot
+    )
     _close(out, want_out, "output")
     got_leaves, _ = jax.tree_util.tree_flatten_with_path(grads)
     for (path, g), w in zip(got_leaves, jax.tree_util.tree_leaves(want_grads)):
         _close(g, w, "gradient " + jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("kind", BLOCKS)
+@pytest.mark.parametrize("conv", CONVS)
+def test_grid_form_equals_scatter_oracle(conv, kind):
+    rng = np.random.default_rng(100 * CONVS.index(conv) + BLOCKS.index(kind))
+    _check_grid_equals_scatter(conv, kind, rng)
+
+
+@pytest.mark.parametrize("f", [64, 200, 256])
+def test_grid_form_equals_scatter_oracle_at_real_widths(f):
+    """Below, at twice and off the 128-lane width, on 13 dst rows (no
+    whole 8-row tile, so the transpose takes `jnp.repeat`) and on 24
+    (whole tiles: the 0/1 product)."""
+    for n_dst in (13, 24):
+        _check_grid_equals_scatter(
+            "sage", "shipped_fanout", np.random.default_rng(f + n_dst),
+            n_dst=n_dst, f=f,
+        )
+
+
+@pytest.mark.parametrize(
+    "conv,kind,form",
+    [
+        ("sage", "shipped_fanout", "grid"),  # messages are x_src itself
+        ("sage", "whole_graph", "grid"),  # gathered by real edge_src
+        ("sage", "whole_graph", "scatter"),
+        ("gat", "whole_graph", "grid"),
+        ("gat", "whole_graph", "scatter"),
+    ],
+)
+def test_bf16_inputs_get_bf16_cotangents_close_to_float32(conv, kind, form):
+    """bfloat16 rows through both aggregation forms: no float32 update
+    scattered into a bfloat16 buffer (the FutureWarning, as an error),
+    each cotangent in its primal's dtype, values near the float32 run."""
+    rng = np.random.default_rng(7)
+    block, n_src = _block(kind, rng)
+    if form == "scatter":
+        block = _scatter_oracle(block)
+    x_dst = jnp.asarray(rng.normal(size=(N_DST, F)), jnp.bfloat16)
+    x_src = jnp.asarray(rng.normal(size=(n_src, F)), jnp.bfloat16)
+    layer = get_conv(conv)(out_dim=F)
+    params = layer.init(jax.random.PRNGKey(1), x_dst, x_src, block)
+    cot32 = rng.normal(size=(N_DST, F)).astype(np.float32)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", FutureWarning)
+        out = layer.apply(params, x_dst, x_src, block)
+        _, grads = _vjp_on(
+            layer, params, block, x_dst, x_src, jnp.asarray(cot32, out.dtype)
+        )
+    primals = (params, x_dst, x_src)
+    assert jax.tree.map(lambda g: g.dtype, grads) == jax.tree.map(
+        lambda p: p.dtype, primals
+    )
+    _, want = _vjp_on(
+        layer, params, block, x_dst.astype(jnp.float32),
+        x_src.astype(jnp.float32), jnp.asarray(cot32),
+    )
+    for g, w in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(w), rtol=0.05, atol=0.05
+        )
 
 
 def test_a_row_with_no_valid_slot_has_mean_zero_and_count_clamped():

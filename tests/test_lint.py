@@ -85,9 +85,9 @@ def test_gate_covers_the_package():
         "euler_tpu/estimator/prefetch.py",
         "euler_tpu/query/plan.py",
         # the paged device-sampling lane (ISSUE 6): traced draw code,
-        # Pallas kernels, and the read-cache plumbing it leans on
+        # its page readers, and the read-cache plumbing it leans on
         "euler_tpu/dataflow/device.py",
-        "euler_tpu/ops/pallas_kernels.py",
+        "euler_tpu/ops/paged_ops.py",
         "euler_tpu/distributed/cache.py",
         # the streaming-mutation lane (ISSUE 8): delta buffers merged
         # under the store lock and the batched writer client — exactly
