@@ -30,6 +30,9 @@ Memory — two layouts:
   nodes × deg 15 ≈ 12 MB); power-law graphs with hub nodes blow the
   table up — `max_degree` (default 512) is a GUARD that fails
   construction loudly in that case (truncating would bias sampling).
+  The fan-out flows (`DeviceSageFlow` and its subclasses) round Dmax up
+  to whole 128-lane tiles, which is what a row costs on the chip once
+  it is contiguous: their hops read one plane row a frontier node.
 - `layout="paged"`: ragged neighbor PAGES — fixed-size pages (default
   16 slots) in a flat HBM buffer plus a per-node page table
   (`page_start`), so a hub node spans ⌈deg/P⌉ pages instead of widening
@@ -69,6 +72,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from euler_tpu.ops.paged_ops import PAGE_LANES as _LANES
 from euler_tpu.utils import trace
 from euler_tpu.utils.staged import StagedTables
 
@@ -80,6 +84,10 @@ _STAGE_CHUNK = 16384
 # hub degree, so the chunk length adapts to keep the temp bounded
 _STAGE_TEMP_BYTES = 64 << 20
 _U32_MAX = np.uint32(0xFFFFFFFF)
+# `_LANES`, the lanes of a TPU tile: a 2-D table whose width is a whole
+# number of them lies row-major on the chip by default; any other width
+# lies column-major, a row spread over as many tiles as it has slots, and
+# a row read (`plane[cur]`) makes XLA copy the whole plane first, each step
 
 
 def _node_table(graph):
@@ -193,6 +201,14 @@ def frontier_contrib(weights, global_vec, src_rows):
         return np.asarray(out, np.float64)
 
 
+def _pick_slots(rows, idx):
+    """rows [W, D], idx [W, k] in [0, D) -> [W, k], `rows[w, idx[w, j]]`
+    with no gather: every draw masks its row down to its one slot and
+    sums the lanes, which is exact for integers."""
+    lane = jnp.arange(rows.shape[1], dtype=idx.dtype)
+    return jnp.where(lane == idx[:, :, None], rows[:, None, :], 0).sum(-1)
+
+
 class DeviceGraphTables(StagedTables):
     """HBM-resident graph tables + traced draw primitives.
 
@@ -282,6 +298,11 @@ class DeviceGraphTables(StagedTables):
     # paged; flows that read the dense planes directly (walk bias,
     # per-relation type planes, layerwise scatter) override this False
     _PAGED_OK = True
+    # flows whose draws read whole rows of the dense planes (a fan-out of
+    # k > 1, `_draw_neighbors`) stage them a whole number of lane tiles
+    # wide, so that a row is contiguous on the chip: the slots past the
+    # max degree are padding like any row's tail, and no draw changes
+    _ROW_READS = False
 
     def __init__(
         self,
@@ -393,11 +414,12 @@ class DeviceGraphTables(StagedTables):
             self._stage_paged(graph, ids, degs, edge_types, page_size)
             return
         n = len(ids)
-        adj = np.zeros((n + 1, dmax), dtype=np.int32)
+        width = -(-dmax // _LANES) * _LANES if self._ROW_READS else dmax
+        adj = np.zeros((n + 1, width), dtype=np.int32)
         deg = np.zeros(n + 1, dtype=np.int32)
-        wtab = np.zeros((n + 1, dmax), dtype=np.float32)
+        wtab = np.zeros((n + 1, width), dtype=np.float32)
         ttab = (
-            np.full((n + 1, dmax), -1, dtype=np.int32) if stage_types else None
+            np.full((n + 1, width), -1, dtype=np.int32) if stage_types else None
         )
         unit_w = True
         with trace.span("stage.graph.sweep"):
@@ -448,7 +470,7 @@ class DeviceGraphTables(StagedTables):
                 self.qtab = None
             else:
                 valid = (
-                    np.arange(dmax)[None, :] < deg[:, None]
+                    np.arange(width)[None, :] < deg[:, None]
                 )
                 self.qtab = jax.device_put(_quantize_rows(wtab, valid))
             self.ttab = jax.device_put(ttab) if ttab is not None else None
@@ -637,13 +659,13 @@ class DeviceGraphTables(StagedTables):
         return block, wblk, ttb, d, st
 
     def _refresh_dense(self, graph, rows, ids, degs) -> int:
-        width = int(self.adj.shape[1])
-        if int(degs.max(initial=0)) > width:
+        if int(degs.max(initial=0)) > self.max_deg:
             raise ValueError(
                 f"mutated degree {int(degs.max())} outgrew the staged "
-                f"dense width {width} — build a fresh device flow (or "
-                "the paged layout, which has no width to outgrow)"
+                f"dense width {self.max_deg} — build a fresh device flow "
+                "(or the paged layout, which has no width to outgrow)"
             )
+        width = int(self.adj.shape[1])  # max_deg, or its lane tiles
         block, wblk, ttb, d, st = self._refresh_block(graph, ids, width)
         r1 = rows + 1
         self.adj = self.adj.at[r1].set(jnp.asarray(block))
@@ -875,7 +897,12 @@ class DeviceGraphTables(StagedTables):
         the per-row uint32-quantized CDF staged at construction — the
         SAME integers in both layouts, so the paged lane below draws
         bit-identical neighbors under the same key. Padding rows (0)
-        yield padding.
+        yield padding. A fan-out (k > 1) over planes staged in whole lane
+        tiles (`_ROW_READS`) gathers one plane row a frontier node and
+        picks its k slots from the row (`_pick_slots`); a single draw,
+        or a plane of any other width, gathers slot by slot. Which it
+        was is tallied (`draw_rows` / `draw_elements`; the program's
+        `step.first_call` span carries both).
         """
         if getattr(self, "layout", "dense") == "paged":
             return self._draw_neighbors_paged(cur, key, k)
@@ -894,9 +921,17 @@ class DeviceGraphTables(StagedTables):
                 .astype(jnp.int32)
             )
         idx = jnp.minimum(idx, jnp.maximum(deg[:, None] - 1, 0))
-        nbr = jnp.where(
-            deg[:, None] > 0, self.adj[cur[:, None], idx], 0
-        ).reshape(-1)
+        alive = deg[:, None] > 0
+        if k > 1 and self.adj.shape[1] % _LANES == 0:
+            # one gather index a frontier node, not one a draw
+            trace.count("draw_rows")
+            picked = _pick_slots(self.adj[cur], idx)
+        else:
+            # one draw a row saves no index; a plane of any other width
+            # has no contiguous rows to read (`_LANES`)
+            trace.count("draw_elements")
+            picked = self.adj[cur[:, None], idx]
+        nbr = jnp.where(alive, picked, 0).reshape(-1)
         if not self.unit_w:
             # exact staged weight of the drawn edge (zero on padded slots)
             ew = (
@@ -999,6 +1034,8 @@ class DeviceSageFlow(DeviceGraphTables):
     device_put, so models, hydration, and the feature cache are shared.
     """
 
+    _ROW_READS = True  # every hop of a fan-out reads its frontier's rows
+
     def __init__(
         self,
         graph,
@@ -1023,7 +1060,9 @@ class DeviceSageFlow(DeviceGraphTables):
         layout="auto" stages the dense padded adjacency while the max
         degree fits `max_degree` and the ragged paged layout otherwise
         (power-law graphs; HBM ∝ edges) — draws are bit-identical either
-        way under the same keys."""
+        way under the same keys. The dense planes are staged a whole
+        number of 128-lane tiles wide (`_ROW_READS`): (N+1)·⌈Dmax/128⌉·512
+        bytes each."""
         super().__init__(
             graph, edge_types, max_degree, roots_pool, root_node_type, mesh,
             layout=layout, page_size=page_size,

@@ -376,6 +376,10 @@ _COMPILE_EVENTS = {
     "/jax/compilation_cache/cache_retrieval_time_sec": "cache_fetch",
 }
 
+# `trace.count` names of choices made while a program is traced; the
+# program's `step.first_call` span carries how often each was taken.
+_TRACED_FORMS = ("agg_grid", "agg_scatter", "draw_rows", "draw_elements")
+
 
 @contextlib.contextmanager
 def _first_call(program: str, tables: dict):
@@ -386,7 +390,9 @@ def _first_call(program: str, tables: dict):
     what remains is the first run. `table_arg_bytes` is what went in as
     the `tables` argument rather than as constants of the executable;
     `agg_grid` / `agg_scatter` count the aggregations the program's convs
-    traced in each form (`layers/conv.py:Conv.agg_add`)."""
+    traced in each form (`layers/conv.py:Conv.agg_add`), `draw_rows` /
+    `draw_elements` the neighbour draws that read the plane by whole rows
+    or slot by slot (`dataflow/device.py:_draw_neighbors`)."""
     table_arg_bytes = sum(
         leaf.nbytes for leaf in jax.tree_util.tree_leaves(tables)
     )
@@ -407,7 +413,7 @@ def _first_call(program: str, tables: dict):
             before = trace.counts()
             yield
             after = trace.counts()
-            for form in ("agg_grid", "agg_scatter"):
+            for form in _TRACED_FORMS:
                 call.args[form] = after.get(form, 0) - before.get(form, 0)
             for kind, (lo, hi) in parts.items():
                 call.child(f"step.first_call.{kind}", lo, hi, program=program)

@@ -323,15 +323,9 @@ def test_sage_stack_lowers_without_gather_or_scatter():
         _close(g, w, "stack gradient")
 
 
-@pytest.mark.parametrize(
-    "kind,steps_per_call,want",
-    [
-        ("sage", 1, {"train_step": (6, 0)}),
-        ("sage", 2, {"multi_step": (6, 0)}),
-        ("skipgram", 1, {"train_step": (0, 0)}),
-    ],
-)
-def test_first_call_span_carries_the_tally(tmp_path, kind, steps_per_call, want):
+def _first_calls(tmp_path, kind, steps_per_call=1):
+    """The `step.first_call` spans of a rehearsal-size Estimator over a
+    `DeviceSageFlow` ("sage") or a `DeviceWalkFlow`, by program."""
     graph = random_graph(num_nodes=300, out_degree=5, feat_dim=8, seed=7)
     since = time.perf_counter_ns()
     if kind == "sage":
@@ -354,12 +348,41 @@ def test_first_call_span_carries_the_tally(tmp_path, kind, steps_per_call, want)
     )
     est = Estimator(model, flow, cfg, feature_cache=cache)
     est.train(2 * steps_per_call, log=False, save=False)
-    firsts = {
-        s.args["program"]: (s.args["agg_grid"], s.args["agg_scatter"])
+    return {
+        s.args["program"]: s.args
         for s in trace.spans()
         if s.name == "step.first_call" and s.start_ns >= since
     }
-    assert firsts == want
+
+
+@pytest.mark.parametrize(
+    "kind,steps_per_call,want",
+    [
+        ("sage", 1, {"train_step": (6, 0)}),
+        ("sage", 2, {"multi_step": (6, 0)}),
+        ("skipgram", 1, {"train_step": (0, 0)}),
+    ],
+)
+def test_first_call_span_carries_the_tally(tmp_path, kind, steps_per_call, want):
+    firsts = _first_calls(tmp_path, kind, steps_per_call)
+    assert {
+        program: (args["agg_grid"], args["agg_scatter"])
+        for program, args in firsts.items()
+    } == want
+
+
+@pytest.mark.parametrize(
+    "kind,want",
+    [
+        ("sage", (3, 0)),
+        # a walk is single draws: the plane is read slot by slot, as before
+        ("skipgram", (0, 3)),
+    ],
+)
+def test_first_call_span_carries_the_draw_forms(tmp_path, kind, want):
+    (args,) = _first_calls(tmp_path, kind).values()
+    assert args["program"] == "train_step"
+    assert (args["draw_rows"], args["draw_elements"]) == want
 
 
 def test_conv_scope_has_no_scope_nested_in_it():

@@ -912,3 +912,140 @@ def test_remainder_steps(graph, flow, fcache, tmp_path):
     )
     losses = est.train(total_steps=10, log=False, save=False)
     assert len(losses) == 10 and np.isfinite(losses).all()
+
+
+def _draw_tables(weighted: bool, dmax: int, seed: int = 5):
+    """Dense-layout tables made by hand: row 0 the padding row, rows 1-3
+    of degree 0, row 4 of full degree `dmax`, the rest of every degree in
+    between."""
+    from euler_tpu.dataflow.device import DeviceGraphTables, _quantize_rows
+
+    rng = np.random.default_rng(seed)
+    n = 64
+    deg = rng.integers(1, dmax, n + 1).astype(np.int32)
+    deg[:4], deg[4] = 0, dmax
+    valid = np.arange(dmax)[None, :] < deg[:, None]
+    adj = np.where(valid, rng.integers(1, n + 1, (n + 1, dmax)), 0)
+    tables = object.__new__(DeviceGraphTables)
+    tables.mesh, tables.layout = None, "dense"
+    tables.adj = jnp.asarray(adj, jnp.int32)
+    tables.deg = jnp.asarray(deg)
+    tables.unit_w = not weighted
+    if weighted:
+        w = np.where(valid, rng.random((n + 1, dmax)) + 0.1, 0.0)
+        tables.qtab = jnp.asarray(_quantize_rows(w, valid))
+        tables.wtab = jnp.asarray(w, jnp.float32)
+    return tables, adj, deg
+
+
+@pytest.mark.parametrize("weighted", [False, True], ids=["unit", "weighted"])
+@pytest.mark.parametrize("dmax", [120, 128, 256])
+@pytest.mark.parametrize("k", [1, 2, 5, 15])
+def test_draw_is_the_element_gather_it_replaces(k, dmax, weighted):
+    """Whatever reads the plane — whole rows where a fan-out finds them
+    contiguous (a width of whole lane tiles), single slots otherwise —
+    the drawn neighbours are `adj[cur[:, None], idx]` under the same key,
+    bit for bit."""
+    from euler_tpu.utils import trace
+
+    tables, adj, deg = _draw_tables(weighted, dmax)
+    # padding row, degree-0 rows, the full row, then every row twice over
+    cur = np.concatenate([[0, 0, 1, 2, 3, 4, 4], np.arange(65), np.arange(65)])
+    key = jax.random.PRNGKey(7 + k)
+    before = trace.counts()
+    nbr, ew, idx = jax.jit(tables._draw_neighbors, static_argnums=2)(
+        jnp.asarray(cur, jnp.int32), key, k
+    )
+    after = trace.counts()
+    took = {
+        form: after.get(form, 0) - before.get(form, 0)
+        for form in ("draw_rows", "draw_elements")
+    }
+    rows = k > 1 and dmax % 128 == 0
+    assert took == {"draw_rows": int(rows), "draw_elements": int(not rows)}
+    idx = np.asarray(idx)
+    d = deg[cur][:, None]
+    assert idx.shape == (len(cur), k)
+    assert ((idx >= 0) & (idx <= np.maximum(d - 1, 0))).all()
+    if not weighted:
+        u = np.asarray(jax.random.uniform(key, (len(cur), k)))
+        want_idx = np.minimum((u * d).astype(np.int32), np.maximum(d - 1, 0))
+        np.testing.assert_array_equal(idx, want_idx)
+        assert idx[cur == 4].max() > 100  # the full row is drawn deep
+    want = np.where(d > 0, adj[cur[:, None], idx], 0).reshape(-1)
+    np.testing.assert_array_equal(np.asarray(nbr), want)
+    assert (want.reshape(-1, k)[deg[cur] > 0] > 0).all()
+    assert (want.reshape(-1, k)[deg[cur] == 0] == 0).all()
+    if weighted:
+        want_w = np.asarray(tables.wtab)[cur[:, None], idx].reshape(-1)
+        np.testing.assert_array_equal(
+            np.asarray(ew), np.asarray(jnp.asarray(want_w).astype(jnp.bfloat16))
+        )
+    else:
+        assert ew is None
+
+
+@pytest.mark.parametrize("dmax,k", [(120, 5), (7, 2), (128, 15), (300, 1)])
+def test_pick_slots_is_take_along_axis(dmax, k):
+    """Any width, any k, ids past 2**24 (no float could carry them)."""
+    from euler_tpu.dataflow.device import _pick_slots
+
+    rng = np.random.default_rng(dmax)
+    rows = rng.integers(0, 2**31 - 1, (37, dmax)).astype(np.int32)
+    idx = rng.integers(0, dmax, (37, k)).astype(np.int32)
+    idx[0], idx[1] = 0, dmax - 1
+    got = jax.jit(_pick_slots)(jnp.asarray(rows), jnp.asarray(idx))
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(
+        np.asarray(got), np.take_along_axis(rows, idx, axis=1)
+    )
+
+
+def test_fanout_flows_stage_whole_lane_tiles(graph, flow):
+    """A `DeviceSageFlow`'s dense planes are a whole number of 128-lane
+    tiles wide, the slots past the max degree padding; a walk flow's
+    planes keep the max degree as their width."""
+    from euler_tpu.dataflow import DeviceWalkFlow
+
+    assert flow.layout == "dense" and flow.adj.shape == (301, 128)
+    assert 0 < flow.max_deg < 128
+    assert not np.asarray(flow.adj[:, flow.max_deg:]).any()
+    assert int(flow.deg.max()) == flow.max_deg
+    walk = DeviceWalkFlow(graph, batch_size=4, walk_len=3, window=1)
+    assert walk.adj.shape == (301, walk.max_deg)
+
+
+@pytest.mark.parametrize(
+    "seed,digest,hop2_head,hop1_ids_head,root_head",
+    [
+        (0, "68f578d9653fd77e1b48b58cdbbe15edaa0775d9ac962c93fef9f45ba8dc9d3f",
+         [44, 244, 234, 141, 209, 1], [249, 153, 233, 32], [92, 162, 248]),
+        (31, "92068536e02f730ad07c873f390a73e1204b8647346153eea9b721305c2f7e3a",
+         [207, 135, 125, 290, 290, 100], [105, 257, 51, 261], [299, 136, 208]),
+        (2**31 + 7,
+         "fe3620c2a9c56deb08f9691b14d8d00bc7fdf4a4e9747ce29ae40ebfa9136c40",
+         [8, 105, 148, 141, 93, 241], [33, 184, 184, 125], [237, 165, 102]),
+    ],
+)
+def test_sage_sample_is_the_parents_batch(
+    graph, seed, digest, hop2_head, hop1_ids_head, root_head
+):
+    """`feats`, `hop_ids` and `root_idx` of `DeviceSageFlow.sample` as the
+    element-gather draw gave them (commit 6c2b044, the same keys): the
+    sha256 of their int32 bytes in that order, and a few values to read."""
+    import hashlib
+
+    flow = DeviceSageFlow(
+        graph, fanouts=[4, 3], batch_size=16, label_feature="label",
+        with_hop_ids=True,
+    )
+    mb = jax.jit(flow.sample)(jax.random.PRNGKey(seed))
+    leaves = [*mb.feats, *mb.hop_ids, mb.root_idx]
+    assert [x.shape for x in leaves] == [(16,), (64,), (192,)] * 2 + [(16,)]
+    assert np.asarray(mb.feats[2][:6]).tolist() == hop2_head
+    assert np.asarray(mb.hop_ids[1][:4]).tolist() == hop1_ids_head
+    assert np.asarray(mb.root_idx[:3]).tolist() == root_head
+    got = hashlib.sha256(
+        b"".join(np.asarray(x, "<i4").tobytes() for x in leaves)
+    ).hexdigest()
+    assert got == digest
