@@ -378,7 +378,10 @@ _COMPILE_EVENTS = {
 
 # `trace.count` names of choices made while a program is traced; the
 # program's `step.first_call` span carries how often each was taken.
-_TRACED_FORMS = ("agg_grid", "agg_scatter", "draw_rows", "draw_elements")
+_TRACED_FORMS = (
+    "agg_grid", "agg_scatter", "draw_rows", "draw_elements",
+    "dsa_layers", "dsa_topk", "dsa_core_masked",
+)
 
 
 @contextlib.contextmanager
@@ -392,7 +395,12 @@ def _first_call(program: str, tables: dict):
     `agg_grid` / `agg_scatter` count the aggregations the program's convs
     traced in each form (`layers/conv.py:Conv.agg_add`), `draw_rows` /
     `draw_elements` the neighbour draws that read the plane by whole rows
-    or slot by slot (`dataflow/device.py:_draw_neighbors`)."""
+    or slot by slot (`dataflow/device.py:_draw_neighbors`), `dsa_layers`
+    the indexed-sparse-attention mixers, `dsa_topk` the keys a query of
+    theirs may pick (summed over those layers) and `dsa_core_masked` how
+    many of them attend over the picked set as dense blocks under its
+    mask, the one form there is
+    (`layers/sequence.py:IndexedSparseAttention`)."""
     table_arg_bytes = sum(
         leaf.nbytes for leaf in jax.tree_util.tree_leaves(tables)
     )

@@ -22,13 +22,13 @@ No assignment to a held expert is dropped, and no bound is guessed. A
 grouped matmul needs a static number of rows and the number routed here
 is the router's to decide, between none and the worst case (every token
 routed to `min(top_k, count)` held experts). So the sorted assignments
-are taken in tiles of as many rows as there are tokens, `min(top_k,
-count)` tiles at the worst, and only the tiles that hold a routed row
-are run: a loop whose trip count the step's own routing sets, forward
-and backward (`held_experts`). An even router fills
-`top_k * count / num_experts` of a tile; a router that has learned to
-prefer the experts held here costs the tiles it fills and nothing
-overflows.
+are taken in tiles of as many rows as there are tokens — of a multiple
+of that where an even router would fill such a tile (`tile_rows`) — and
+only the tiles that hold a routed row are run: a loop whose trip count
+the step's own routing sets, forward and backward (`held_experts`). An
+even router fills at most four fifths of a tile; a router that has
+learned to prefer the experts held here costs the tiles it fills and
+nothing overflows.
 """
 
 from __future__ import annotations
@@ -49,18 +49,33 @@ def _swiglu(x, w_gate, w_up, w_down, matmul):
     return matmul(jax.nn.silu(matmul(x, w_gate)) * matmul(x, w_up), w_down)
 
 
-def _tile(top_k, start, order, ends, x, weight, w_gate, w_up, w_down):
+def tile_rows(tokens: int, top_k: int, count: int, num_experts: int) -> int:
+    """Sorted assignments a tile: `tokens` times the least divisor of
+    `top_k` (so that whole tiles cover the `tokens * top_k` assignments)
+    that leaves a quarter to spare over the rows an even router sends to
+    `count` of `num_experts`. Where those rows all but fill a tile — 8
+    picks a token, an eighth of the experts held — every router near even,
+    as each is at the start, would run one tile at one step and two at the
+    next, and the step's time would follow the draw."""
+    even = tokens * top_k * count / num_experts
+    return tokens * next(
+        m for m in range(1, top_k + 1)
+        if top_k % m == 0 and (m * tokens >= 1.25 * even or m == top_k)
+    )
+
+
+def _tile(top_k, step, start, order, ends, x, weight, w_gate, w_up, w_down):
     """What the held experts add to every token from the sorted
-    assignments `start .. start + N` (N = the number of tokens): [N, H]."""
+    assignments `start .. start + step`: [N, H]."""
     tokens = x.shape[0]
     with trace.scope("moe.dispatch"):
-        mine = jax.lax.dynamic_slice(order, (start,), (tokens,))
+        mine = jax.lax.dynamic_slice(order, (start,), (step,))
         token = mine // top_k
         rows = gather(x, token)
         # each expert's rows inside this tile; the rows past the last
         # routed one belong to no group, and the grouped matmul takes and
         # leaves them as zeros, forward and transposed
-        sizes = jnp.diff(jnp.clip(ends, start, start + tokens), prepend=start)
+        sizes = jnp.diff(jnp.clip(ends, start, start + step), prepend=start)
     with trace.scope("moe.experts"):
         out = _swiglu(
             rows, w_gate, w_up, w_down,
@@ -70,46 +85,44 @@ def _tile(top_k, start, order, ends, x, weight, w_gate, w_up, w_down):
         return scatter_add(out * gather(weight, mine)[:, None], token, tokens)
 
 
-def _tiles(x, ends):
-    """How many tiles of `len(x)` sorted assignments hold a routed row."""
-    return (ends[-1] + x.shape[0] - 1) // x.shape[0]
+def _tiles(step, ends):
+    """How many tiles of `step` sorted assignments hold a routed row."""
+    return (ends[-1] + step - 1) // step
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def held_experts(top_k, order, ends, x, weight, w_gate, w_up, w_down):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def held_experts(top_k, step, order, ends, x, weight, w_gate, w_up, w_down):
     """sum over the assignments (token n, expert e held here) of
     `weight[n, e] E_e(x[n])`, per token: [N, H].
 
     `order` [N * top_k] lists the flat assignments sorted by held expert,
     those to absent experts last; `ends` [count] is where each held
     expert's run of them ends; `weight` [N * top_k] the routing weights.
-    The tiles are run by a loop of `_tiles` trips, which reverse-mode
-    differentiation cannot pass through: the backward pass is the same
-    loop over each tile's own vjp."""
-    step = x.shape[0]
+    The tiles, of `step` assignments (`tile_rows`), are run by a loop of
+    `_tiles` trips, which reverse-mode differentiation cannot pass
+    through: the backward pass is the same loop over each tile's own vjp."""
     return jax.lax.fori_loop(
-        0, _tiles(x, ends),
-        lambda t, y: y + _tile(top_k, t * step, order, ends, x, weight, w_gate, w_up, w_down),
+        0, _tiles(step, ends),
+        lambda t, y: y + _tile(top_k, step, t * step, order, ends, x, weight, w_gate, w_up, w_down),
         jnp.zeros_like(x),
     )
 
 
-def _held_experts_fwd(top_k, order, ends, *inputs):
-    return held_experts(top_k, order, ends, *inputs), (order, ends, inputs)
+def _held_experts_fwd(top_k, step, order, ends, *inputs):
+    return held_experts(top_k, step, order, ends, *inputs), (order, ends, inputs)
 
 
-def _held_experts_bwd(top_k, kept, dy):
+def _held_experts_bwd(top_k, step, kept, dy):
     order, ends, inputs = kept
-    step = inputs[0].shape[0]
 
     def tile_grads(t, grads):
         _, pull = jax.vjp(
-            functools.partial(_tile, top_k, t * step, order, ends), *inputs
+            functools.partial(_tile, top_k, step, t * step, order, ends), *inputs
         )
         return jax.tree_util.tree_map(jnp.add, grads, pull(dy))
 
     grads = jax.lax.fori_loop(
-        0, _tiles(inputs[0], ends), tile_grads,
+        0, _tiles(step, ends), tile_grads,
         jax.tree_util.tree_map(jnp.zeros_like, inputs),
     )
     return (None, None, *grads)
@@ -127,6 +140,8 @@ class SparseMoE(nn.Module):
     `y = sum_{e in top_k, e held} p_e E_e(x) + sigmoid(x . w_s) E_shared(x)`,
     `E(x) = W_down (SiLU(W_gate x) * W_up x)`. Every assignment to a held
     expert is computed, however many there are (`held_experts`).
+    `shared_dim` 0 is a layer with no shared expert: no such term and no
+    such parameters.
     """
 
     num_experts: int
@@ -151,15 +166,6 @@ class SparseMoE(nn.Module):
         w_down = self.param(
             "experts_down", _MATRIX, (count, self.expert_dim, hidden), jnp.float32
         )
-        s_gate = self.param(
-            "shared_gate", _MATRIX, (hidden, self.shared_dim), jnp.float32
-        )
-        s_up = self.param("shared_up", _MATRIX, (hidden, self.shared_dim), jnp.float32)
-        s_down = self.param(
-            "shared_down", _MATRIX, (self.shared_dim, hidden), jnp.float32
-        )
-        s_mix = self.param("shared_mix", _MATRIX, (hidden, 1), jnp.float32)
-
         with trace.scope("moe.route"):
             # float32 for real: a top-k pick that flips on a bf16-rounded
             # logit would send a token to other experts
@@ -177,8 +183,15 @@ class SparseMoE(nn.Module):
             order = jnp.argsort(slot, stable=True).astype(jnp.int32)
             ends = jnp.cumsum(jnp.bincount(slot, length=count + 1)[:count])
             ends = ends.astype(jnp.int32)
-        y = held_experts(k, order, ends, x, top_p.reshape(-1), w_gate, w_up, w_down)
-        with trace.scope("moe.shared"):
-            mix = jax.nn.sigmoid((x @ s_mix).astype(jnp.float32))
-            y = y + mix * _swiglu(x, s_gate, s_up, s_down, jnp.matmul)
+        step = tile_rows(x.shape[0], k, count, self.num_experts)
+        y = held_experts(k, step, order, ends, x, top_p.reshape(-1), w_gate, w_up, w_down)
+        if self.shared_dim:
+            shape = (hidden, self.shared_dim)
+            s_gate = self.param("shared_gate", _MATRIX, shape, jnp.float32)
+            s_up = self.param("shared_up", _MATRIX, shape, jnp.float32)
+            s_down = self.param("shared_down", _MATRIX, shape[::-1], jnp.float32)
+            s_mix = self.param("shared_mix", _MATRIX, (hidden, 1), jnp.float32)
+            with trace.scope("moe.shared"):
+                mix = jax.nn.sigmoid((x @ s_mix).astype(jnp.float32))
+                y = y + mix * _swiglu(x, s_gate, s_up, s_down, jnp.matmul)
         return y, ends[-1]

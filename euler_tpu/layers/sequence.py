@@ -1,18 +1,26 @@
-"""Sequence mixers of hybrid linear/softmax-attention language models
-(Qwen3-Next: HF `modeling_qwen3_next.py`): `GatedDeltaNet`,
-`GatedAttention` and the zero-centred `RMSNorm` they share.
+"""Sequence mixers of decoder-only language models and what they share:
+`GatedDeltaNet` (linear attention by the gated delta rule),
+`GatedAttention` (causal softmax attention with an output gate),
+`IndexedSparseAttention` (causal softmax attention over the keys a
+learned indexer picks for each query), the zero-centred `RMSNorm` and
+the rotary embedding, by one position or by three.
 
 Inputs are [B, T, H] float32. Projections run at the backend's default
-matmul precision; norms, gates, the delta rule's state and the softmax
-are float32 (ops/seq_ops.py). Each mixer names its parts for a trace:
-`euler.gdn.{proj,conv,scan,out}`, `euler.attn.{proj,core,out}`.
+matmul precision; norms, gates, the delta rule's state, every softmax
+and the indexer's KL term are float32 (ops/seq_ops.py). Each mixer names
+its parts for a trace: `euler.gdn.{proj,conv,scan,out}`,
+`euler.attn.{proj,core,out}`,
+`euler.dsa.{proj,index,select,core,aux,out}`.
 """
 
 from __future__ import annotations
 
+import functools
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from euler_tpu.ops import seq_ops
 from euler_tpu.utils import trace
@@ -27,7 +35,8 @@ def rms(x, eps: float):
 
 class RMSNorm(nn.Module):
     """`(1 + w) * x / sqrt(mean(x^2) + eps)` over the last axis, `w` from
-    zeros (Qwen3-Next's zero-centred weight)."""
+    zeros (a zero-centred weight; `w` from ones in `w * x / ...` is the
+    same function)."""
 
     eps: float = 1e-6
 
@@ -37,15 +46,26 @@ class RMSNorm(nn.Module):
         return rms(x, self.eps) * (1.0 + w)
 
 
-def rotary(x, theta: float, rotary_dim: int):
+def rotary(x, theta: float, rotary_dim: int, positions=None, sections=None):
     """Rotary position embedding on the first `rotary_dim` of the last
-    axis (HF `rotate_half` pairing: dimension i with i + rotary_dim/2),
-    positions 0..T-1 along axis 1. x [B, T, heads, d]."""
+    axis (HF `rotate_half` pairing: dimension i with i + rotary_dim/2).
+    x [B, T, heads, d]. Without `positions` they are 0..T-1 along axis 1.
+    With `positions` [A, B, T] (A axes: time, height, width) frequency
+    pair j turns by the axis `sections` puts it in — consecutive runs of
+    `sections[a]` pairs, which add up to `rotary_dim / 2` (Qwen2-VL's
+    multi-axis rotary) — and by `positions[0]` when `sections` is None."""
     half = rotary_dim // 2
     inv_freq = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) / half)
-    angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq
-    cos = jnp.cos(angle)[None, :, None, :]
-    sin = jnp.sin(angle)[None, :, None, :]
+    if positions is None:
+        angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq
+        cos = jnp.cos(angle)[None, :, None, :]
+        sin = jnp.sin(angle)[None, :, None, :]
+    else:
+        axis = np.repeat(np.arange(len(sections)), sections) if sections else np.zeros(half, int)
+        # [B, T, half]: pair j reads the position of its own axis
+        angle = jnp.moveaxis(positions.astype(jnp.float32), 0, -1)[..., axis] * inv_freq
+        cos = jnp.cos(angle)[:, :, None, :]
+        sin = jnp.sin(angle)[:, :, None, :]
     x1, x2, rest = x[..., :half], x[..., half:rotary_dim], x[..., rotary_dim:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1
@@ -166,3 +186,141 @@ class GatedAttention(nn.Module):
             o = o.transpose(0, 3, 1, 2, 4).reshape(batch, length, nq, d)
             o = o * jax.nn.sigmoid(gate.astype(jnp.float32))
             return o.reshape(batch, length, nq * d) @ w_o
+
+
+def query_runs(length: int, block: int, topk: int) -> list:
+    """How `IndexedSparseAttention` cuts its queries: `(first row, blocks,
+    rows a block, keys)` for each run of equal blocks that attend to the
+    first `keys` keys — a stretch that holds the run's last row. The
+    stretches double from `max(topk, block)` up to `length`, so a context
+    of any length is a handful of programs (not one a block) and the
+    scores computed are at most two thirds of the square, where whole
+    blocks over the causal half would be a half. The first run's queries
+    have `topk` keys or fewer when `topk` is whole blocks; a last block
+    that is not whole is a run of its own."""
+    block = min(block, length)
+    whole, done, runs = length // block, 0, []
+    keys = -(-max(topk, block) // block) * block
+    while done < whole:
+        upto = min(keys // block, whole)
+        runs.append((done * block, upto - done, block, min(keys, length)))
+        done, keys = upto, 2 * keys
+    if length % block:
+        runs.append((whole * block, 1, length % block, length))
+    return runs
+
+
+class IndexedSparseAttention(nn.Module):
+    """Causal softmax attention with grouped queries in which a query
+    attends to the `topk` keys a learned indexer scores highest for it
+    (DeepSeek sparse attention's lightning indexer), one set for all
+    heads; a query that has `topk` keys or fewer attends to all of them.
+    x [B, T, H], positions [3, B, T] -> (y [B, T, H], the indexer's loss).
+
+    Attention: zero-centred RMSNorm on each query and key head, rotary by
+    three position axes over the whole head (`sections`), no output gate.
+    Indexer, fed `x` with the gradient stopped: `index_heads` queries of
+    `index_dim` and one key, LayerNorm on the key, rotary by the time
+    position over the whole of `index_dim`, head weights `x W_w` scaled
+    by `index_heads^-0.5 index_dim^-0.5`; `I[t, s] = sum_j w[t, j]
+    relu(q[t, j] . k[s])`. Its loss: the mean over the queries of
+    `KL(p_t || softmax over the picked keys of I[t])`, p_t the heads'
+    attention probabilities summed and normalised, as a constant. The
+    pick passes no gradient, so the language-model loss does not reach
+    the indexer, and this loss reaches nothing else.
+
+    One block of `block` queries at a time: scores, pick, attention
+    under the pick's mask (a gather of the picked rows would cost more
+    than the masked products it saves), KL. The blocks of a run
+    (`query_runs`) see the same stretch of keys and are one loop over one
+    program; a block is rematerialised in the backward pass, so neither
+    its [block, keys] index scores nor its heads' probabilities outlive
+    it.
+    """
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    index_heads: int
+    index_dim: int
+    topk: int
+    rope_theta: float = 1e7
+    sections: tuple = ()
+    block: int = 512
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x, positions):
+        batch, length, hidden = x.shape
+        nq, nkv, d = self.num_heads, self.num_kv_heads, self.head_dim
+        ni, di = self.index_heads, self.index_dim
+        w_q = self.param("q_proj", _MATRIX, (hidden, nq * d), jnp.float32)
+        w_k = self.param("k_proj", _MATRIX, (hidden, nkv * d), jnp.float32)
+        w_v = self.param("v_proj", _MATRIX, (hidden, nkv * d), jnp.float32)
+        w_o = self.param("o_proj", _MATRIX, (nq * d, hidden), jnp.float32)
+        i_q = self.param("index_q", _MATRIX, (hidden, ni * di), jnp.float32)
+        i_k = self.param("index_k", _MATRIX, (hidden, di), jnp.float32)
+        i_w = self.param("index_w", _MATRIX, (hidden, ni), jnp.float32)
+        i_scale = self.param("index_k_norm_w", nn.initializers.zeros, (di,), jnp.float32)
+        i_bias = self.param("index_k_norm_b", nn.initializers.zeros, (di,), jnp.float32)
+        trace.count("dsa_layers")
+        trace.count("dsa_topk", self.topk)
+        trace.count("dsa_core_masked")
+        with trace.scope("dsa.proj"):
+            q = (x @ w_q).reshape(batch, length, nq, d)
+            k = (x @ w_k).reshape(batch, length, nkv, d)
+            v = (x @ w_v).reshape(batch, length, nkv, d)
+            turn = functools.partial(
+                rotary, theta=self.rope_theta, rotary_dim=d,
+                positions=positions, sections=self.sections,
+            )
+            q = turn(RMSNorm(self.eps, name="q_norm")(q))
+            k = turn(RMSNorm(self.eps, name="k_norm")(k))
+            q = q.reshape(batch, length, nkv, nq // nkv, d).transpose(0, 2, 3, 1, 4)
+            k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+            fed = jax.lax.stop_gradient(x)
+            qi = rotary(
+                (fed @ i_q).reshape(batch, length, ni, di),
+                self.rope_theta, di, positions[:1],
+            )
+            ki = (fed @ i_k).astype(jnp.float32)
+            ki = ki - jnp.mean(ki, axis=-1, keepdims=True)
+            ki = rms(ki, self.eps) * (1.0 + i_scale) + i_bias
+            ki = rotary(ki[:, :, None, :], self.rope_theta, di, positions[:1])[:, :, 0]
+            wi = (fed @ i_w).astype(jnp.float32) * (ni**-0.5 * di**-0.5)
+
+        def one(q_b, qi_b, wi_b, first, k_r, v_r, ki_r):
+            with trace.scope("dsa.index"):
+                scores = seq_ops.indexer_scores(qi_b, ki_r, wi_b, first)
+            with trace.scope("dsa.select"):
+                # a run whose last query has `topk` keys or fewer picks
+                # every key it may see
+                keep = scores > -jnp.inf
+                if k_r.shape[2] > self.topk:
+                    keep = seq_ops.topk_mask(jax.lax.stop_gradient(scores), self.topk)
+            with trace.scope("dsa.core"):
+                o_b, probs = seq_ops.masked_attention(q_b, k_r, v_r, keep, d**-0.5)
+            with trace.scope("dsa.aux"):
+                return o_b, seq_ops.index_kl(probs, scores, keep)
+
+        outs, kl = [], jnp.zeros((), jnp.float32)
+        for first, count, rows, keys in query_runs(length, self.block, self.topk):
+            upto = first + count * rows
+            cut = lambda a, axis: jnp.moveaxis(  # noqa: E731  blocks to the front
+                a.reshape(a.shape[:axis] + (count, rows) + a.shape[axis + 1 :]), axis, 0
+            )
+            o_r, kl_r = jax.lax.map(
+                lambda xs: jax.checkpoint(one)(
+                    *xs, k[:, :, :keys], v[:, :, :keys], ki[:, :keys]
+                ),
+                (
+                    cut(q[:, :, :, first:upto], 3), cut(qi[:, first:upto], 1),
+                    cut(wi[:, first:upto], 1), first + rows * jnp.arange(count),
+                ),
+            )
+            o_r = jnp.moveaxis(o_r, 0, 3)  # [B, G, R, count, rows, d]
+            outs.append(o_r.reshape(o_r.shape[:3] + (count * rows, d)))
+            kl = kl + jnp.sum(kl_r)
+        with trace.scope("dsa.out"):
+            o = jnp.concatenate(outs, axis=3).transpose(0, 3, 1, 2, 4)
+            return o.reshape(batch, length, nq * d) @ w_o, kl / (batch * length)

@@ -1,20 +1,27 @@
-"""`Qwen3NextLM`: a decoder-only language model of the Qwen3-Next family
-(HF `modeling_qwen3_next.py`) as an `Estimator` model.
+"""Decoder-only language models as `Estimator` models: one decoder,
+`DecoderLM`, whose layers differ in their mixer alone, and the two
+architectures that plan their mixers on it.
 
-Decoder layer l: `h += Mixer_l(norm(h))`, then `h += MoE(norm(h))`;
-`Mixer_l` is `GatedAttention` when `(l + 1) % full_attention_interval ==
-0` and `GatedDeltaNet` otherwise (layers/sequence.py), the feed-forward a
-`SparseMoE` that holds `experts_here` of the routed experts
-(layers/moe.py). Embedding (`nn/encoders.py:Embedding`, so `euler.embed`
-and the table's scatter-add gradient are the ones every embedding model
-here has), the layers, a final norm, an untied head and the mean
-next-token cross-entropy in float32. Every layer is rematerialised in the
-backward pass: what is kept of the forward is each layer's input.
+Decoder layer l: `h += Mixer_l(norm(h))`, then `h += MoE(norm(h))`; the
+feed-forward is a `SparseMoE` that holds `experts_here` of the routed
+experts (layers/moe.py). `Qwen3NextLM` (HF `modeling_qwen3_next.py`)
+plans `GatedAttention` where `(l + 1) % full_attention_interval == 0` and
+`GatedDeltaNet` elsewhere, with a shared expert; `KeyeVL2LM`
+(Keye-VL-2.0's language model) plans `IndexedSparseAttention` in every
+layer, no shared expert, and adds the mean of the layers' indexer losses
+to the loss (layers/sequence.py). Embedding (`nn/encoders.py:Embedding`,
+so `euler.embed` and the table's scatter-add gradient are the ones every
+embedding model here has), the layers, a final norm, an untied head and
+the mean next-token cross-entropy in float32. Every layer is
+rematerialised in the backward pass: what is kept of the forward is each
+layer's input.
 
 The batch is what `DeviceSequenceFlow.sample` returns: int32 ids
 [B, T + 1]; positions 0..T-1 are the inputs and 1..T the targets. The
 vocabulary may be a slice (`vocab_size` rows of the published table): ids,
-logits and loss are over the slice.
+logits and loss are over the slice. `positions` [3, B, T] (time, height,
+width) is what a flow with images would hand in; text has all three equal
+to 0..T-1, which is what the model makes when it is given none.
 """
 
 from __future__ import annotations
@@ -25,81 +32,68 @@ import jax.numpy as jnp
 import optax
 
 from euler_tpu.layers.moe import SparseMoE
-from euler_tpu.layers.sequence import GatedAttention, GatedDeltaNet, RMSNorm
+from euler_tpu.layers.sequence import (
+    GatedAttention,
+    GatedDeltaNet,
+    IndexedSparseAttention,
+    RMSNorm,
+)
 from euler_tpu.nn.encoders import Embedding
 from euler_tpu.utils import trace
 
 
 class DecoderLayer(nn.Module):
+    """-> (h, the assignments routed to held experts, the mixer's own
+    loss or None)."""
+
     mixer: nn.Module
     moe: nn.Module
     eps: float = 1e-6
 
     @nn.compact
-    def __call__(self, h):
-        h = h + self.mixer(RMSNorm(self.eps, name="input_norm")(h))
+    def __call__(self, h, positions):
+        x, aux = RMSNorm(self.eps, name="input_norm")(h), None
+        if isinstance(self.mixer, IndexedSparseAttention):
+            mixed, aux = self.mixer(x, positions)
+        else:  # positions 0..T-1, or none at all
+            mixed = self.mixer(x)
+        h = h + mixed
         x = RMSNorm(self.eps, name="post_norm")(h)
         y, routed = self.moe(x.reshape(-1, x.shape[-1]))
-        return h + y.reshape(h.shape), routed
+        return h + y.reshape(h.shape), routed, aux
 
 
-class Qwen3NextLM(nn.Module):
-    """Returns `(emb, loss, "routed_share", share)`: the final hidden
-    states [B, T, H], the loss, and the share of the step's token-expert
+class DecoderLM(nn.Module):
+    """The decoder both architectures share; a subclass plans `mixer(l)`.
+    Returns `(emb, loss, "routed_share", share)`: the final hidden states
+    [B, T, H], the loss, and the share of the step's token-expert
     assignments that landed on experts held here (`experts_here[1] /
     num_experts` when the router is even)."""
 
     vocab_size: int
     hidden_size: int
     num_layers: int
-    full_attention_interval: int = 4
-    # gated attention
+    # softmax attention
     num_heads: int = 16
     num_kv_heads: int = 2
     head_dim: int = 256
     rope_theta: float = 1e7
-    partial_rotary_factor: float = 0.25
+    rope_sections: tuple = ()  # pairs turned by each of several position axes
     attention_block: int = 512
-    # gated DeltaNet
-    linear_num_key_heads: int = 16
-    linear_num_value_heads: int = 32
-    linear_key_head_dim: int = 128
-    linear_value_head_dim: int = 128
-    linear_conv_kernel_dim: int = 4
-    chunk: int = 64
     # experts
     num_experts: int = 512
     num_experts_per_tok: int = 10
     moe_intermediate_size: int = 512
-    shared_expert_intermediate_size: int = 512
+    shared_expert_intermediate_size: int = 512  # 0: no shared expert
     norm_topk_prob: bool = True
     experts_here: tuple = (0, 0)  # (first, count); count 0 = all
     rms_norm_eps: float = 1e-6
     loss_chunks: int = 1  # the head and loss run over T in this many parts
 
+    def mixer(self, index: int) -> nn.Module:
+        raise NotImplementedError
+
     def _layer(self, index: int):
-        if (index + 1) % self.full_attention_interval == 0:
-            mixer = GatedAttention(
-                num_heads=self.num_heads,
-                num_kv_heads=self.num_kv_heads,
-                head_dim=self.head_dim,
-                rope_theta=self.rope_theta,
-                rotary_dim=int(self.head_dim * self.partial_rotary_factor),
-                block=self.attention_block,
-                eps=self.rms_norm_eps,
-                parent=None,  # adopted by the layer, as its `mixer`
-            )
-        else:
-            mixer = GatedDeltaNet(
-                num_k_heads=self.linear_num_key_heads,
-                num_v_heads=self.linear_num_value_heads,
-                head_k_dim=self.linear_key_head_dim,
-                head_v_dim=self.linear_value_head_dim,
-                conv_kernel=self.linear_conv_kernel_dim,
-                chunk=self.chunk,
-                eps=self.rms_norm_eps,
-                parent=None,  # adopted by the layer, as its `mixer`
-            )
         moe = SparseMoE(
             num_experts=self.num_experts,
             top_k=self.num_experts_per_tok,
@@ -110,17 +104,22 @@ class Qwen3NextLM(nn.Module):
             parent=None,
         )
         return nn.remat(DecoderLayer)(
-            mixer, moe, self.rms_norm_eps, name=f"layer_{index}",
+            self.mixer(index), moe, self.rms_norm_eps, name=f"layer_{index}",
         )
 
     @nn.compact
-    def __call__(self, ids):
+    def __call__(self, ids, positions=None):
         tokens, targets = ids[:, :-1], ids[:, 1:]
+        if positions is None and self.rope_sections:  # text: every axis is time
+            positions = jnp.broadcast_to(
+                jnp.arange(tokens.shape[1]), (len(self.rope_sections),) + tokens.shape
+            )
         h = Embedding(self.vocab_size, self.hidden_size, name="embed")(tokens)
-        routed = jnp.zeros((), jnp.int32)
+        routed, own = jnp.zeros((), jnp.int32), []
         for index in range(self.num_layers):
-            h, here = self._layer(index)(h)
+            h, here, aux = self._layer(index)(h, positions)
             routed = routed + here
+            own += [] if aux is None else [aux]
         emb = RMSNorm(self.rms_norm_eps, name="final_norm")(h)
         w_head = self.param(
             "head", nn.initializers.normal(stddev=0.02),
@@ -147,7 +146,83 @@ class Qwen3NextLM(nn.Module):
         )
         with trace.scope("loss"):
             loss = total / targets.size
+            if own:  # the mixers' own losses, coefficient 1
+                loss = loss + sum(own) / len(own)
         assignments = tokens.size * self.num_experts_per_tok * self.num_layers
         share = routed.astype(jnp.float32) / assignments
         return emb, loss, "routed_share", share
 
+
+class Qwen3NextLM(DecoderLM):
+    """A period of `full_attention_interval - 1` `GatedDeltaNet` layers
+    and one `GatedAttention` layer (rotary on `partial_rotary_factor` of
+    the head), a shared expert beside the routed ones."""
+
+    full_attention_interval: int = 4
+    partial_rotary_factor: float = 0.25
+    # gated DeltaNet
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_conv_kernel_dim: int = 4
+    chunk: int = 64
+
+    def mixer(self, index: int):
+        if (index + 1) % self.full_attention_interval == 0:
+            return GatedAttention(
+                num_heads=self.num_heads,
+                num_kv_heads=self.num_kv_heads,
+                head_dim=self.head_dim,
+                rope_theta=self.rope_theta,
+                rotary_dim=int(self.head_dim * self.partial_rotary_factor),
+                block=self.attention_block,
+                eps=self.rms_norm_eps,
+                parent=None,  # adopted by the layer, as its `mixer`
+            )
+        return GatedDeltaNet(
+            num_k_heads=self.linear_num_key_heads,
+            num_v_heads=self.linear_num_value_heads,
+            head_k_dim=self.linear_key_head_dim,
+            head_v_dim=self.linear_value_head_dim,
+            conv_kernel=self.linear_conv_kernel_dim,
+            chunk=self.chunk,
+            eps=self.rms_norm_eps,
+            parent=None,  # adopted by the layer, as its `mixer`
+        )
+
+
+class KeyeVL2LM(DecoderLM):
+    """`IndexedSparseAttention` in every layer: grouped queries, rotary
+    by three position axes over the whole head, the `topk` keys of the
+    context an indexer of `index_heads` x `index_dim` picks; no shared
+    expert. The loss is the cross-entropy plus the mean over the layers
+    of the indexer's KL term. The vision tower is not here: `positions`
+    is where its three axes would come in."""
+
+    num_heads: int = 32
+    num_kv_heads: int = 4
+    head_dim: int = 128
+    rope_sections: tuple = (16, 24, 24)
+    index_heads: int = 16
+    index_dim: int = 64
+    topk: int = 2048
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: int = 768
+    shared_expert_intermediate_size: int = 0
+
+    def mixer(self, index: int):
+        return IndexedSparseAttention(
+            num_heads=self.num_heads,
+            num_kv_heads=self.num_kv_heads,
+            head_dim=self.head_dim,
+            index_heads=self.index_heads,
+            index_dim=self.index_dim,
+            topk=self.topk,
+            rope_theta=self.rope_theta,
+            sections=tuple(self.rope_sections),
+            block=self.attention_block,
+            eps=self.rms_norm_eps,
+            parent=None,  # adopted by the layer, as its `mixer`
+        )

@@ -1,7 +1,9 @@
 """Device-side primitives of sequence layers (layers/sequence.py,
 layers/moe.py): a causal depthwise convolution over time, the chunked
-gated delta rule, causal softmax attention by query blocks, and the
-grouped matmul over rows sorted by expert.
+gated delta rule, causal softmax attention by query blocks, the parts of
+indexed sparse attention (an indexer's scores, the k largest of a row as
+a mask, softmax attention under that mask, the indexer's KL term), and
+the grouped matmul over rows sorted by expert.
 
 All plain XLA over static shapes; matmuls run at the backend's default
 precision (bf16 inputs, float32 accumulation on the TPU) unless said.
@@ -194,6 +196,85 @@ def blockwise_causal_attention(
             one(q[:, :, :, first:end], k[:, :, :end], v[:, :, :end], first)
         )
     return jnp.concatenate(outs, axis=3)
+
+
+def indexer_scores(q: Array, k: Array, w: Array, first: int) -> Array:
+    """A block of queries' index scores against the keys up to its end:
+    `I[t, s] = sum_j w[t, j] relu(q[t, j] . k[s])`, -inf where s > t.
+
+    q [B, R, J, d] (J indexer heads), k [B, S, d] (one key for all of
+    them), w [B, R, J]; row r is position `first + r`. Returns float32
+    [B, R, S]; the [B, J, R, S] products are the largest tensor alive.
+    """
+    dots = jnp.einsum("brjd,bsd->bjrs", q, k).astype(jnp.float32)
+    weight = jnp.moveaxis(w.astype(jnp.float32), 2, 1)[..., None]
+    scores = jnp.sum(weight * jax.nn.relu(dots), axis=1)
+    rows = first + jnp.arange(q.shape[1])[:, None]
+    return jnp.where(jnp.arange(k.shape[1])[None, :] <= rows, scores, -jnp.inf)
+
+
+def _ordered_bits(x: Array) -> Array:
+    """float32 -> uint32 that orders as the floats do (-0.0 as +0.0)."""
+    bits = jax.lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x), jnp.uint32)
+    flip = jnp.where(bits >> 31 == 1, jnp.uint32(0xFFFFFFFF), jnp.uint32(0x80000000))
+    return bits ^ flip
+
+
+def topk_mask(scores: Array, k: int) -> Array:
+    """True at the k largest entries of each row of `scores` [..., S]
+    that are not -inf (all of them where a row has k or fewer), ties to
+    the lower index: what a stable descending sort would keep.
+
+    No sort: the k-th largest value of a row is found bit by bit, from
+    the top, over the floats' bits put in order — 32 passes that each
+    count the entries at or over a candidate — and the entries equal to
+    it are taken from the left until the row has its k. `jax.lax.top_k`
+    at k in the thousands is a full sort of the row on the TPU.
+    """
+    seen = scores > -jnp.inf
+    keys = _ordered_bits(scores)
+    want = jnp.minimum(jnp.sum(seen, axis=-1, dtype=jnp.int32), k)[..., None]
+
+    def refine(i, kth):
+        trial = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(keys >= trial, axis=-1, keepdims=True, dtype=jnp.int32) >= want
+        return jnp.where(enough, trial, kth)
+
+    kth = jax.lax.fori_loop(0, 32, refine, jnp.zeros_like(want, jnp.uint32))
+    over = keys > kth
+    level = keys == kth
+    room = want - jnp.sum(over, axis=-1, keepdims=True, dtype=jnp.int32)
+    return seen & (over | (level & (jnp.cumsum(level, axis=-1, dtype=jnp.int32) <= room)))
+
+
+def masked_attention(q: Array, k: Array, v: Array, keep: Array, scale: float):
+    """Softmax attention of a block of queries over the keys `keep`
+    marks, grouped queries, softmax in float32.
+
+    q [B, G, R, t, d], k, v [B, G, S, d], keep [B, t, S] bool with at
+    least one key a row. Returns (o [B, G, R, t, d], probs [B, G, R, t, S]).
+    """
+    scores = jnp.einsum("bgrtd,bgsd->bgrts", q, k) * scale
+    scores = jnp.where(keep[:, None, None], scores.astype(jnp.float32), -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bgrts,bgsd->bgrtd", probs, v), probs
+
+
+def index_kl(probs: Array, scores: Array, keep: Array) -> Array:
+    """sum over rows of KL(p || softmax over the kept keys of `scores`),
+    p the heads' attention probabilities summed and L1-normalised, taken
+    as a constant (the indexer is trained towards the attention, never
+    the attention towards the indexer).
+
+    probs [B, G, R, t, S] (zero off the kept keys), scores, keep [B, t, S].
+    """
+    p = jax.lax.stop_gradient(jnp.sum(probs, axis=(1, 2)))
+    p = p / jnp.sum(p, axis=-1, keepdims=True)
+    log_q = jax.nn.log_softmax(jnp.where(keep, scores, -jnp.inf), axis=-1)
+    live = keep & (p > 0)
+    return jnp.sum(
+        jnp.where(live, p * (jnp.log(jnp.where(live, p, 1.0)) - jnp.where(live, log_q, 0.0)), 0.0)
+    )
 
 
 def grouped_matmul(rows: Array, weights: Array, group_sizes: Array) -> Array:

@@ -113,12 +113,13 @@ class span:
         _record(name, start_ns, end_ns, self.id, next(_ids), args)
 
 
-def count(name: str) -> None:
-    """One more of `name` in the process-wide tally: for a choice code
-    makes while it is traced (once a program, not once a step), where a
-    span has nothing to time. Whoever opened the span around the tracing
-    reads `counts()` before and after and keeps the difference."""
-    _counts[name] += 1
+def count(name: str, amount: int = 1) -> None:
+    """`amount` more of `name` in the process-wide tally: for a choice
+    code makes, or a size it is given, while it is traced (once a
+    program, not once a step), where a span has nothing to time. Whoever
+    opened the span around the tracing reads `counts()` before and after
+    and keeps the difference."""
+    _counts[name] += amount
 
 
 def counts() -> dict:

@@ -5,6 +5,7 @@ recurrence, gated attention and its partial rotary, the share test of the
 expert layer, three `Estimator.train` steps against the reference's loop,
 and the device flow's sequences against the reference's walks."""
 
+import contextlib
 import importlib.util
 import json
 import os
@@ -38,6 +39,7 @@ def bench():
 
         yield {
             "ref": _load(os.path.join(BENCH, "reference", "qwen3_next.py"), "ref_qwen3_next"),
+            "ref_keye_vl2": _load(os.path.join(BENCH, "reference", "keye_vl2.py"), "ref_keye_vl2"),
             "train": _load(os.path.join(BENCH, "reference", "train.py"), "ref_train"),
             "family": _load(os.path.join(BENCH, "families", "qwen3_next.py"), "fam_qwen3_next"),
             "graphs": graphs,
@@ -48,9 +50,8 @@ def bench():
         sys.path.remove(BENCH)
 
 
-@pytest.fixture(scope="module")
-def config():
-    with open(os.path.join(BENCH, "configs", "qwen3-next-80b-a3b-ep16.json")) as f:
+def _rehearsal(name):
+    with open(os.path.join(BENCH, "configs", f"{name}.json")) as f:
         full = json.load(f)
 
     def merge(base, over):
@@ -60,6 +61,11 @@ def config():
         return out
 
     return merge(full, full["rehearse"])
+
+
+@pytest.fixture(scope="module")
+def config():
+    return _rehearsal("qwen3-next-80b-a3b-ep16")
 
 
 def _highest(fn):
@@ -178,16 +184,22 @@ def _moe_layers(config):
     common = dict(
         num_experts=experts, top_k=top_k,
         expert_dim=config["moe_intermediate_size"],
-        shared_dim=config["shared_expert_intermediate_size"],
+        shared_dim=config.get("shared_expert_intermediate_size", 0),
     )
     return experts, top_k, lambda first, count: SparseMoE(held=(first, count), **common)
 
 
+@pytest.mark.parametrize("shared_expert", [True, False])
 @pytest.mark.parametrize("router_scale", [1.0, 40.0])
-def test_expert_shares_add_up_to_the_whole_layer(bench, config, router_scale):
+def test_expert_shares_add_up_to_the_whole_layer(bench, config, router_scale, shared_expert):
     """The parts all `num_experts / count` chips compute, the shared
     expert counted once, are the uncut layer: under an even router and
-    under one far from even, whose shares see unequal loads."""
+    under one far from even, whose shares see unequal loads; with a
+    shared expert, and in a layer that has none (`shared_dim` 0: the
+    other reference, whose configuration has no such key)."""
+    ref = bench["ref"]
+    if not shared_expert:
+        ref, config = bench["ref_keye_vl2"], _rehearsal("keye-vl2-30b-a3b-ep8")
     experts, top_k, layer = _moe_layers(config)
     hidden, count = config["hidden_size"], config["model"]["experts_here"][1]
     x = jax.random.normal(jax.random.PRNGKey(0), (96, hidden))
@@ -195,8 +207,11 @@ def test_expert_shares_add_up_to_the_whole_layer(bench, config, router_scale):
     params["router"] = params["router"] * router_scale
 
     uncut = dict(config, model=dict(config["model"], experts_here=[0, experts]))
-    want = _highest(bench["ref"].mixture)(params, x, uncut, "")
-    shared_only = _highest(bench["ref"].mixture)(params, x, uncut, "no_routed")
+    want = _highest(ref.mixture)(params, x, uncut, "")
+    assert shared_expert == ("shared_gate" in params)
+    shared_only = jnp.zeros_like(x)
+    if shared_expert:
+        shared_only = _highest(ref.mixture)(params, x, uncut, "no_routed")
 
     total, rows = jnp.zeros_like(x), 0
     for first in range(0, experts, count):
@@ -207,7 +222,7 @@ def test_expert_shares_add_up_to_the_whole_layer(bench, config, router_scale):
         # this chip's result against the reference given the same share
         cut = dict(config, model=dict(config["model"], experts_here=[first, count]))
         np.testing.assert_allclose(
-            y, _highest(bench["ref"].mixture)(mine, x, cut, ""), rtol=1e-4, atol=1e-6
+            y, _highest(ref.mixture)(mine, x, cut, ""), rtol=1e-4, atol=1e-6
         )
         total, rows = total + (y - shared_only), rows + int(routed)
     assert rows == x.shape[0] * top_k  # every assignment landed on one chip
@@ -215,14 +230,21 @@ def test_expert_shares_add_up_to_the_whole_layer(bench, config, router_scale):
     assert float(jnp.max(jnp.abs(want - shared_only))) > 1e-4  # the experts matter
 
 
+@pytest.mark.parametrize("held", [4, 8])
 @pytest.mark.parametrize("prefers_held", [0.0, 8.0])
-def test_expert_layer_drops_nothing_however_the_router_leans(bench, config, prefers_held):
+def test_expert_layer_drops_nothing_however_the_router_leans(bench, config, prefers_held, held):
     """Result and gradients of one chip's share against the reference,
     under an even router (less than one tile of rows lands here) and
     under one that sends every token's whole top-k here (all the tiles):
-    the loop over tiles, forward and backward, leaves no row out."""
-    _, top_k, layer = _moe_layers(config)
-    first, count = config["model"]["experts_here"]
+    the loop over tiles, forward and backward, leaves no row out. With 4
+    of 16 experts held a tile is as many assignments as there are tokens;
+    with 8 an even router would fill that, and a tile is twice as many
+    (`tile_rows`)."""
+    from euler_tpu.layers.moe import tile_rows
+
+    experts, top_k, layer = _moe_layers(config)
+    first, count = 0, held
+    config = dict(config, model=dict(config["model"], experts_here=[first, count]))
     part = layer(first, count)
     x = jax.random.normal(jax.random.PRNGKey(2), (96, config["hidden_size"]))
     params = part.init(jax.random.PRNGKey(3), x)["params"]
@@ -239,13 +261,31 @@ def test_expert_layer_drops_nothing_however_the_router_leans(bench, config, pref
     with jax.default_matmul_precision("highest"):
         (got, routed), g_got = jax.value_and_grad(program, (0, 1), has_aux=True)(params, x)
         (want, _), g_want = jax.value_and_grad(reference, (0, 1), has_aux=True)(params, x)
-    tiles = -(-int(routed) // x.shape[0])
-    assert tiles == (min(top_k, count) if prefers_held else 1), int(routed)
+    step = tile_rows(x.shape[0], top_k, count, experts)
+    assert step == x.shape[0] * (1 if held == 4 else 2)
+    tiles = -(-int(routed) // step)
+    assert tiles == (x.shape[0] * min(top_k, count) // step if prefers_held else 1), int(routed)
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for (path, a), b in zip(
         jax.tree_util.tree_leaves_with_path(g_got), jax.tree_util.tree_leaves(g_want)
     ):
         np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-6, err_msg=str(path))
+
+
+def test_tile_rows_leave_an_even_router_a_quarter_to_spare():
+    """The two cells' expert layers: 10 picks over 32 of 512 experts fill
+    five eighths of a tile of as many assignments as tokens, which stays;
+    8 picks over 16 of 128 would fill it, and the tile is twice that. A
+    layer that holds every expert takes its assignments as one tile."""
+    from euler_tpu.layers.moe import tile_rows
+
+    assert tile_rows(16384, 10, 32, 512) == 16384
+    assert tile_rows(16384, 8, 16, 128) == 2 * 16384
+    assert tile_rows(96, 6, 8, 8) == 6 * 96
+    for tokens, top_k, count, experts in [(16384, 10, 32, 512), (16384, 8, 16, 128), (100, 6, 5, 8)]:
+        step = tile_rows(tokens, top_k, count, experts)
+        assert (tokens * top_k) % step == 0  # whole tiles cover the assignments
+        assert step >= 1.25 * tokens * top_k * count / experts or step == tokens * top_k
 
 
 def test_grouped_matmul_keeps_rows_past_the_groups_out():
@@ -283,11 +323,15 @@ def _built(bench, config):
     return graph, built
 
 
-@pytest.mark.parametrize("seed", [3000000019])
-def test_three_train_steps_match_the_reference(bench, config, seed, tmp_path):
+def _program_first_steps(bench, config, seed, later_steps=contextlib.nullcontext):
+    """Three `Estimator.train` steps from seeded weights at float32
+    `highest`, the second and third inside `later_steps()`: the numbers
+    `benchmarks/run.py` compares (losses, the first gradient's norm per
+    leaf from Adam's first moment, each leaf's change), and the
+    reference's three steps as a function of a planted fault."""
     from euler_tpu.estimator import Estimator, EstimatorConfig
 
-    weights, train = bench["weights"], bench["train"]
+    weights = bench["weights"]
     graph, built = _built(bench, config)
     spec = bench["ref"].param_spec(config, graph)
     lr = config["optimizer"]["learning_rate"]
@@ -304,19 +348,8 @@ def test_three_train_steps_match_the_reference(bench, config, seed, tmp_path):
             k: float(v) / 0.1
             for k, v in weights.leaf_norms(weights.flatten(adam.mu)).items()
         }
-        # a profiler session, whoever started it: each step dispatched
-        # under it keeps the model's metric, on the device, in its span
-        with jax.profiler.trace(str(tmp_path)):
+        with later_steps():
             losses += est.train(2, log=False, save=False)
-    from euler_tpu.utils import trace
-
-    mine = [s for s in trace.spans() if s.name == "train.dispatch"][-3:]
-    assert "metric" not in mine[0].args  # step 0 ran under no session
-    kept = [s for s in mine if "metric" in s.args]
-    assert [(s.name, s.step) for s in kept] == [("train.dispatch", 1), ("train.dispatch", 2)]
-    held = config["model"]["experts_here"][1] / config["model"]["router_experts"]
-    for s in kept:
-        assert 0.5 * held < float(s.args["metric"]) < 2.0 * held
     change = weights.change_norms(
         weights.flatten(est.params), weights.make_params(spec, seed)
     )
@@ -325,13 +358,36 @@ def test_three_train_steps_match_the_reference(bench, config, seed, tmp_path):
         "change_norm": {k: float(v) for k, v in change.items()},
     }
     tables, loss_fn = bench["ref"].make(config, {}, graph)
-    want = train.first_steps(loss_fn, tables, spec, seed, lr)
+
+    def reference(fault=""):
+        return bench["train"].first_steps(loss_fn, tables, spec, seed, lr, fault=fault)
+
+    return got, reference
+
+
+@pytest.mark.parametrize("seed", [3000000019])
+def test_three_train_steps_match_the_reference(bench, config, seed, tmp_path):
+    from euler_tpu.utils import trace
+
+    # a profiler session, whoever started it: each step dispatched
+    # under it keeps the model's metric, on the device, in its span
+    got, reference = _program_first_steps(
+        bench, config, seed, lambda: jax.profiler.trace(str(tmp_path))
+    )
+    mine = [s for s in trace.spans() if s.name == "train.dispatch"][-3:]
+    assert "metric" not in mine[0].args  # step 0 ran under no session
+    kept = [s for s in mine if "metric" in s.args]
+    assert [(s.name, s.step) for s in kept] == [("train.dispatch", 1), ("train.dispatch", 2)]
+    held = config["model"]["experts_here"][1] / config["model"]["router_experts"]
+    for s in kept:
+        assert 0.5 * held < float(s.args["metric"]) < 2.0 * held
+    want = reference()
     assert set(got["grad_norm"]) == set(want["grad_norm"])  # one tree, leaf for leaf
-    compared = train.compare(got, want)
+    compared = bench["train"].compare(got, want)
     assert all(v < 1e-4 for v in compared.values()), compared
     # the experts' sum left out is another model: the comparison sees it
-    broken = train.first_steps(loss_fn, tables, spec, seed, lr, fault="no_routed")
-    assert max(train.compare(broken, want).values()) > 1e-2
+    broken = reference("no_routed")
+    assert max(bench["train"].compare(broken, want).values()) > 1e-2
 
 
 # -- (e) the device flow ------------------------------------------------------
