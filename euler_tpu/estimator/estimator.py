@@ -380,7 +380,7 @@ _COMPILE_EVENTS = {
 # program's `step.first_call` span carries how often each was taken.
 _TRACED_FORMS = (
     "agg_grid", "agg_scatter", "draw_rows", "draw_elements",
-    "dsa_layers", "dsa_topk", "dsa_core_masked",
+    "dsa_layers", "dsa_topk", "dsa_core_masked", "mixer_core_kept",
 )
 
 
@@ -400,7 +400,11 @@ def _first_call(program: str, tables: dict):
     theirs may pick (summed over those layers) and `dsa_core_masked` how
     many of them attend over the picked set as dense blocks under its
     mask, the one form there is
-    (`layers/sequence.py:IndexedSparseAttention`)."""
+    (`layers/sequence.py:IndexedSparseAttention`), `mixer_core_kept` the
+    mixers whose layer keeps their attention core's output through its
+    rematerialisation, so that the core's loop of query blocks runs
+    twice a step and not three times (`layers/sequence.py:_keep_core`:
+    every softmax mixer; a `GatedDeltaNet` keeps nothing)."""
     table_arg_bytes = sum(
         leaf.nbytes for leaf in jax.tree_util.tree_leaves(tables)
     )

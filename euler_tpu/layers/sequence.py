@@ -10,7 +10,10 @@ matmul precision; norms, gates, the delta rule's state, every softmax
 and the indexer's KL term are float32 (ops/seq_ops.py). Each mixer names
 its parts for a trace: `euler.gdn.{proj,conv,scan,out}`,
 `euler.attn.{proj,core,out}`,
-`euler.dsa.{proj,index,select,core,aux,out}`.
+`euler.dsa.{proj,index,select,core,aux,out}`. The two softmax mixers
+name their core's output `CORE_OUTPUT` (`_keep_core`): a rematerialised
+decoder layer keeps that one value of its forward
+(models/sequence_lm.py).
 """
 
 from __future__ import annotations
@@ -21,11 +24,27 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from euler_tpu.ops import seq_ops
 from euler_tpu.utils import trace
 
 _MATRIX = nn.initializers.normal(stddev=0.02)
+
+CORE_OUTPUT = "mixer_core"
+
+
+def _keep_core(o):
+    """Names a mixer's core output [B, G, R, T, d], the one thing the
+    rest of its layer wants from the loop over query blocks. The blocks
+    are checkpointed one by one: their residuals are their inputs, which
+    the projections remake, so a layer rematerialised under
+    `save_only_these_names(CORE_OUTPUT)` has no use for a second run of
+    the loop and each block's forward runs twice a step (forward, and
+    before its own backward), not three times. Tallied as
+    `mixer_core_kept`, once a mixer that is traced."""
+    trace.count("mixer_core_kept")
+    return checkpoint_name(o, CORE_OUTPUT)
 
 
 def rms(x, eps: float):
@@ -151,7 +170,8 @@ class GatedAttention(nn.Module):
     `rotary_dim` of the head, and a sigmoid gate on the output, computed
     from the same projection as the query (W_q holds, head by head,
     [query | gate]). The softmax runs block by block
-    (`seq_ops.blockwise_causal_attention`)."""
+    (`seq_ops.blockwise_causal_attention`); what it returns, before the
+    gate, is the layer's `CORE_OUTPUT`."""
 
     num_heads: int
     num_kv_heads: int
@@ -178,10 +198,10 @@ class GatedAttention(nn.Module):
             k = rotary(RMSNorm(self.eps, name="k_norm")(k), self.rope_theta, self.rotary_dim)
         with trace.scope("attn.core"):
             q = q.reshape(batch, length, nkv, nq // nkv, d).transpose(0, 2, 3, 1, 4)
-            o = seq_ops.blockwise_causal_attention(
+            o = _keep_core(seq_ops.blockwise_causal_attention(
                 q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
                 scale=d**-0.5, block=self.block,
-            )
+            ))
         with trace.scope("attn.out"):
             o = o.transpose(0, 3, 1, 2, 4).reshape(batch, length, nq, d)
             o = o * jax.nn.sigmoid(gate.astype(jnp.float32))
@@ -233,9 +253,13 @@ class IndexedSparseAttention(nn.Module):
     under the pick's mask (a gather of the picked rows would cost more
     than the masked products it saves), KL. The blocks of a run
     (`query_runs`) see the same stretch of keys and are one loop over one
-    program; a block is rematerialised in the backward pass, so neither
-    its [block, keys] index scores nor its heads' probabilities outlive
-    it.
+    program; a block is rematerialised before its own backward, so
+    neither its [block, keys] index scores nor its heads' probabilities
+    outlive it (23 GB a layer at 16,384 tokens), and its residuals are
+    its inputs. The runs' outputs put together, [B, G, R, T, d], are the
+    layer's `CORE_OUTPUT`: a decoder layer that is rematerialised keeps
+    them, so its second forward remakes the projections and never runs
+    the loops.
     """
 
     num_heads: int
@@ -322,5 +346,5 @@ class IndexedSparseAttention(nn.Module):
             outs.append(o_r.reshape(o_r.shape[:3] + (count * rows, d)))
             kl = kl + jnp.sum(kl_r)
         with trace.scope("dsa.out"):
-            o = jnp.concatenate(outs, axis=3).transpose(0, 3, 1, 2, 4)
+            o = _keep_core(jnp.concatenate(outs, axis=3)).transpose(0, 3, 1, 2, 4)
             return o.reshape(batch, length, nq * d) @ w_o, kl / (batch * length)

@@ -14,7 +14,8 @@ so `euler.embed` and the table's scatter-add gradient are the ones every
 embedding model here has), the layers, a final norm, an untied head and
 the mean next-token cross-entropy in float32. Every layer is
 rematerialised in the backward pass: what is kept of the forward is each
-layer's input.
+layer's input and, where the mixer names one, its attention core's output
+(`_KEEP_CORE`).
 
 The batch is what `DeviceSequenceFlow.sample` returns: int32 ids
 [B, T + 1]; positions 0..T-1 are the inputs and 1..T the targets. The
@@ -33,6 +34,7 @@ import optax
 
 from euler_tpu.layers.moe import SparseMoE
 from euler_tpu.layers.sequence import (
+    CORE_OUTPUT,
     GatedAttention,
     GatedDeltaNet,
     IndexedSparseAttention,
@@ -41,10 +43,20 @@ from euler_tpu.layers.sequence import (
 from euler_tpu.nn.encoders import Embedding
 from euler_tpu.utils import trace
 
+# What a rematerialised layer keeps of its forward besides its input: the
+# value its mixer names `CORE_OUTPUT` (`layers/sequence.py:_keep_core`
+# says why), so that the layer's second forward skips the mixer's loop of
+# query blocks, by far its longest part. The price is that value from
+# forward to backward: [B, T, heads * head_dim] float32 a layer, 268 MB
+# at 16,384 tokens of 32 x 128. A mixer that names nothing
+# (`GatedDeltaNet`) is rematerialised whole.
+_KEEP_CORE = jax.checkpoint_policies.save_only_these_names(CORE_OUTPUT)
+
 
 class DecoderLayer(nn.Module):
     """-> (h, the assignments routed to held experts, the mixer's own
-    loss or None)."""
+    loss or None). Run under `nn.remat` (`DecoderLM._layer`): nothing in
+    here outlives the forward but what `_KEEP_CORE` names."""
 
     mixer: nn.Module
     moe: nn.Module
@@ -94,6 +106,8 @@ class DecoderLM(nn.Module):
         raise NotImplementedError
 
     def _layer(self, index: int):
+        """Layer `index`, rematerialised in the backward pass under
+        `_KEEP_CORE`."""
         moe = SparseMoE(
             num_experts=self.num_experts,
             top_k=self.num_experts_per_tok,
@@ -103,7 +117,7 @@ class DecoderLM(nn.Module):
             norm_topk=self.norm_topk_prob,
             parent=None,
         )
-        return nn.remat(DecoderLayer)(
+        return nn.remat(DecoderLayer, policy=_KEEP_CORE)(
             self.mixer(index), moe, self.rms_norm_eps, name=f"layer_{index}",
         )
 

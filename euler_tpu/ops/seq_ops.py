@@ -171,8 +171,11 @@ def blockwise_causal_attention(
     a time: a block's scores against the keys up to its end are the
     largest tensor there is ([B, G, R, block, end] float32), never the
     whole [T, T] square, and the upper half beyond a block's own diagonal
-    square is not computed at all. Each block is rematerialised in the
-    backward pass, so no block's scores outlive it.
+    square is not computed at all. Each block is rematerialised before
+    its own backward, so no block's scores outlive it and a block's
+    residuals are its inputs: a caller that is itself rematerialised
+    and keeps the result (`layers/sequence.py:_keep_core`) never runs
+    the blocks in its second forward.
 
     q [B, G, R, T, d] (G key/value heads, R query heads to each),
     k, v [B, G, T, d]. Returns [B, G, R, T, d].

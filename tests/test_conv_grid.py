@@ -385,6 +385,14 @@ def test_first_call_span_carries_the_draw_forms(tmp_path, kind, want):
     assert (args["draw_rows"], args["draw_elements"]) == want
 
 
+@pytest.mark.parametrize("kind", ["sage", "skipgram"])
+def test_first_call_span_of_a_graph_model_keeps_no_mixer_core(tmp_path, kind):
+    """`mixer_core_kept` is the sequence mixers' (`layers/sequence.py`):
+    a graph model's step program has none."""
+    (args,) = _first_calls(tmp_path, kind).values()
+    assert (args["mixer_core_kept"], args["dsa_layers"]) == (0, 0)
+
+
 def test_conv_scope_has_no_scope_nested_in_it():
     """`benchmarks/scoped.py` names an op by its innermost `euler.*`
     scope and `conv_ms` reads `conv.forward` / `conv.backward` exactly."""

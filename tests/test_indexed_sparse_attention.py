@@ -9,13 +9,24 @@ indexer's gradient comes from, the scopes' names, and three
 import os
 import re
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from test_sequence_lm import BENCH, _built, _highest, _load, _program_first_steps, _rehearsal
+from test_sequence_lm import (
+    BENCH,
+    _assert_keeping_the_core_changes_no_bit,
+    _built,
+    _highest,
+    _load,
+    _named,
+    _one_layer_both_ways,
+    _program_first_steps,
+    _rehearsal,
+)
 
 INDEXER = ("index_q", "index_k", "index_w", "index_k_norm_w", "index_k_norm_b")
 
@@ -337,6 +348,27 @@ def test_model_takes_positions_and_text_needs_none(bench, config):
     assert abs(float(loss_at(image)) - float(loss)) > 1e-6
 
 
+def test_keeping_the_attention_core_changes_no_bit(bench, config, monkeypatch):
+    _assert_keeping_the_core_changes_no_bit(bench, config, monkeypatch)
+
+
+def test_a_layer_keeps_its_core_output_and_no_blocks_scores(bench, config, monkeypatch):
+    """One decoder layer: of the mixer's loops over query blocks the
+    backward keeps their output [B, G, R, T, d] and nothing with a
+    block's rows (index scores [B, block, keys], probabilities
+    [B, G, R, block, keys]); rematerialised whole it would keep neither,
+    and run every loop once more."""
+    model = _built(bench, config)[1]["model"].clone(num_layers=1, attention_block=8)
+    (kept, program), (whole, whole_program), core = _one_layer_both_ways(model, monkeypatch)
+    assert _named(kept) == [core] and _named(whole) == [] and len(kept) == len(whole) + 1
+    assert not [shape for shape, _ in kept if len(shape) > 1 and shape[-2] == 8]
+    assert max(np.prod(shape) for shape, _ in kept) <= 2 * 64 * max(model.hidden_size, core[1] * core[2] * core[4])
+    program, whole_program = program.compile().as_text(), whole_program.compile().as_text()
+    # keys 8, 16, 32, 64: four runs, each one loop fewer (and the pick's inside it)
+    assert whole_program.count(" while(") - program.count(" while(") >= 4
+    assert whole_program.count(" dot(") > program.count(" dot(")
+
+
 @pytest.fixture(scope="module")
 def three_steps(bench, config):
     """Three `Estimator.train` steps from seeded weights, once for the
@@ -344,9 +376,10 @@ def three_steps(bench, config):
     of the step program."""
     from euler_tpu.utils import trace
 
-    before = len(trace.spans())
+    since = time.perf_counter_ns()  # not a count of spans: the record is bounded
     got, reference = _program_first_steps(bench, config, 3000000023)
-    return {"got": got, "spans": trace.spans()[before:], "reference": reference}
+    spans = [s for s in trace.spans() if s.start_ns >= since]
+    return {"got": got, "spans": spans, "reference": reference}
 
 
 def test_first_call_span_carries_the_mixers_forms(config, three_steps):
@@ -356,6 +389,7 @@ def test_first_call_span_carries_the_mixers_forms(config, three_steps):
     )
     layers, topk = config["num_hidden_layers"], config["sa_config"]["topk"]
     assert (args["dsa_layers"], args["dsa_topk"], args["dsa_core_masked"]) == (layers, layers * topk, layers)
+    assert args["mixer_core_kept"] == layers  # every layer's mixer is a softmax attention
     assert (args["agg_grid"], args["draw_rows"], args["draw_elements"]) == (0, 0, 1)
 
 

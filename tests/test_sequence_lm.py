@@ -7,9 +7,12 @@ and the device flow's sequences against the reference's walks."""
 
 import contextlib
 import importlib.util
+import io
 import json
 import os
+import re
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -390,7 +393,118 @@ def test_three_train_steps_match_the_reference(bench, config, seed, tmp_path):
     assert max(bench["train"].compare(broken, want).values()) > 1e-2
 
 
-# -- (e) the device flow ------------------------------------------------------
+# -- (e) what a rematerialised layer keeps ------------------------------------
+
+
+def _loss_and_grads(bench, config, seed=11):
+    """The rehearsal model's loss and gradients on one drawn batch, as
+    one compiled program."""
+    graph, built = _built(bench, config)
+    weights = bench["weights"]
+    params = weights.nest(weights.make_params(bench["ref"].param_spec(config, graph), seed))
+    ids = jax.jit(built["flow"].sample)(bench["train"].step_key(seed, 0))
+    return jax.jit(jax.value_and_grad(lambda p: built["model"].apply(p, ids)[1]))(params)
+
+
+def _assert_keeping_the_core_changes_no_bit(bench, config, monkeypatch):
+    """Loss and every gradient leaf under `_KEEP_CORE` against the same
+    model with each layer rematerialised whole."""
+    from euler_tpu.models import sequence_lm
+
+    loss, grads = _loss_and_grads(bench, config)
+    monkeypatch.setattr(sequence_lm, "_KEEP_CORE", None)
+    loss_whole, grads_whole = _loss_and_grads(bench, config)
+    assert float(loss) == float(loss_whole) and np.isfinite(float(loss))
+    for (path, a), b in zip(
+        jax.tree_util.tree_leaves_with_path(grads), jax.tree_util.tree_leaves(grads_whole)
+    ):
+        np.testing.assert_array_equal(a, b, err_msg=str(path))
+        assert float(jnp.max(jnp.abs(a))) > 0, path
+
+
+def _kept_of_the_forward(model, params, ids):
+    """[(shape, where from)] of what the backward pass of `model`'s loss
+    keeps of its forward that is no argument, by
+    `jax.ad_checkpoint.print_saved_residuals`."""
+    out = io.StringIO()
+    with contextlib.redirect_stdout(out):
+        jax.ad_checkpoint.print_saved_residuals(lambda p: model.apply(p, ids)[1], params)
+    kept = []
+    for line in out.getvalue().splitlines():
+        shape, origin = re.match(r"\w+\[([\d,]*)\] (.*)", line).groups()
+        if not origin.startswith("from the argument"):
+            kept.append((tuple(int(n) for n in shape.split(",") if n), origin))
+    return kept
+
+
+def _one_layer_both_ways(model, monkeypatch):
+    """A one-layer model's loss-and-gradient step under `_KEEP_CORE` and
+    with the layer rematerialised whole: for each `(what the backward
+    keeps of the forward, the lowered program)`, and the shape
+    [B, G, R, T, d] of the mixer's core output."""
+    from euler_tpu.models import sequence_lm
+
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 65), 0, model.vocab_size)
+    params = model.init(jax.random.PRNGKey(0), ids)
+
+    def look():  # a jit of its own each time: the policy is no argument of the step
+        step = jax.jit(jax.value_and_grad(lambda p: model.apply(p, ids)[1]))
+        return _kept_of_the_forward(model, params, ids), step.lower(params)
+
+    kept = look()
+    monkeypatch.setattr(sequence_lm, "_KEEP_CORE", None)
+    core = (2, model.num_kv_heads, model.num_heads // model.num_kv_heads, 64, model.head_dim)
+    return kept, look(), core
+
+
+def _named(kept):
+    return [shape for shape, origin in kept if "_keep_core" in origin]
+
+
+def test_keeping_the_attention_core_changes_no_bit(bench, config, monkeypatch):
+    _assert_keeping_the_core_changes_no_bit(bench, config, monkeypatch)
+
+
+def test_an_attention_layer_keeps_its_blocks_output_and_no_scores(bench, config, monkeypatch):
+    """One `GatedAttention` layer: the backward keeps the blocks' output
+    [B, G, R, T, d] and nothing else of five axes (a block's scores are
+    [B, G, R, block, keys]), and the layer's second forward runs no
+    block."""
+    model = _built(bench, config)[1]["model"].clone(num_layers=1, full_attention_interval=1)
+    (kept, program), (whole, whole_program), core = _one_layer_both_ways(model, monkeypatch)
+    assert _named(kept) == [core] and _named(whole) == [] and len(kept) == len(whole) + 1
+    assert not [shape for shape, _ in kept if len(shape) > 3 and shape != core]
+    assert whole_program.compile().as_text().count(" dot(") > program.compile().as_text().count(" dot(")
+
+
+def test_a_deltanet_layer_keeps_nothing(bench, config, monkeypatch):
+    """A `GatedDeltaNet` names nothing: its layer's program is the one
+    rematerialised whole, to the letter."""
+    model = _built(bench, config)[1]["model"].clone(num_layers=1)
+    (kept, program), (whole, whole_program), _ = _one_layer_both_ways(model, monkeypatch)
+    assert _named(kept) == [] and kept == whole
+    assert program.as_text() == whole_program.as_text()
+
+
+def test_first_call_span_counts_the_one_core_kept(bench, config):
+    """Of the period's four mixers the `GatedAttention` alone names its
+    core's output; the three `GatedDeltaNet` layers keep nothing."""
+    from euler_tpu.estimator import Estimator, EstimatorConfig
+    from euler_tpu.utils import trace
+
+    _, built = _built(bench, config)
+    assert built["model"].num_layers == built["model"].full_attention_interval == 4
+    cfg = EstimatorConfig(model_dir="/tmp/never_saved", log_steps=10**9, steps_per_call=1)
+    since = time.perf_counter_ns()  # not a count of spans: the record is bounded
+    Estimator(built["model"], built["flow"], cfg).train(1, log=False, save=False)
+    (args,) = [
+        s.args for s in trace.spans() if s.name == "step.first_call" and s.start_ns >= since
+    ]
+    assert args["program"] == "train_step" and args["mixer_core_kept"] == 1
+    assert (args["dsa_layers"], args["draw_elements"]) == (0, 1)
+
+
+# -- (f) the device flow ------------------------------------------------------
 
 
 def test_device_sequence_flow_draws_the_reference_walks(bench, config):
