@@ -381,6 +381,8 @@ _COMPILE_EVENTS = {
 _TRACED_FORMS = (
     "agg_grid", "agg_scatter", "draw_rows", "draw_elements",
     "dsa_layers", "dsa_topk", "dsa_core_masked", "mixer_core_kept",
+    "swa_layers", "swa_window", "attn_full_layers", "dense_layers",
+    "router_sigmoid",
 )
 
 
@@ -404,7 +406,11 @@ def _first_call(program: str, tables: dict):
     mixers whose layer keeps their attention core's output through its
     rematerialisation, so that the core's loop of query blocks runs
     twice a step and not three times (`layers/sequence.py:_keep_core`:
-    every softmax mixer; a `GatedDeltaNet` keeps nothing)."""
+    every softmax mixer; a `GatedDeltaNet` keeps nothing), `swa_layers`
+    the `GatedAttention` layers with a window, `swa_window` their windows
+    summed, `attn_full_layers` those without one, `dense_layers` the
+    decoder layers whose feed-forward is a `DenseMLP`, `router_sigmoid`
+    the expert layers that route by sigmoid scores (`layers/moe.py`)."""
     table_arg_bytes = sum(
         leaf.nbytes for leaf in jax.tree_util.tree_leaves(tables)
     )

@@ -17,7 +17,7 @@ from euler_tpu.layers.conv import (  # noqa: F401
     TAGConv,
     degrees,
 )
-from euler_tpu.layers.moe import SparseMoE  # noqa: F401
+from euler_tpu.layers.moe import DenseMLP, SparseMoE  # noqa: F401
 from euler_tpu.layers.sequence import (  # noqa: F401
     GatedAttention,
     GatedDeltaNet,

@@ -1,5 +1,6 @@
 """`SparseMoE`: a mixture-of-experts feed-forward layer that is told which
-of the routed experts it holds.
+of the routed experts it holds; `DenseMLP`, the dense layer that stands
+in its place in a model's leading layers.
 
 Expert parallelism divides a layer's experts over chips; every chip
 routes every token over ALL experts (router width and top-k as
@@ -141,7 +142,16 @@ class SparseMoE(nn.Module):
     `E(x) = W_down (SiLU(W_gate x) * W_up x)`. Every assignment to a held
     expert is computed, however many there are (`held_experts`).
     `shared_dim` 0 is a layer with no shared expert: no such term and no
-    such parameters.
+    such parameters; `shared_gated` False adds the shared expert as it is,
+    with no sigmoid mix and no `w_s`.
+
+    `score` "sigmoid" is the router of the aux-loss-free balancing
+    (DeepSeek-V3's): `p = sigmoid(x W_r)`, each expert scored by itself;
+    the pick is made on `p + b`, `b` an `expert_bias` that no gradient
+    reaches (whoever balances the load moves it; it starts at zero), and
+    the kept weights are the `p` themselves, without it, divided by their
+    sum + 1e-20. Either router's kept weights are multiplied by
+    `route_scale`.
     """
 
     num_experts: int
@@ -150,6 +160,9 @@ class SparseMoE(nn.Module):
     shared_dim: int
     held: tuple = (0, 0)  # (first, count); count 0 = all of them
     norm_topk: bool = True
+    score: str = "softmax"  # or "sigmoid"
+    route_scale: float = 1.0
+    shared_gated: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -173,9 +186,22 @@ class SparseMoE(nn.Module):
                 x.astype(jnp.float32), w_router,
                 precision=jax.lax.Precision.HIGHEST,
             )
-            top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-            if self.norm_topk:
-                top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            if self.score == "sigmoid":
+                trace.count("router_sigmoid")
+                bias = self.param(
+                    "expert_bias", nn.initializers.zeros, (self.num_experts,), jnp.float32
+                )
+                scores = jax.nn.sigmoid(logits)
+                _, top_e = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
+                top_p = jnp.take_along_axis(scores, top_e, axis=-1)
+                if self.norm_topk:
+                    top_p = top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
+            else:
+                top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+                if self.norm_topk:
+                    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            if self.route_scale != 1.0:
+                top_p = top_p * self.route_scale
         with trace.scope("moe.dispatch"):
             here = (top_e >= first) & (top_e < first + count)
             # assignments sorted by held expert, those to absent experts last
@@ -190,8 +216,31 @@ class SparseMoE(nn.Module):
             s_gate = self.param("shared_gate", _MATRIX, shape, jnp.float32)
             s_up = self.param("shared_up", _MATRIX, shape, jnp.float32)
             s_down = self.param("shared_down", _MATRIX, shape[::-1], jnp.float32)
-            s_mix = self.param("shared_mix", _MATRIX, (hidden, 1), jnp.float32)
+            if self.shared_gated:
+                s_mix = self.param("shared_mix", _MATRIX, (hidden, 1), jnp.float32)
             with trace.scope("moe.shared"):
-                mix = jax.nn.sigmoid((x @ s_mix).astype(jnp.float32))
-                y = y + mix * _swiglu(x, s_gate, s_up, s_down, jnp.matmul)
+                if self.shared_gated:
+                    mix = jax.nn.sigmoid((x @ s_mix).astype(jnp.float32))
+                    y = y + mix * _swiglu(x, s_gate, s_up, s_down, jnp.matmul)
+                else:
+                    y = y + _swiglu(x, s_gate, s_up, s_down, jnp.matmul)
         return y, ends[-1]
+
+
+class DenseMLP(nn.Module):
+    """The dense feed-forward a model's leading layers have in an expert
+    layer's place: `W_down (SiLU(W_gate x) * W_up x)` at `dim`, under
+    `euler.mlp`. Called as an expert layer is: x [N, H] -> (y [N, H], 0
+    assignments routed)."""
+
+    dim: int
+
+    @nn.compact
+    def __call__(self, x):
+        shape = (x.shape[1], self.dim)
+        w_gate = self.param("gate", _MATRIX, shape, jnp.float32)
+        w_up = self.param("up", _MATRIX, shape, jnp.float32)
+        w_down = self.param("down", _MATRIX, shape[::-1], jnp.float32)
+        trace.count("dense_layers")
+        with trace.scope("mlp"):
+            return _swiglu(x, w_gate, w_up, w_down, jnp.matmul), jnp.zeros((), jnp.int32)

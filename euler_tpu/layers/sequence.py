@@ -1,6 +1,7 @@
 """Sequence mixers of decoder-only language models and what they share:
 `GatedDeltaNet` (linear attention by the gated delta rule),
-`GatedAttention` (causal softmax attention with an output gate),
+`GatedAttention` (causal softmax attention with an output gate, over
+every earlier key or over a sliding window of them),
 `IndexedSparseAttention` (causal softmax attention over the keys a
 learned indexer picks for each query), the zero-centred `RMSNorm` and
 the rotary embedding, by one position or by three.
@@ -9,8 +10,9 @@ Inputs are [B, T, H] float32. Projections run at the backend's default
 matmul precision; norms, gates, the delta rule's state, every softmax
 and the indexer's KL term are float32 (ops/seq_ops.py). Each mixer names
 its parts for a trace: `euler.gdn.{proj,conv,scan,out}`,
-`euler.attn.{proj,core,out}`,
-`euler.dsa.{proj,index,select,core,aux,out}`. The two softmax mixers
+`euler.attn.{proj,core,out}` (`euler.swa.*` where the layer has a window),
+`euler.dsa.{proj,index,select,core,aux,out}`. Every mixer is called as
+`(x, positions) -> (y, its own loss or None)`. The two softmax mixers
 name their core's output `CORE_OUTPUT` (`_keep_core`): a rematerialised
 decoder layer keeps that one value of its forward
 (models/sequence_lm.py).
@@ -103,6 +105,8 @@ class GatedDeltaNet(nn.Module):
     the result is RMS-normalised per head, gated by SiLU(z) and projected
     back. Columns of W_qkvz lie [q | k | v | z], each head by head (HF
     interleaves them by key head: a permutation of columns).
+    x [B, T, H] -> (y [B, T, H], None: no loss of its own); the order of
+    the sequence is all the position it knows.
     """
 
     num_k_heads: int
@@ -114,7 +118,7 @@ class GatedDeltaNet(nn.Module):
     eps: float = 1e-6
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None):
         batch, length, hidden = x.shape
         nk, nv, dk, dv = (
             self.num_k_heads, self.num_v_heads, self.head_k_dim, self.head_v_dim
@@ -161,17 +165,22 @@ class GatedDeltaNet(nn.Module):
             o = o.transpose(0, 2, 1, 3)  # [B, T, nv, dv]
             gate = jax.nn.silu(z.reshape(batch, length, nv, dv))
             o = rms(o, self.eps) * (1.0 + w_norm) * gate
-            return o.reshape(batch, length, value_dim) @ w_out
+            return o.reshape(batch, length, value_dim) @ w_out, None
 
 
 class GatedAttention(nn.Module):
     """Causal softmax attention with grouped queries, a zero-centred
     RMSNorm on each query and key head, rotary embedding on the first
-    `rotary_dim` of the head, and a sigmoid gate on the output, computed
-    from the same projection as the query (W_q holds, head by head,
-    [query | gate]). The softmax runs block by block
+    `rotary_dim` of the head (0: no rotary, and the layer knows no
+    position at all), and a sigmoid gate on the output, computed from
+    the same projection as the query (W_q holds, head by head,
+    [query | gate]). With a `window`, a query sees that many keys, itself
+    among them, and the layer's scopes are `euler.swa.*`, so that a trace
+    tells the two kinds of layer apart; without one it sees every earlier
+    key, under `euler.attn.*`. The softmax runs block by block
     (`seq_ops.blockwise_causal_attention`); what it returns, before the
-    gate, is the layer's `CORE_OUTPUT`."""
+    gate, is the layer's `CORE_OUTPUT`.
+    x [B, T, H] -> (y [B, T, H], None: no loss of its own)."""
 
     num_heads: int
     num_kv_heads: int
@@ -180,32 +189,43 @@ class GatedAttention(nn.Module):
     rotary_dim: int = 64
     block: int = 512
     eps: float = 1e-6
+    window: int | None = None
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None):
         batch, length, hidden = x.shape
         nq, nkv, d = self.num_heads, self.num_kv_heads, self.head_dim
         w_q = self.param("q_proj", _MATRIX, (hidden, nq * d * 2), jnp.float32)
         w_k = self.param("k_proj", _MATRIX, (hidden, nkv * d), jnp.float32)
         w_v = self.param("v_proj", _MATRIX, (hidden, nkv * d), jnp.float32)
         w_o = self.param("o_proj", _MATRIX, (nq * d, hidden), jnp.float32)
-        with trace.scope("attn.proj"):
+        kind = "attn" if self.window is None else "swa"
+        if self.window is None:
+            trace.count("attn_full_layers")
+        else:
+            trace.count("swa_layers")
+            trace.count("swa_window", self.window)
+        with trace.scope(f"{kind}.proj"):
             qg = (x @ w_q).reshape(batch, length, nq, 2 * d)
             q, gate = qg[..., :d], qg[..., d:]
             k = (x @ w_k).reshape(batch, length, nkv, d)
             v = (x @ w_v).reshape(batch, length, nkv, d)
-            q = rotary(RMSNorm(self.eps, name="q_norm")(q), self.rope_theta, self.rotary_dim)
-            k = rotary(RMSNorm(self.eps, name="k_norm")(k), self.rope_theta, self.rotary_dim)
-        with trace.scope("attn.core"):
+
+            def turn(a):
+                return rotary(a, self.rope_theta, self.rotary_dim) if self.rotary_dim else a
+
+            q = turn(RMSNorm(self.eps, name="q_norm")(q))
+            k = turn(RMSNorm(self.eps, name="k_norm")(k))
+        with trace.scope(f"{kind}.core"):
             q = q.reshape(batch, length, nkv, nq // nkv, d).transpose(0, 2, 3, 1, 4)
             o = _keep_core(seq_ops.blockwise_causal_attention(
                 q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
-                scale=d**-0.5, block=self.block,
+                scale=d**-0.5, block=self.block, window=self.window,
             ))
-        with trace.scope("attn.out"):
+        with trace.scope(f"{kind}.out"):
             o = o.transpose(0, 3, 1, 2, 4).reshape(batch, length, nq, d)
             o = o * jax.nn.sigmoid(gate.astype(jnp.float32))
-            return o.reshape(batch, length, nq * d) @ w_o
+            return o.reshape(batch, length, nq * d) @ w_o, None
 
 
 def query_runs(length: int, block: int, topk: int) -> list:

@@ -1,18 +1,24 @@
 """Decoder-only language models as `Estimator` models: one decoder,
-`DecoderLM`, whose layers differ in their mixer alone, and the two
-architectures that plan their mixers on it.
+`DecoderLM`, whose layers differ in their mixer and in whether their
+feed-forward is dense, and the three architectures that plan their
+layers on it.
 
-Decoder layer l: `h += Mixer_l(norm(h))`, then `h += MoE(norm(h))`; the
-feed-forward is a `SparseMoE` that holds `experts_here` of the routed
-experts (layers/moe.py). `Qwen3NextLM` (HF `modeling_qwen3_next.py`)
-plans `GatedAttention` where `(l + 1) % full_attention_interval == 0` and
-`GatedDeltaNet` elsewhere, with a shared expert; `KeyeVL2LM`
-(Keye-VL-2.0's language model) plans `IndexedSparseAttention` in every
-layer, no shared expert, and adds the mean of the layers' indexer losses
-to the loss (layers/sequence.py). Embedding (`nn/encoders.py:Embedding`,
+Decoder layer l: `h += Mixer_l(norm(h))`, then `h += FFN(norm(h))`; with
+`sandwich_norms` each sublayer's output passes a norm of its own before
+it is added (four norms a layer). The feed-forward is a `SparseMoE` that
+holds `experts_here` of the routed experts, or in the first
+`num_dense_layers` layers a `DenseMLP` (layers/moe.py). `Qwen3NextLM`
+(HF `modeling_qwen3_next.py`) plans `GatedAttention` where `(l + 1) %
+full_attention_interval == 0` and `GatedDeltaNet` elsewhere, with a
+shared expert; `KeyeVL2LM` (Keye-VL-2.0's language model) plans
+`IndexedSparseAttention` in every layer, no shared expert, and adds the
+mean of the layers' indexer losses to the loss; `TrinityLM` (Arcee's
+Trinity, HF `afmoe`) plans `GatedAttention` by `layer_types`, with a
+window and rotary or with neither, behind a sigmoid-scored router
+(layers/sequence.py). Embedding (`nn/encoders.py:Embedding`,
 so `euler.embed` and the table's scatter-add gradient are the ones every
-embedding model here has), the layers, a final norm, an untied head and
-the mean next-token cross-entropy in float32. Every layer is
+embedding model here has) times `embed_scale`, the layers, a final norm,
+an untied head and the mean next-token cross-entropy in float32. Every layer is
 rematerialised in the backward pass: what is kept of the forward is each
 layer's input and, where the mixer names one, its attention core's output
 (`_KEEP_CORE`).
@@ -32,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from euler_tpu.layers.moe import SparseMoE
+from euler_tpu.layers.moe import DenseMLP, SparseMoE
 from euler_tpu.layers.sequence import (
     CORE_OUTPUT,
     GatedAttention,
@@ -55,28 +61,36 @@ _KEEP_CORE = jax.checkpoint_policies.save_only_these_names(CORE_OUTPUT)
 
 class DecoderLayer(nn.Module):
     """-> (h, the assignments routed to held experts, the mixer's own
-    loss or None). Run under `nn.remat` (`DecoderLM._layer`): nothing in
-    here outlives the forward but what `_KEEP_CORE` names."""
+    loss or None). `mixer` is called as `(x, positions) -> (y, its own
+    loss or None)`; the feed-forward, `mlp` where the layer has one and
+    else `moe`, as `x [N, H] -> (y, assignments routed)`. With `sandwich`
+    each sublayer's output is normalised before it is added. Run under
+    `nn.remat` (`DecoderLM._layer`): nothing in here outlives the forward
+    but what `_KEEP_CORE` names."""
 
     mixer: nn.Module
-    moe: nn.Module
+    moe: nn.Module | None
     eps: float = 1e-6
+    mlp: nn.Module | None = None  # a dense feed-forward in the experts' place
+    sandwich: bool = False
 
     @nn.compact
     def __call__(self, h, positions):
-        x, aux = RMSNorm(self.eps, name="input_norm")(h), None
-        if isinstance(self.mixer, IndexedSparseAttention):
-            mixed, aux = self.mixer(x, positions)
-        else:  # positions 0..T-1, or none at all
-            mixed = self.mixer(x)
+        mixed, aux = self.mixer(RMSNorm(self.eps, name="input_norm")(h), positions)
+        if self.sandwich:
+            mixed = RMSNorm(self.eps, name="mixer_out_norm")(mixed)
         h = h + mixed
         x = RMSNorm(self.eps, name="post_norm")(h)
-        y, routed = self.moe(x.reshape(-1, x.shape[-1]))
-        return h + y.reshape(h.shape), routed, aux
+        feed_forward = self.moe if self.mlp is None else self.mlp
+        y, routed = feed_forward(x.reshape(-1, x.shape[-1]))
+        y = y.reshape(h.shape)
+        if self.sandwich:
+            y = RMSNorm(self.eps, name="ffn_out_norm")(y)
+        return h + y, routed, aux
 
 
 class DecoderLM(nn.Module):
-    """The decoder both architectures share; a subclass plans `mixer(l)`.
+    """The decoder the architectures share; a subclass plans `mixer(l)`.
     Returns `(emb, loss, "routed_share", share)`: the final hidden states
     [B, T, H], the loss, and the share of the step's token-expert
     assignments that landed on experts held here (`experts_here[1] /
@@ -98,7 +112,15 @@ class DecoderLM(nn.Module):
     moe_intermediate_size: int = 512
     shared_expert_intermediate_size: int = 512  # 0: no shared expert
     norm_topk_prob: bool = True
+    router_score: str = "softmax"  # or "sigmoid" (layers/moe.py)
+    route_scale: float = 1.0
+    shared_expert_gated: bool = True
     experts_here: tuple = (0, 0)  # (first, count); count 0 = all
+    # the first layers' feed-forward is dense, of this width
+    num_dense_layers: int = 0
+    intermediate_size: int = 0
+    sandwich_norms: bool = False
+    embed_scale: float = 1.0
     rms_norm_eps: float = 1e-6
     loss_chunks: int = 1  # the head and loss run over T in this many parts
 
@@ -108,17 +130,25 @@ class DecoderLM(nn.Module):
     def _layer(self, index: int):
         """Layer `index`, rematerialised in the backward pass under
         `_KEEP_CORE`."""
-        moe = SparseMoE(
-            num_experts=self.num_experts,
-            top_k=self.num_experts_per_tok,
-            expert_dim=self.moe_intermediate_size,
-            shared_dim=self.shared_expert_intermediate_size,
-            held=tuple(self.experts_here),
-            norm_topk=self.norm_topk_prob,
-            parent=None,
-        )
+        moe = mlp = None
+        if index < self.num_dense_layers:
+            mlp = DenseMLP(self.intermediate_size, parent=None)
+        else:
+            moe = SparseMoE(
+                num_experts=self.num_experts,
+                top_k=self.num_experts_per_tok,
+                expert_dim=self.moe_intermediate_size,
+                shared_dim=self.shared_expert_intermediate_size,
+                held=tuple(self.experts_here),
+                norm_topk=self.norm_topk_prob,
+                score=self.router_score,
+                route_scale=self.route_scale,
+                shared_gated=self.shared_expert_gated,
+                parent=None,
+            )
         return nn.remat(DecoderLayer, policy=_KEEP_CORE)(
-            self.mixer(index), moe, self.rms_norm_eps, name=f"layer_{index}",
+            self.mixer(index), moe, self.rms_norm_eps, mlp, self.sandwich_norms,
+            name=f"layer_{index}",
         )
 
     @nn.compact
@@ -129,6 +159,9 @@ class DecoderLM(nn.Module):
                 jnp.arange(tokens.shape[1]), (len(self.rope_sections),) + tokens.shape
             )
         h = Embedding(self.vocab_size, self.hidden_size, name="embed")(tokens)
+        if self.embed_scale != 1.0:
+            with trace.scope("embed"):
+                h = h * self.embed_scale
         routed, own = jnp.zeros((), jnp.int32), []
         for index in range(self.num_layers):
             h, here, aux = self._layer(index)(h, positions)
@@ -162,7 +195,8 @@ class DecoderLM(nn.Module):
             loss = total / targets.size
             if own:  # the mixers' own losses, coefficient 1
                 loss = loss + sum(own) / len(own)
-        assignments = tokens.size * self.num_experts_per_tok * self.num_layers
+        expert_layers = self.num_layers - self.num_dense_layers
+        assignments = tokens.size * self.num_experts_per_tok * expert_layers
         share = routed.astype(jnp.float32) / assignments
         return emb, loss, "routed_share", share
 
@@ -238,5 +272,54 @@ class KeyeVL2LM(DecoderLM):
             sections=tuple(self.rope_sections),
             block=self.attention_block,
             eps=self.rms_norm_eps,
+            parent=None,  # adopted by the layer, as its `mixer`
+        )
+
+
+class TrinityLM(DecoderLM):
+    """`GatedAttention` in every layer, of the kind `layer_types` names:
+    "sliding_attention" sees `sliding_window` keys and turns queries and
+    keys by rotary over the whole head, "full_attention" sees every
+    earlier key and has no rotary, so no position at all. The first
+    `num_dense_layers` feed-forwards are dense; the others route by
+    sigmoid scores, scale the kept weights by `route_scale` and add one
+    shared expert as it is. Sandwich norms; the embedding enters times
+    `embed_scale` (sqrt(hidden) under muP). The router's `expert_bias`
+    stays where it starts: the balancing rule that moves it between
+    steps belongs to a train step that carries a state no gradient
+    updates."""
+
+    num_heads: int = 32
+    num_kv_heads: int = 4
+    head_dim: int = 128
+    rope_theta: float = 1e4
+    layer_types: tuple = ("sliding_attention",) * 3 + ("full_attention",)
+    sliding_window: int = 2048
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: int = 1024
+    shared_expert_intermediate_size: int = 1024
+    router_score: str = "sigmoid"
+    route_scale: float = 2.826
+    shared_expert_gated: bool = False
+    num_dense_layers: int = 2
+    intermediate_size: int = 6144
+    sandwich_norms: bool = True
+    rms_norm_eps: float = 1e-5
+
+    def mixer(self, index: int):
+        kind = self.layer_types[index]
+        if kind not in ("sliding_attention", "full_attention"):
+            raise ValueError(f"layer {index} is of no known kind: {kind!r}")
+        local = kind == "sliding_attention"
+        return GatedAttention(
+            num_heads=self.num_heads,
+            num_kv_heads=self.num_kv_heads,
+            head_dim=self.head_dim,
+            rope_theta=self.rope_theta,
+            rotary_dim=self.head_dim if local else 0,
+            block=self.attention_block,
+            eps=self.rms_norm_eps,
+            window=self.sliding_window if local else None,
             parent=None,  # adopted by the layer, as its `mixer`
         )

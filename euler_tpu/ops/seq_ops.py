@@ -1,6 +1,7 @@
 """Device-side primitives of sequence layers (layers/sequence.py,
 layers/moe.py): a causal depthwise convolution over time, the chunked
-gated delta rule, causal softmax attention by query blocks, the parts of
+gated delta rule, causal softmax attention by query blocks (over every
+earlier key, or over a sliding window of them), the parts of
 indexed sparse attention (an indexer's scores, the k largest of a row as
 a mask, softmax attention under that mask, the indexer's KL term), and
 the grouped matmul over rows sorted by expert.
@@ -13,7 +14,7 @@ and the attention's softmax.
 
 from __future__ import annotations
 
-import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -165,7 +166,8 @@ def chunk_gated_delta_rule(
 
 
 def blockwise_causal_attention(
-    q: Array, k: Array, v: Array, scale: float, block: int = 512
+    q: Array, k: Array, v: Array, scale: float, block: int = 512,
+    window: int | None = None,
 ) -> Array:
     """Causal softmax attention, grouped queries, one block of queries at
     a time: a block's scores against the keys up to its end are the
@@ -177,27 +179,76 @@ def blockwise_causal_attention(
     and keeps the result (`layers/sequence.py:_keep_core`) never runs
     the blocks in its second forward.
 
+    With a `window` shorter than the sequence, query t sees the keys s
+    with `t - window < s <= t` (`window` of them, itself among them), and
+    a block is scored against the keys from `window - 1` before its first
+    row — rounded down to a whole 128-key tile, so that the stretch
+    starts where a tile does — to its own end, not from key 0. The first
+    blocks' stretches begin at key 0 and grow, one program each as
+    without a window; from the block whose stretch no longer reaches key
+    0 on, every whole block sees a stretch of one length and the blocks
+    are one program run in a loop (`jax.lax.map`); a last block that is
+    not whole is a program of its own. A window that holds the whole
+    sequence is no window: the same program, to the letter.
+
     q [B, G, R, T, d] (G key/value heads, R query heads to each),
     k, v [B, G, T, d]. Returns [B, G, R, T, d].
     """
     length = q.shape[3]
     block = min(block, length)
+    if window is not None and window >= length:
+        window = None
 
-    @functools.partial(jax.checkpoint, static_argnums=(3,))
-    def one(q_b, k_b, v_b, first):
+    def one(q_b, k_b, v_b, first, key0):
+        """Rows `first ..` against the keys `key0 ..`."""
         scores = jnp.einsum("bgrtd,bgsd->bgrts", q_b, k_b) * scale
         rows = first + jnp.arange(q_b.shape[3])[:, None]
-        seen = jnp.arange(k_b.shape[2])[None, :] <= rows
+        keys = jnp.arange(k_b.shape[2])[None, :]
+        if window is None:
+            seen = keys <= rows
+        else:
+            keys = key0 + keys
+            seen = (keys <= rows) & (keys > rows - window)
         scores = jnp.where(seen, scores.astype(jnp.float32), -jnp.inf)
         probs = jax.nn.softmax(scores, axis=-1)
         return jnp.einsum("bgrts,bgsd->bgrtd", probs, v_b)
 
-    outs = []
-    for first in range(0, length, block):
-        end = min(first + block, length)
-        outs.append(
-            one(q[:, :, :, first:end], k[:, :, :end], v[:, :, :end], first)
+    # keys before a block's first row that it is scored against
+    tile = math.gcd(block, 128)
+    back = length if window is None else -(-(window - 1) // tile) * tile
+    firsts = range(0, length, block)
+    run = [first for first in firsts if back <= first <= length - block]
+
+    def alone(first):
+        """A block that is a program of its own, against the keys from
+        `back` before its first row, or from key 0, to its end."""
+        key0, end = max(first - back, 0), first + block
+        return jax.checkpoint(one, static_argnums=(3, 4))(
+            q[:, :, :, first:end], k[:, :, key0:end], v[:, :, key0:end], first, key0
         )
+
+    # stretches that begin at key 0 and grow: one program each
+    outs = [alone(first) for first in firsts if first < back]
+    if run:  # whole blocks that see `back + block` keys each: one program
+
+        @jax.checkpoint
+        def sliding(q_b, first, k, v):
+            # the stretch is cut inside the block's own rematerialisation:
+            # cut outside, every block's keys would be kept for its backward
+            cut = lambda a: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+                a, first - back, back + block, axis=2
+            )
+            return one(q_b, cut(k), cut(v), first, first - back)
+
+        q_r = q[:, :, :, run[0] : run[-1] + block]
+        q_r = q_r.reshape(q_r.shape[:3] + (len(run), block, q_r.shape[-1]))
+        o_r = jax.lax.map(
+            lambda xs: sliding(*xs, k, v), (jnp.moveaxis(q_r, 3, 0), jnp.asarray(run))
+        )
+        o_r = jnp.moveaxis(o_r, 0, 3)  # [B, G, R, blocks, block, d]
+        outs.append(o_r.reshape(o_r.shape[:3] + (len(run) * block, o_r.shape[-1])))
+    # a last block that is not whole
+    outs += [alone(first) for first in firsts if first >= back and first not in run]
     return jnp.concatenate(outs, axis=3)
 
 
