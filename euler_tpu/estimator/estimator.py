@@ -380,7 +380,8 @@ _COMPILE_EVENTS = {
 # program's `step.first_call` span carries how often each was taken.
 _TRACED_FORMS = (
     "agg_grid", "agg_scatter", "draw_rows", "draw_elements",
-    "dsa_layers", "dsa_topk", "dsa_core_masked", "mixer_core_kept",
+    "dsa_layers", "dsa_topk", "dsa_core_masked", "dsa_core_kernel",
+    "mixer_core_kept",
     "swa_layers", "swa_window", "attn_full_layers", "dense_layers",
     "router_sigmoid",
 )
@@ -399,9 +400,11 @@ def _first_call(program: str, tables: dict):
     `draw_elements` the neighbour draws that read the plane by whole rows
     or slot by slot (`dataflow/device.py:_draw_neighbors`), `dsa_layers`
     the indexed-sparse-attention mixers, `dsa_topk` the keys a query of
-    theirs may pick (summed over those layers) and `dsa_core_masked` how
-    many of them attend over the picked set as dense blocks under its
-    mask, the one form there is
+    theirs may pick (summed over those layers), `dsa_core_kernel` how
+    many of them attend over the picked set tile by tile in the Pallas
+    kernels and `dsa_core_masked` how many as dense blocks under the
+    pick's mask — the shapes of a layer's runs decide, and a layer whose
+    runs differ counts under both
     (`layers/sequence.py:IndexedSparseAttention`), `mixer_core_kept` the
     mixers whose layer keeps their attention core's output through its
     rematerialisation, so that the core's loop of query blocks runs

@@ -271,12 +271,18 @@ class IndexedSparseAttention(nn.Module):
 
     One block of `block` queries at a time: scores, pick, attention
     under the pick's mask (a gather of the picked rows would cost more
-    than the masked products it saves), KL. The blocks of a run
-    (`query_runs`) see the same stretch of keys and are one loop over one
-    program; a block is rematerialised before its own backward, so
-    neither its [block, keys] index scores nor its heads' probabilities
-    outlive it (23 GB a layer at 16,384 tokens), and its residuals are
-    its inputs. The runs' outputs put together, [B, G, R, T, d], are the
+    than the masked products it saves), the heads' probabilities
+    averaged, KL. The blocks of a run (`query_runs`) see the same stretch
+    of keys and are one loop over one program; a block is rematerialised
+    before its own backward, so its [block, keys] index scores do not
+    outlive it, and its residuals are its inputs. Where a run's blocks
+    are whole tiles of the chip (rows, keys and head multiples of 128:
+    `seq_ops.attends_by_tiles`) the attention and the average are Pallas
+    kernels and a block's [B, G, R, block, keys] scores and probabilities
+    never exist (23 GB a layer at 16,384 tokens); elsewhere they are
+    dense float32 tensors that live as long as the block. A layer is
+    tallied `dsa_core_kernel`, `dsa_core_masked`, or both if its runs
+    differ. The runs' outputs put together, [B, G, R, T, d], are the
     layer's `CORE_OUTPUT`: a decoder layer that is rematerialised keeps
     them, so its second forward remakes the projections and never runs
     the loops.
@@ -309,7 +315,6 @@ class IndexedSparseAttention(nn.Module):
         i_bias = self.param("index_k_norm_b", nn.initializers.zeros, (di,), jnp.float32)
         trace.count("dsa_layers")
         trace.count("dsa_topk", self.topk)
-        trace.count("dsa_core_masked")
         with trace.scope("dsa.proj"):
             q = (x @ w_q).reshape(batch, length, nq, d)
             k = (x @ w_k).reshape(batch, length, nkv, d)
@@ -343,20 +348,23 @@ class IndexedSparseAttention(nn.Module):
                 if k_r.shape[2] > self.topk:
                     keep = seq_ops.topk_mask(jax.lax.stop_gradient(scores), self.topk)
             with trace.scope("dsa.core"):
-                o_b, probs = seq_ops.masked_attention(q_b, k_r, v_r, keep, d**-0.5)
+                o_b, lse = seq_ops.masked_attention(q_b, k_r, v_r, keep, d**-0.5)
             with trace.scope("dsa.aux"):
-                return o_b, seq_ops.index_kl(probs, scores, keep)
+                p = seq_ops.attention_share(q_b, k_r, keep, lse, d**-0.5)
+                return o_b, seq_ops.index_kl(p, scores, keep)
 
-        outs, kl = [], jnp.zeros((), jnp.float32)
+        outs, kl, forms = [], jnp.zeros((), jnp.float32), set()
         for first, count, rows, keys in query_runs(length, self.block, self.topk):
             upto = first + count * rows
             cut = lambda a, axis: jnp.moveaxis(  # noqa: E731  blocks to the front
                 a.reshape(a.shape[:axis] + (count, rows) + a.shape[axis + 1 :]), axis, 0
             )
+            # cut outside the loop: the blocks' cotangents of the stretch add
+            # up at its own length, and are padded to the sequence's once
+            stretch = (k[:, :, :keys], v[:, :, :keys], ki[:, :keys])
+            forms.add(seq_ops.attends_by_tiles(q[:, :, :, :rows], stretch[0]))
             o_r, kl_r = jax.lax.map(
-                lambda xs: jax.checkpoint(one)(
-                    *xs, k[:, :, :keys], v[:, :, :keys], ki[:, :keys]
-                ),
+                lambda xs: jax.checkpoint(one)(*xs, *stretch),
                 (
                     cut(q[:, :, :, first:upto], 3), cut(qi[:, first:upto], 1),
                     cut(wi[:, first:upto], 1), first + rows * jnp.arange(count),
@@ -365,6 +373,9 @@ class IndexedSparseAttention(nn.Module):
             o_r = jnp.moveaxis(o_r, 0, 3)  # [B, G, R, count, rows, d]
             outs.append(o_r.reshape(o_r.shape[:3] + (count * rows, d)))
             kl = kl + jnp.sum(kl_r)
+        # a layer counts under each form any of its runs took
+        for by_tiles in forms:
+            trace.count("dsa_core_kernel" if by_tiles else "dsa_core_masked")
         with trace.scope("dsa.out"):
             o = _keep_core(jnp.concatenate(outs, axis=3)).transpose(0, 3, 1, 2, 4)
             return o.reshape(batch, length, nq * d) @ w_o, kl / (batch * length)

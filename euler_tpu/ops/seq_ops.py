@@ -3,13 +3,16 @@ layers/moe.py): a causal depthwise convolution over time, the chunked
 gated delta rule, causal softmax attention by query blocks (over every
 earlier key, or over a sliding window of them), the parts of
 indexed sparse attention (an indexer's scores, the k largest of a row as
-a mask, softmax attention under that mask, the indexer's KL term), and
-the grouped matmul over rows sorted by expert.
+a mask, softmax attention under that mask, the heads' probabilities
+averaged, the indexer's KL term), and the grouped matmul over rows sorted
+by expert.
 
-All plain XLA over static shapes; matmuls run at the backend's default
-precision (bf16 inputs, float32 accumulation on the TPU) unless said.
-Inputs and results are float32; so are the delta rule's state and gates
-and the attention's softmax.
+Plain XLA over static shapes, but for the attention under a mask where
+its block is whole tiles of the chip: that is two Pallas kernels
+(ops/masked_flash.py, imported when such a block is first traced).
+Matmuls run at the backend's default precision (bf16 inputs, float32
+accumulation on the TPU) unless said. Inputs and results are float32; so
+are the delta rule's state and gates and the attention's softmax.
 """
 
 from __future__ import annotations
@@ -301,28 +304,72 @@ def topk_mask(scores: Array, k: int) -> Array:
     return seen & (over | (level & (jnp.cumsum(level, axis=-1, dtype=jnp.int32) <= room)))
 
 
+def attends_by_tiles(q: Array, k: Array) -> bool:
+    """Which of its two forms `masked_attention` (and `attention_share`)
+    takes for a block of queries q [B, G, R, t, d] against keys
+    k [B, G, S, d]: the kernels (ops/masked_flash.py), where t, S and d
+    are whole 128-wide tiles of the chip — the cell's blocks of 512
+    queries at head 128 against 2,048 to 16,384 keys — and a key/value
+    head's dq [R, t, d], which the backward kernel holds on the chip,
+    is at most 8 MiB (the cell's: 2); the dense tensors below everywhere
+    else: a block of 8, a last block that is not whole, a head of 64.
+    The shapes decide; nothing a caller sets."""
+    heads, rows, head_dim = q.shape[2:]
+    return (
+        rows % 128 == 0 and k.shape[2] % 128 == 0 and head_dim % 128 == 0
+        and heads * rows * head_dim * 4 <= 8 * 2**20
+    )
+
+
 def masked_attention(q: Array, k: Array, v: Array, keep: Array, scale: float):
     """Softmax attention of a block of queries over the keys `keep`
     marks, grouped queries, softmax in float32.
 
     q [B, G, R, t, d], k, v [B, G, S, d], keep [B, t, S] bool with at
-    least one key a row. Returns (o [B, G, R, t, d], probs [B, G, R, t, S]).
+    least one key a row. Returns (o [B, G, R, t, d], the logsumexp of a
+    row's kept scores [B, G, R, t], a constant: `attention_share` makes
+    the probabilities again from it).
+
+    By tiles (`attends_by_tiles`) the scores [B, G, R, t, S] exist only a
+    tile at a time, on the chip, forward and backward; otherwise they
+    and the probabilities are whole float32 tensors.
     """
+    if attends_by_tiles(q, k):
+        from euler_tpu.ops import masked_flash
+
+        return masked_flash.attention(q, k, v, keep, scale)
     scores = jnp.einsum("bgrtd,bgsd->bgrts", q, k) * scale
     scores = jnp.where(keep[:, None, None], scores.astype(jnp.float32), -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bgrts,bgsd->bgrtd", probs, v), probs
+    lse = jax.lax.stop_gradient(jax.nn.logsumexp(scores, axis=-1))
+    return jnp.einsum("bgrts,bgsd->bgrtd", probs, v), lse
 
 
-def index_kl(probs: Array, scores: Array, keep: Array) -> Array:
+def attention_share(q: Array, k: Array, keep: Array, lse: Array, scale: float) -> Array:
+    """The heads' attention probabilities on the kept keys, averaged
+    over the G R heads, as a constant: `p[t, s] = mean_h exp(scale
+    q_h[t] . k[s] - lse_h[t])`, 0 off `keep` — a row sums to 1. float32
+    [B, t, S], 1 / (G R) of the probabilities it stands for; by tiles no
+    more than that ever exists. Arguments as `masked_attention` took and
+    left them."""
+    if attends_by_tiles(q, k):
+        from euler_tpu.ops import masked_flash
+
+        return masked_flash.share(q, k, keep, lse, scale)
+    scores = (jnp.einsum("bgrtd,bgsd->bgrts", q, k) * scale).astype(jnp.float32)
+    probs = jnp.where(keep[:, None, None], jnp.exp(scores - lse[..., None]), 0.0)
+    return jax.lax.stop_gradient(jnp.mean(probs, axis=(1, 2)))
+
+
+def index_kl(p: Array, scores: Array, keep: Array) -> Array:
     """sum over rows of KL(p || softmax over the kept keys of `scores`),
-    p the heads' attention probabilities summed and L1-normalised, taken
-    as a constant (the indexer is trained towards the attention, never
-    the attention towards the indexer).
+    p the heads' attention probabilities averaged (`attention_share`)
+    and L1-normalised, taken as a constant (the indexer is trained
+    towards the attention, never the attention towards the indexer).
 
-    probs [B, G, R, t, S] (zero off the kept keys), scores, keep [B, t, S].
+    p (zero off the kept keys), scores, keep [B, t, S].
     """
-    p = jax.lax.stop_gradient(jnp.sum(probs, axis=(1, 2)))
+    p = jax.lax.stop_gradient(p)
     p = p / jnp.sum(p, axis=-1, keepdims=True)
     log_q = jax.nn.log_softmax(jnp.where(keep, scores, -jnp.inf), axis=-1)
     live = keep & (p > 0)

@@ -3,9 +3,14 @@ CPU at a small size, against the benchmark's plain reference
 (`benchmarks/reference/keye_vl2.py`, loaded by path): the mixer forward
 and backward at lengths below, at and above `topk`, the selection
 against a stable sort, the rotary by three position axes, where the
-indexer's gradient comes from, the scopes' names, and three
-`Estimator.train` steps against the reference's loop."""
+indexer's gradient comes from, the scopes' names, three
+`Estimator.train` steps against the reference's loop, and the attention
+under the pick's mask as Pallas kernels (through the interpreter here):
+against a plain oracle, against the dense form inside the layer, which
+form which shapes take, and what the kernel form never makes."""
 
+import contextlib
+import io
 import os
 import re
 import sys
@@ -278,11 +283,16 @@ def test_rotary_with_equal_axes_is_the_rotary_by_position(rotary_dim):
 # -- (d) the scopes ------------------------------------------------------------
 
 
-def test_dsa_scopes_are_named_and_none_nests_in_another(config):
+@pytest.mark.parametrize("form", ["masked", "kernel"])
+def test_dsa_scopes_are_named_and_none_nests_in_another(config, form):
     """`benchmarks/scoped.py` names an op by its innermost `euler.*`
     scope, and the readers sum `dsa.*` by prefix: every op of the mixer
-    lies under exactly one of the six names, forward and backward."""
-    layer, params, x, positions = _mixer_inputs(config, 24, 8)
+    lies under exactly one of the six names, forward and backward — the
+    ops of the two kernels too, as the interpreter lowers them."""
+    if form == "masked":
+        layer, params, x, positions = _mixer_inputs(config, 24, 8)
+    else:
+        layer, params, x, positions = _tiled_mixer_inputs(length=256)
 
     def scalar(params, x):
         y, kl = layer.apply({"params": params}, x, positions)
@@ -293,7 +303,224 @@ def test_dsa_scopes_are_named_and_none_nests_in_another(config):
     scoped = [n for n in names if "euler." in n]
     found = {m for n in scoped for m in re.findall(r"euler\.([a-z_.]+)", n)}
     assert found == {"dsa.proj", "dsa.index", "dsa.select", "dsa.core", "dsa.aux", "dsa.out"}
-    assert all(n.count("euler.") == 1 for n in scoped), [n for n in scoped if n.count("euler.") > 1]
+    # the interpreter, which runs the kernels here, names a kernel's ops by
+    # the whole stack twice over: the same scope again, never another
+    nested = [n for n in scoped if len(set(re.findall(r"euler\.([a-z_.]+)", n))) > 1]
+    assert not nested, nested
+    if form == "masked":
+        assert all(n.count("euler.") == 1 for n in scoped), [n for n in scoped if n.count("euler.") > 1]
+
+
+# -- (d2) the attention under the mask, tile by tile ---------------------------
+
+
+def _plain_attention(q, k, v, keep, scale):
+    """(o, logsumexp, the heads' probabilities averaged) by whole
+    tensors, float32 throughout."""
+    s = jnp.einsum("bgrtd,bgsd->bgrts", q, k, precision="highest") * scale
+    s = jnp.where(keep[:, None, None], s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    p = jnp.exp(s - lse[..., None])
+    return jnp.einsum("bgrts,bgsd->bgrtd", p, v, precision="highest"), lse, jnp.mean(p, axis=(1, 2))
+
+
+def _masks(case, rows, keys, key):
+    """keep [2, rows, keys] and the block's first row for each case."""
+    first = {"first_not_zero": keys - rows}.get(case, 0)
+    at = first + jnp.arange(rows)[:, None]
+    cols = jnp.arange(keys)[None, :]
+    causal = jnp.broadcast_to(cols <= at, (2, rows, keys))
+    if case == "all_causal":  # a run whose queries pick every key they see
+        return causal
+    picked = jax.random.uniform(key, (2, rows, keys)) < 0.25
+    keep = (picked & causal) | (cols == at)  # its own key, so no row is empty
+    if case == "empty_tiles":  # keys 128..255 picked by no row: a whole tile of every row tile
+        keep = keep & ~((cols >= 128) & (cols < 256)) | (cols == 0)
+    if case == "one_key_row":
+        keep = keep.at[:, 5].set(cols[0] == 3).at[1, 77].set(cols[0] == 0)
+    if case == "first_not_zero":  # a row with nothing in the two live tiles before its one key's
+        keep = keep.at[:, 7].set(cols[0] == 300)
+    return keep
+
+
+@pytest.mark.parametrize(
+    "case,groups,keys",
+    [("empty_tiles", 2, 512), ("one_key_row", 1, 256), ("all_causal", 1, 256), ("first_not_zero", 2, 512)],
+)
+def test_kernels_match_a_plain_oracle(case, groups, keys):
+    """Output, logsumexp, the heads' shares and the gradients to q, k, v
+    of the kernel pair, tiles of 128 so that every case spans several.
+    q, k, v are whole bf16 numbers, so the scores agree to float32
+    rounding; the probabilities enter the MXU as bf16 in the kernels and
+    as float32 in the oracle: that is the output's and the gradients'
+    tolerance."""
+    from euler_tpu.ops import masked_flash
+
+    rows, heads, d = 128, 2, 128
+    ks = jax.random.split(jax.random.PRNGKey(keys + groups), 5)
+    whole = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
+    q = whole(jax.random.normal(ks[0], (2, groups, heads, rows, d)))
+    k = whole(jax.random.normal(ks[1], (2, groups, keys, d)))
+    v = whole(jax.random.normal(ks[2], (2, groups, keys, d)))
+    weigh = jax.random.normal(ks[3], q.shape)
+    keep = _masks(case, rows, keys, ks[4])
+    assert int(keep.sum(-1).min()) >= 1
+    if case == "empty_tiles":
+        assert not bool(keep[:, :, 128:256].any())
+    scale = d**-0.5
+
+    def run(attend):
+        def scalar(q, k, v):
+            o, lse = attend(q, k, v)
+            return jnp.sum(o * weigh), (o, lse)
+
+        return jax.jit(jax.value_and_grad(scalar, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+
+    (_, (o, lse)), grads = run(lambda q, k, v: masked_flash.attention(q, k, v, keep, scale, 128, 128))
+    (_, (o_want, lse_want)), grads_want = run(lambda q, k, v: _plain_attention(q, k, v, keep, scale)[:2])
+    np.testing.assert_allclose(lse, lse_want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(o, o_want, atol=2e-2)
+    assert float(jnp.max(jnp.abs(o - o_want))) < 1e-2 * float(jnp.max(jnp.abs(o_want)))
+    for name, got, want in zip("qkv", grads, grads_want):
+        assert float(jnp.max(jnp.abs(got - want))) < 1e-2 * float(jnp.max(jnp.abs(want))), name
+    share = jax.jit(lambda: masked_flash.share(q, k, keep, lse, scale, 128, 128))()
+    share_want = _plain_attention(q, k, v, keep, scale)[2]
+    np.testing.assert_allclose(share, share_want, rtol=1e-4, atol=1e-7)
+    np.testing.assert_allclose(jnp.sum(share, axis=-1), 1.0, rtol=1e-5)
+    assert not bool(jnp.any(jnp.where(keep, 0.0, share) != 0))
+    # the tiles chosen from the shapes are another cut of the same sums
+    o_auto, lse_auto = jax.jit(lambda: masked_flash.attention(q, k, v, keep, scale))()
+    np.testing.assert_allclose(lse_auto, lse, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(o_auto, o, atol=2e-2)
+
+
+def _tiled_mixer_inputs(length=512, head_dim=128, block=128):
+    """A mixer whose blocks are whole tiles (rows 128, head 128, keys
+    256 and 512) at a width small enough for the interpreter: 2 key/value
+    heads of 2 query heads, 3 indexer heads, and what `_mixer_inputs`
+    gives."""
+    from euler_tpu.layers.sequence import IndexedSparseAttention
+
+    layer = IndexedSparseAttention(
+        num_heads=4, num_kv_heads=2, head_dim=head_dim, index_heads=3, index_dim=16,
+        topk=256, rope_theta=1e4, sections=(head_dim // 4, head_dim // 8, head_dim // 8),
+        block=block,
+    )
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, length, 32))
+    time = jnp.broadcast_to(jnp.arange(length), (1, length))
+    positions = jnp.stack([time, time // 3, time % 3])
+    params = layer.init(jax.random.PRNGKey(1), x, positions)["params"]
+    params = jax.tree_util.tree_map(
+        lambda p: p + 0.1 * jax.random.normal(jax.random.PRNGKey(2), p.shape), params
+    )
+    return layer, params, x, positions
+
+
+def _counted(fn, *args):
+    """How often each `dsa_core_*` form was traced inside `fn(*args)`."""
+    from euler_tpu.utils import trace
+
+    before = trace.counts()
+    jax.eval_shape(fn, *args)
+    after = trace.counts()
+    return {
+        name: after.get(name, 0) - before.get(name, 0)
+        for name in ("dsa_core_kernel", "dsa_core_masked")
+    }
+
+
+@pytest.mark.parametrize(
+    "length,head_dim,block,kernel,masked",
+    # whole tiles; a block of 8; a head that is half a tile; a last block
+    # that is not whole beside runs that are
+    [(512, 128, 128, 1, 0), (64, 128, 8, 0, 1), (512, 64, 128, 0, 1), (576, 128, 128, 1, 1)],
+)
+def test_the_form_follows_the_shapes_and_the_counter_says_so(length, head_dim, block, kernel, masked):
+    layer, params, x, positions = _tiled_mixer_inputs(length, head_dim, block)
+    text = str(jax.make_jaxpr(lambda p, x: layer.apply({"params": p}, x, positions))(params, x))
+    assert ("pallas_call" in text) == bool(kernel)
+    counted = _counted(lambda p, x: layer.apply({"params": p}, x, positions), params, x)
+    assert counted == {"dsa_core_kernel": kernel, "dsa_core_masked": masked}
+
+
+def test_the_layer_by_tiles_is_the_layer_by_dense_blocks(monkeypatch):
+    """Loss, KL and every gradient, through `checkpoint` + `lax.map` +
+    `custom_vjp`, at the tolerance of bf16 operands (the dense form's
+    products are whole float32 here on the CPU)."""
+    from euler_tpu.ops import seq_ops
+
+    layer, params, x, positions = _tiled_mixer_inputs()
+
+    def run():
+        def scalar(params, x):
+            y, kl = layer.apply({"params": params}, x, positions)
+            return jnp.sum(jnp.sin(y)) + kl, (y, kl)
+
+        return jax.jit(jax.value_and_grad(scalar, argnums=(0, 1), has_aux=True))(params, x)
+
+    (loss, (y, kl)), grads = run()
+    monkeypatch.setattr(seq_ops, "attends_by_tiles", lambda q, k: False)
+    (loss_want, (y_want, kl_want)), grads_want = run()
+    assert float(kl_want) > 1e-3
+    np.testing.assert_allclose(loss, loss_want, rtol=2e-3)
+    np.testing.assert_allclose(kl, kl_want, rtol=5e-3)
+    np.testing.assert_allclose(y, y_want, atol=2e-2 * float(jnp.max(jnp.abs(y_want))))
+    for (path, got), want in zip(
+        jax.tree_util.tree_leaves_with_path(grads), jax.tree_util.tree_leaves(grads_want)
+    ):
+        assert float(jnp.max(jnp.abs(want))) > 0, path
+        assert float(jnp.max(jnp.abs(got - want))) < 2e-2 * float(jnp.max(jnp.abs(want))), path
+
+
+def _float32_shapes(jaxpr, found):
+    """Every float32 value's shape in `jaxpr` and in what its equations
+    hold (loops, checkpoints, custom rules, kernels)."""
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            if getattr(var.aval, "dtype", None) == jnp.float32:
+                found.add(tuple(var.aval.shape))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _float32_shapes(sub, found)
+    return found
+
+
+def test_by_tiles_no_blocks_scores_are_made_or_kept(monkeypatch):
+    """The kernel form makes no float32 value of rank >= 4 whose last two
+    axes are a block's rows and a run's keys but the indexer's products
+    [B, 3, rows, keys] — forward, backward, or kept between them; the
+    dense form, at the same shapes, does."""
+    from euler_tpu.ops import seq_ops
+
+    layer, params, x, positions = _tiled_mixer_inputs()
+
+    def scalar(params, x):
+        y, kl = layer.apply({"params": params}, x, positions)
+        return jnp.sum(y) + kl
+
+    def scores_of(shapes):  # rows 128; the runs' keys are 256 and 512, the head 128
+        return sorted(
+            s for s in shapes
+            if len(s) >= 4 and s[-2] == 128 and s[-1] in (256, 512) and s[:-2] != (1, 3)
+        )
+
+    def look():
+        made = _float32_shapes(jax.make_jaxpr(jax.grad(scalar))(params, x).jaxpr, set())
+        out = io.StringIO()
+        with contextlib.redirect_stdout(out):
+            jax.ad_checkpoint.print_saved_residuals(scalar, params, x)
+        kept = {
+            tuple(int(n) for n in shape.split(",") if n)
+            for shape in re.findall(r"^f32\[([\d,]*)\]", out.getvalue(), re.M)
+        }
+        text = jax.jit(jax.grad(scalar)).lower(params, x).compile().as_text()
+        compiled = {tuple(int(n) for n in s.split(",")) for s in re.findall(r"f32\[([\d,]+)\]", text)}
+        return scores_of(made), scores_of(kept), scores_of(compiled)
+
+    assert look() == ([], [], [])
+    monkeypatch.setattr(seq_ops, "attends_by_tiles", lambda q, k: False)
+    made, kept, compiled = look()
+    assert (1, 2, 2, 128, 256) in made and (1, 2, 2, 128, 512) in made and kept == []
+    assert (1, 2, 2, 128, 512) in compiled
 
 
 # -- (e) the model ------------------------------------------------------------
@@ -389,6 +616,7 @@ def test_first_call_span_carries_the_mixers_forms(config, three_steps):
     )
     layers, topk = config["num_hidden_layers"], config["sa_config"]["topk"]
     assert (args["dsa_layers"], args["dsa_topk"], args["dsa_core_masked"]) == (layers, layers * topk, layers)
+    assert args["dsa_core_kernel"] == 0  # blocks of 8 at the rehearsal's size: no whole tile
     assert args["mixer_core_kept"] == layers  # every layer's mixer is a softmax attention
     assert (args["agg_grid"], args["draw_rows"], args["draw_elements"]) == (0, 0, 1)
 
