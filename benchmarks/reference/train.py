@@ -60,15 +60,22 @@ def _step(params, m, v, tables, key, count, *, loss_fn, lr, dtype, fault):
 
 def first_steps(
     loss_fn, tables, spec: list, seed: int, lr: float,
-    dtype=jnp.float32, fault: str = "",
+    dtype=jnp.float32, fault: str = "", weights_seed: int | None = None,
 ) -> dict:
-    """Weights from `spec` and `seed`, three steps. Returns the three
-    losses, the first gradient's norm per leaf, and the norm of each
-    leaf's change after the three."""
+    """Weights from `spec` and `seed`, three steps on the batches of
+    `seed`: the seed `weights.run_seed` gave the program's side
+    (`proof.py` may make the weights from a `weights_seed` of their own,
+    as it does the program's). Returns the three losses, the first
+    gradient's norm per leaf, and the norm of each leaf's change after
+    the three."""
     from weights import change_norms, key_seed, make_params
 
+    if weights_seed is None:
+        weights_seed = seed
     seed = key_seed(seed)
-    params = {k: v.astype(dtype) for k, v in make_params(spec, seed).items()}
+    params = {
+        k: v.astype(dtype) for k, v in make_params(spec, weights_seed).items()
+    }
     m = {k: jnp.zeros_like(v) for k, v in params.items()}
     v = {k: jnp.zeros_like(p) for k, p in params.items()}
     count = jnp.zeros((), jnp.int32)
@@ -81,7 +88,7 @@ def first_steps(
         losses.append(loss)
         if step == 0:
             first_grad = gnorm
-    change = change_norms(params, make_params(spec, seed))
+    change = change_norms(params, make_params(spec, weights_seed))
     out = {
         "loss": [float(x) for x in losses],
         "grad_norm": {k: float(x) for k, x in first_grad.items()},

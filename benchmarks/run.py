@@ -10,10 +10,11 @@ the configuration's file, `traffic/<mix>.json`, `families/<family>.py`
 on a cell's name. README.md says how to add each.
 
 One run: build the graph of the configuration, hand it to the program,
-make the weights from --seed, drive the program's first three steps
-through the very Estimator the window then drives, warm up, measure for
---seconds, read the memory peak, free the program, run the plain
-reference over the same three steps and compare.
+make the weights and draw the batches from --seed (or from the seed the
+configuration fixes, `weights.run_seed`), drive the program's first
+three steps through the very Estimator the window then drives, warm up,
+measure for --seconds, read the memory peak, free the program, run the
+plain reference over the same three steps and compare.
 """
 
 from __future__ import annotations
@@ -108,9 +109,9 @@ def require_tpu(chips: int) -> None:
 def configure_jax() -> None:
     """The program's own compile cache (inside the checkout, or where
     JAX_COMPILATION_CACHE_DIR says), taking every program of a run
-    whatever its size or compile time: the step carries the graph as
-    constants (~2 GB), and a machine's cap of 192 MiB would refuse it
-    and make every run compile."""
+    whatever its size or compile time, so that only a checkout's first
+    run of a cell compiles. Since PR 27 the tables are the step's
+    arguments: its entry is 6-20 MB, not the graph's 2 GB."""
     import jax
 
     from euler_tpu.utils.compile_cache import configure_compile_cache
@@ -123,8 +124,8 @@ def configure_jax() -> None:
 
 def trim() -> None:
     """Hands freed host memory back to the system (glibc keeps each
-    thread's arena otherwise): the step's compile needs the room, for it
-    holds the 2 GB of graph constants several times over."""
+    thread's arena otherwise): generating and staging the graph leave
+    several GB freed, and the reference stages it once more."""
     import ctypes
 
     gc.collect()
@@ -160,8 +161,13 @@ class CompileCounter:
             self.count += 1
 
 
-def make_estimator(built: dict, config: dict, mix: dict, spec: list, seed: int):
-    """The object the first steps and the window both drive."""
+def make_estimator(
+    built: dict, config: dict, mix: dict, spec: list, seed: int,
+    weights_seed: int | None = None,
+):
+    """The object the first steps and the window both drive: batches,
+    sampling keys and weights from `seed`; `proof.py` may make the
+    weights from a `weights_seed` of their own."""
     from euler_tpu.estimator import Estimator, EstimatorConfig
 
     import weights
@@ -177,14 +183,17 @@ def make_estimator(built: dict, config: dict, mix: dict, spec: list, seed: int):
     est = Estimator(
         built["model"], built["flow"], cfg, feature_cache=built["feature_cache"]
     )
-    est.params = weights.nest(weights.make_params(spec, seed))
+    if weights_seed is None:
+        weights_seed = seed
+    est.params = weights.nest(weights.make_params(spec, weights_seed))
     return est
 
 
-def program_first_steps(est, spec: list, seed: int) -> dict:
+def program_first_steps(est, spec: list, weights_seed: int) -> dict:
     """Losses of steps 1-3, the first gradient's norm per leaf from
     Adam's first moment after one step, and each leaf's change after
-    three — through `Estimator.train`, as the window calls it."""
+    three (from the weights `weights_seed` made) — through
+    `Estimator.train`, as the window calls it."""
     import weights
 
     losses = est.train(1, log=False, save=False)
@@ -195,7 +204,7 @@ def program_first_steps(est, spec: list, seed: int) -> dict:
     }
     losses += est.train(2, log=False, save=False)
     change = weights.change_norms(
-        weights.flatten(est.params), weights.make_params(spec, seed)
+        weights.flatten(est.params), weights.make_params(spec, weights_seed)
     )
     return {
         "loss": [float(x) for x in losses],
@@ -245,22 +254,6 @@ def measure(est, mix: dict, seconds: float, trace_dir: str | None) -> dict:
     }
 
 
-def sampler_alone(flow, mix: dict, seed: int, trace_dir: str) -> None:
-    """Traces the flow's own jitted sample(key), alone, after a warm
-    call."""
-    import jax
-
-    from weights import key_seed
-
-    sample = jax.jit(flow.sample)
-    base = jax.random.PRNGKey(key_seed(seed))
-    jax.block_until_ready(sample(base))
-    jax.profiler.start_trace(trace_dir)
-    for i in range(mix["sampler_alone_calls"]):
-        jax.block_until_ready(sample(jax.random.fold_in(base, i + 1)))
-    jax.profiler.stop_trace()
-
-
 def traced_window(events: list) -> tuple:
     """[lo, hi) of the traced stretch on the trace's clock: the
     `bench.traced` host span, else the extent of the device's ops."""
@@ -303,9 +296,9 @@ def read_layer_metrics(resolved: dict, run: dict) -> dict:
     return out
 
 
-def reduce_trace(args, resolved, mix, counts, window, memory_peak, flow, trace_dir):
-    """The traced run's part: the window's trace and the sampler's own
-    to per-layer metrics, the device's busy time and the breakdown."""
+def reduce_trace(args, resolved, mix, counts, window, memory_peak, trace_dir):
+    """The traced run's part: the window's trace to per-layer metrics,
+    the device's busy time and the breakdown."""
     import jax
 
     import tracered
@@ -318,26 +311,9 @@ def reduce_trace(args, resolved, mix, counts, window, memory_peak, flow, trace_d
     lo, hi = traced_window(events)
     if args.keep_trace:
         keep_trace(args.keep_trace, trace_dir, events, mix)
-    readers = [
-        load_module("layer_metrics", m["name"], resolved["here"])
-        for m in resolved["per_layer"]
-    ]
-    sampler_events = []
-    if any("sampler_trace" in getattr(r, "NEEDS", ()) for r in readers):
-        # a second program that carries the graph as constants: only a
-        # cell whose metrics ask for it pays its compile
-        sampler_dir = tempfile.mkdtemp(prefix="bench_sampler_")
-        try:
-            sampler_alone(flow, mix, args.seed, sampler_dir)
-            phase("sampler alone")
-            sampler_events = tracered.load(tracered.find_xplane(sampler_dir))
-        finally:
-            shutil.rmtree(sampler_dir, ignore_errors=True)
     run_facts = {
         "trace": events,
-        "sampler_trace": sampler_events,
         "step_program": mix["step_program"],
-        "sample_program": mix["sample_program"],
         "steps_per_program": mix["steps_per_call"],
         "call_seconds": window["call_seconds"],
         "traced_steps": window["traced_steps"],
@@ -410,13 +386,16 @@ def run(args, plant=None) -> dict:
 
     compiles = CompileCounter()
     resolved = stage(args.workload, args.rehearse)
+    import weights  # `stage` has put this directory on the path
+
     cell, mix, config = resolved["cell"], resolved["mix"], resolved["config"]
     graph, built, spec = resolved.pop("graph"), resolved.pop("built"), resolved["spec"]
     reference, train, counts = resolved["reference"], resolved["train"], resolved["counts"]
-    est = make_estimator(built, config, mix, spec, args.seed)
+    seed = weights.run_seed(config, args.seed)
+    est = make_estimator(built, config, mix, spec, seed)
     if plant is not None:
         plant(est, built)
-    got = program_first_steps(est, spec, args.seed)
+    got = program_first_steps(est, spec, seed)
     phase("first three steps")
     est.train(mix["steps_per_train_call"], log=False, save=False)
     jax.block_until_ready(est.params)
@@ -432,11 +411,10 @@ def run(args, plant=None) -> dict:
         device = jax.devices()[0]
         memory_peak = int((device.memory_stats() or {}).get("peak_bytes_in_use", 0))
         failed = sum(1 for x in window["losses"] if x != x or abs(x) == float("inf"))
-        # the peak is read: the program's state goes, the step's executable
-        # (it holds the graph as constants, on the host too) with it
+        # the peak is read: the program's state goes, its staged tables
+        # (the step's arguments) and the step's executable with it
         lr = config["optimizer"]["learning_rate"]
         facts, examples_per_step = built["facts"], built["examples_per_step"]
-        flow = built["flow"]
         est.params = est.opt_state = None
         del est, built
         jax.clear_caches()
@@ -444,20 +422,15 @@ def run(args, plant=None) -> dict:
         phase("estimator freed")
         layer, device_extra, breakdown = {}, {}, None
         if args.trace:
-            traced = reduce_trace(
-                args, resolved, mix, counts, window, memory_peak, flow, trace_dir
+            layer, device_extra, breakdown = reduce_trace(
+                args, resolved, mix, counts, window, memory_peak, trace_dir
             )
-            layer, device_extra, breakdown = traced
     finally:
         if trace_dir:
             shutil.rmtree(trace_dir, ignore_errors=True)
-    del flow
-    jax.clear_caches()
-    gc.collect()
-    phase("program freed")
     tables, loss_fn = reference.make(config, mix, graph)
     t_ref = time.perf_counter()
-    want = train.first_steps(loss_fn, tables, spec, args.seed, lr)
+    want = train.first_steps(loss_fn, tables, spec, seed, lr)
     reference_s = time.perf_counter() - t_ref
     phase("reference done")
     compared = train.compare(got, want)
@@ -498,6 +471,7 @@ def run(args, plant=None) -> dict:
     result["run"] = {
         "workload": cell["name"],
         "seed": args.seed,
+        "run_seed": seed,
         "window_s": window["elapsed"],
         "train_calls": len(window["call_seconds"]),
         "reference_s": reference_s,

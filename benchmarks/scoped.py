@@ -49,7 +49,7 @@ HOST_PREFIXES = (SCOPE, "bench.")
 OP_NAME_STAT = "tf_op"
 
 _PARSED: dict = {}  # trace path -> events, so eight readers parse once
-_TABLE: list = []  # [events, (program, steps), their partition]: the last one
+_TABLE: list = []  # [events, (program, steps), their partition, its `notes`]: the last one
 
 
 def find_trace() -> str | None:
@@ -331,7 +331,7 @@ def layers(run: dict):
     key = (run["step_program"], run["steps_per_program"])
     # the list itself is kept, so that `is` cannot meet a recycled id
     if not _TABLE or _TABLE[0] is not events or _TABLE[1] != key:
-        _TABLE[:] = [events, key, partition(events, *key)]
+        _TABLE[:] = [events, key, partition(events, *key), None]
     return _TABLE[2]
 
 
@@ -351,14 +351,19 @@ def host_spans(events: list, prefix: str = SCOPE) -> list:
     ]
 
 
-def host_step_ns(events: list, names=("next_batch", "dispatch", "drain")):
+# the train loop's body; `train.drain` fetches the call's losses, so it
+# is a wait for the device and no work of the host's, and where a call
+# is one step (the language-model cells) every step would hold one
+HOST_STEP_SPANS = (f"{SCOPE}train.next_batch", f"{SCOPE}train.dispatch")
+
+
+def host_step_ns(events: list):
     """Median over the traced steps of the host time in the train loop's
-    body: the `euler.train.<name>` spans that carry the step's number."""
-    wanted = {f"{SCOPE}train.{n}" for n in names}
+    body: the `HOST_STEP_SPANS` that carry the step's number."""
     per_step: dict = {}
     for e in host_spans(events):
         step = e.get("args", {}).get("step")
-        if e["name"] in wanted and step is not None:
+        if e["name"] in HOST_STEP_SPANS and step is not None:
             per_step[step] = per_step.get(step, 0) + e["dur_ns"]
     return statistics.median(per_step.values()) if per_step else None
 
@@ -376,17 +381,20 @@ def idle_by_span(events: list, lo: int, hi: int) -> dict:
 
 def notes(run: dict):
     """What `breakdown.notes` gets: the whole scope table per step in ms,
-    and the traced stretch's idle gaps by program span."""
+    and the traced stretch's idle gaps by program span. Worked once: in a
+    sequence model's cell `sampler_ms` and `kernel_share.notes` both ask."""
     table = layers(run)
     if table is None:
         return None
+    if _TABLE[3] is not None:
+        return _TABLE[3]
     events = events_of()
     marks = [e for e in events if e["name"] == "bench.traced"]
     runs = tr.program_runs(events, run["step_program"])
     lo = marks[0]["start_ns"] if marks else runs[0][0]
     hi = marks[0]["start_ns"] + marks[0]["dur_ns"] if marks else runs[-1][1]
     idle = idle_by_span(events, lo, hi)
-    return {
+    _TABLE[3] = {
         "scope_ms_per_step": {
             k: v / 1e6 for k, v in sorted(table.items(), key=lambda kv: -kv[1])
         },
@@ -394,3 +402,4 @@ def notes(run: dict):
             k: v / 1e6 for k, v in sorted(idle.items(), key=lambda kv: -kv[1])
         },
     }
+    return _TABLE[3]

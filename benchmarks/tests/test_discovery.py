@@ -6,6 +6,8 @@ import json
 import os
 import shutil
 
+import pytest
+
 import run as harness
 
 
@@ -65,5 +67,53 @@ def test_benchmark_json_names_only_files_that_exist():
         assert os.path.isfile(os.path.join(harness.ROOT, c["file"]))
     for w in bench["workloads"]:
         harness.resolve(w["name"])
-    for m in bench["per_layer"]:
-        assert os.path.isfile(os.path.join(harness.HERE, "layer_metrics", m["name"] + ".py"))
+
+
+@pytest.mark.parametrize("metric", harness.load_benchmark()["per_layer"], ids=lambda m: m["name"])
+def test_a_per_layer_entry_lists_cells_that_exist_and_has_its_reader(metric):
+    """An edit that orphans a reader, or lists a cell that is gone, is
+    caught here on the CPU and not by a traced run on the chip."""
+    cells = {w["name"] for w in harness.load_benchmark()["workloads"]}
+    assert set(metric.get("workloads", cells)) <= cells
+    reader = harness.load_module("layer_metrics", metric["name"])
+    assert callable(reader.read)
+
+
+SAGE, DEEPWALK = "sage-products-id.train-device", "deepwalk-products.train-device"
+QWEN3 = "qwen3-next-80b-a3b-ep16.train-tokens"
+KEYE, TRINITY = "keye-vl2-30b-a3b-ep8.train-long-tokens", "trinity-mini-ep8.train-long-tokens"
+TABLE_AND_SETUP = (
+    "sampler_ms", "gather_ms", "table_grad_ms", "optimizer_ms", "host_step_ms",
+    "stage_s", "step_compile_s",
+)
+
+
+@pytest.mark.parametrize(
+    "metrics, cells",
+    [
+        (("moe_ms", "head_ms", "moe_experts_roofline_pct"), {QWEN3, KEYE, TRINITY}),
+        (("attn_ms", "attn_core_roofline_pct"), {QWEN3, TRINITY}),
+        (TABLE_AND_SETUP, {SAGE, DEEPWALK, QWEN3, KEYE, TRINITY}),
+        (("conv_ms",), {SAGE}),
+        (("dsa_ms",), {KEYE}),
+    ],
+)
+def test_a_reader_is_selected_in_the_cells_its_entry_lists(metrics, cells):
+    reports = {
+        w["name"]: {m["name"] for m in harness.resolve(w["name"])["per_layer"]}
+        for w in harness.load_benchmark()["workloads"]
+    }
+    for name in metrics:
+        assert {cell for cell, names in reports.items() if name in names} == cells, name
+
+
+def test_the_second_sampler_program_is_gone():
+    """`sampler_alone_ms` timed a second program from outside the step;
+    `sampler_ms` reads the layer where the work happens."""
+    bench = harness.load_benchmark()
+    assert "sampler_alone_ms" not in [m["name"] for m in bench["per_layer"]]
+    assert not os.path.exists(os.path.join(harness.HERE, "layer_metrics", "sampler_alone_ms.py"))
+    assert not hasattr(harness, "sampler_alone")
+    for cell in bench["workloads"]:
+        mix = harness.resolve(cell["name"])["mix"]
+        assert not {"sampler_alone_calls", "sample_program"} & (set(mix) | set(mix["why"]))

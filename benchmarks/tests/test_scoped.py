@@ -95,8 +95,8 @@ def test_partition_by_hand():
 
 
 def test_host_time_of_a_step_is_the_median_over_steps():
-    # steps 7, 8, 9: 40, 32 and the call's drain, 480
-    assert scoped.host_step_ns(HAND) == 40
+    # steps 7 and 8: 40 and 32; the call's drain, 480, is a wait and left out
+    assert scoped.host_step_ns(HAND) == 36
     assert scoped.host_step_ns([e for e in HAND if e["plane"] == D]) is None
 
 
@@ -306,9 +306,10 @@ def test_new_metrics_are_found_by_name_in_cells_that_exist(name):
     bench = harness.load_benchmark()
     cells = {w["name"] for w in bench["workloads"]}
     entry = next(m for m in bench["per_layer"] if m["name"] == name)
-    assert set(entry["workloads"]) <= cells and entry["workloads"]
+    listed = entry.get("workloads", sorted(cells))  # no key: every cell
+    assert set(listed) <= cells and listed
     assert callable(reader(name).read)
     moved = {m["name"] for m in bench["end_to_end"]}
     assert entry["moves"] in moved
-    for cell in entry["workloads"]:
+    for cell in listed:
         assert name in [m["name"] for m in harness.resolve(cell)["per_layer"]]

@@ -63,7 +63,6 @@ def test_layer_readers_on_the_hand_trace():
 
     facts = {
         "trace": HAND, "step_program": "jit_train_step", "steps_per_program": 1,
-        "sampler_trace": [], "sample_program": "jit_sample",
         "call_seconds": [1.0, 1.0, 1.0, 1.1, 1.0], "traced_steps": 2,
         "traced_seconds": 1200 / 1e9, "busy_s": 760 / 1e9, "memory_peak_bytes": 2**31,
         "counts": {"flops": 1000, "bytes": 4000},
@@ -75,7 +74,6 @@ def test_layer_readers_on_the_hand_trace():
 
     assert read("dispatch_gap_ms") == 100 / 1e6
     assert read("step_device_ms") == (710 / 2) / 1e6
-    assert read("sampler_alone_ms") is None  # nothing to read: left out, never 0
     assert abs(read("device_idle_pct") - 100 * (1 - 760 / 1200)) < 1e-9
     assert read("hbm_peak_gib") == 2.0
     assert abs(read("step_roofline_pct") - 100 * (4000 / 1e12) / (355e-9)) < 1e-6

@@ -1,4 +1,6 @@
-"""Weights from `--seed`, made by the benchmark and handed to both sides.
+"""Weights from the configuration's `model.run_seed`, else from
+`--seed`, made by the benchmark and handed to both sides; `run_seed`
+says which of the two a run is made from.
 
 A family lists its leaves as (path, shape, init, scale). The program gets
 them nested as its `init_params`; the plain reference reads the same
@@ -15,6 +17,15 @@ import jax.numpy as jnp
 def key_seed(seed: int) -> int:
     """`--seed` may pass 2**31; a PRNG seed here is 32 bits."""
     return int(seed) % (2**32 - 4)
+
+
+def run_seed(config: dict, seed: int) -> int:
+    """The seed a run's weights, its token or root batches and every
+    sampling key (the Estimator's seed, the reference's `step_key`) are
+    made from: the configuration's `model.run_seed` where it fixes one,
+    beside `graph.graph_seed`, else `--seed`. The one place that reads
+    the key, for the program's side and the reference's."""
+    return int(config["model"].get("run_seed", seed))
 
 
 def make_params(spec: list, seed: int) -> dict:
