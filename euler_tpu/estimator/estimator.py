@@ -366,16 +366,6 @@ def _build_train_steps(model, tx, device_flow, feature_cache):
 
 _NO_SPAN = contextlib.nullcontext()
 
-# jax.monitoring's durations of one program's way from Python to the
-# device, as child spans of `step.first_call`. `compile` holds the
-# persistent cache's lookup (`cache_fetch`, on a hit) or XLA's compile.
-_COMPILE_EVENTS = {
-    "/jax/core/compile/jaxpr_trace_duration": "trace",
-    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
-    "/jax/core/compile/backend_compile_duration": "compile",
-    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_fetch",
-}
-
 # `trace.count` names of choices made while a program is traced; the
 # program's `step.first_call` span carries how often each was taken.
 _TRACED_FORMS = (
@@ -391,10 +381,14 @@ _TRACED_FORMS = (
 def _first_call(program: str, tables: dict):
     """The set-up span `step.first_call` around the first execution of a
     step program (the caller waits for the result inside it): tracing,
-    lowering, cache fetch or compile — each one child span, from the
-    first start to the last end of its kind, since traces nest — and
-    what remains is the first run. `table_arg_bytes` is what went in as
-    the `tables` argument rather than as constants of the executable;
+    lowering, cache fetch or compile are its child spans, and what
+    remains is the first run. Traces nest (an inner `jax.jit` is traced,
+    lowered and compiled inside the outer trace), so each of `.trace`,
+    `.lower` and `.compile` is recorded as the stretches that are its
+    own (`trace.self_stretches`): the three cover disjoint time, and
+    `.cache_fetch` lies inside `.compile`. `table_arg_bytes` is what
+    went in as the `tables` argument rather than as constants of the
+    executable;
     `agg_grid` / `agg_scatter` count the aggregations the program's convs
     traced in each form (`layers/conv.py:Conv.agg_add`), `draw_rows` /
     `draw_elements` the neighbour draws that read the plane by whole rows
@@ -417,29 +411,19 @@ def _first_call(program: str, tables: dict):
     table_arg_bytes = sum(
         leaf.nbytes for leaf in jax.tree_util.tree_leaves(tables)
     )
-    parts: dict = {}
-
-    def on_duration(event, seconds, **_kw):
-        kind = _COMPILE_EVENTS.get(event)
-        if kind is not None:
-            end = time.perf_counter_ns()
-            lo, hi = parts.get(kind, (end, end))
-            parts[kind] = (min(lo, end - int(seconds * 1e9)), max(hi, end))
-
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
-    try:
-        with trace.span(
-            "step.first_call", program=program, table_arg_bytes=table_arg_bytes
-        ) as call:
-            before = trace.counts()
-            yield
-            after = trace.counts()
-            for form in _TRACED_FORMS:
-                call.args[form] = after.get(form, 0) - before.get(form, 0)
-            for kind, (lo, hi) in parts.items():
+    with trace.span(
+        "step.first_call", program=program, table_arg_bytes=table_arg_bytes
+    ) as call, trace.compiles() as events:
+        before = trace.counts()
+        yield
+        after = trace.counts()
+        for form in _TRACED_FORMS:
+            call.args[form] = after.get(form, 0) - before.get(form, 0)
+        parts = trace.self_stretches(events, ("trace", "lower", "compile"))
+        parts.update(trace.self_stretches(events, ("cache_fetch",)))
+        for kind, stretches in parts.items():
+            for lo, hi in stretches:
                 call.child(f"step.first_call.{kind}", lo, hi, program=program)
-    finally:
-        jax.monitoring.unregister_event_duration_listener(on_duration)
 
 
 class _ProfileWindow:
@@ -736,7 +720,7 @@ class Estimator:
         self, total_steps: int | None = None, log: bool = True, save: bool = True
     ):
         steps = total_steps if total_steps is not None else self.cfg.total_steps
-        with trace.span("train", steps=steps):
+        with trace.counted("train", steps=steps):
             self._ensure_init()
             k = max(int(self.cfg.steps_per_call), 1)
             if k > 1:
@@ -745,8 +729,10 @@ class Estimator:
 
     def _dispatch(self, step_fn, rngs, batch):
         """One call of a step program on the Estimator's state; returns
-        its (loss or losses, metric). The first call of each program is
-        waited for and recorded as a set-up span (`_first_call`)."""
+        its (loss or losses, metric) and the `args` of its
+        `train.dispatch` span, which the drain that fetches the metric
+        adds it to. The first call of each program is waited for and
+        recorded as a set-up span (`_first_call`)."""
         with trace.span("train.dispatch", step=self.step) as span:
             name = step_fn.__name__
             first = name not in self._called
@@ -762,13 +748,31 @@ class Estimator:
                 # the model's metric of a profiled step, left on the
                 # device: whoever reads the record fetches it
                 span.args["metric"] = metric
-        return loss, metric
+        return loss, metric, span.args
 
     def _drain(self, history: list, fetched: list, concat: bool) -> None:
-        """Fetches the on-device losses of `history` into `fetched`."""
-        with trace.span("train.drain", step=self.step):
-            joined = jnp.concatenate(history) if concat else jnp.stack(history)
-            fetched.extend(np.asarray(joined).tolist())
+        """Fetches what `history` holds on the device, a (loss or
+        losses, metric, its dispatch span's `args`) a dispatch, in one
+        join and one copy: the losses into `fetched`, each metric into
+        those `args` as the float `model_metric`."""
+        losses, metrics, dispatched = zip(*history)
+        steps = sum(row.shape[0] for row in losses) if concat else len(losses)
+        with trace.counted("train.drain", step=self.step):
+            if concat:  # a dispatch's losses are a row: its metric joins as one
+                joined = jnp.concatenate([*losses, *(m[None] for m in metrics)])
+            else:
+                joined = jnp.stack([*losses, *metrics])
+            # asked for before the wait, as `np.asarray` alone would: the
+            # transfer follows the join on the device with no round trip
+            # through the host between them
+            joined.copy_to_host_async()
+            with trace.span("train.drain.wait"):
+                jax.block_until_ready(joined)  # the device, or the runtime
+            with trace.span("train.drain.copy"):
+                values = np.asarray(joined).tolist()  # what is left of it
+        fetched.extend(values[:steps])
+        for args, value in zip(dispatched, values[steps:]):
+            args["model_metric"] = value
         history.clear()
 
     def _checkpoint(self) -> None:
@@ -778,7 +782,7 @@ class Estimator:
     def _train_steps(self, steps: int, log: bool, save: bool):
         step_fn = self._train_step()
         t0 = time.time()
-        history = []  # on-device losses not yet drained to the host
+        history = []  # (loss, metric, span args) not yet drained to the host
         fetched: list[float] = []
         # drain in chunks: keeping one live device scalar per step for a
         # long run pins an unbounded number of small device buffers
@@ -786,31 +790,33 @@ class Estimator:
         profile = _ProfileWindow(self)
         try:
             for _ in range(steps):
-                with trace.span("train.next_batch", step=self.step):
-                    batch = self._next_batch(1)
-                    rngs = self._rngs(self.step)
-                with profile.step():
-                    loss, metric = self._dispatch(step_fn, rngs, batch)
-                self.step += 1
-                profile.stop_if_done(loss)
-                if log and self.step % self.cfg.log_steps == 0:
-                    loss_v = float(loss)
-                    dt = time.time() - t0
-                    print(
-                        f"step {self.step}: loss={loss_v:.4f} "
-                        f"metric={float(metric):.4f} ({self.step / dt:.1f} it/s)"
-                    )
-                # keep losses on device — a float() here would force a
-                # blocking device→host round trip every step and
-                # serialize the pipeline
-                history.append(loss)
-                if len(history) >= drain_every:
-                    self._drain(history, fetched, concat=False)
-                if (
-                    self.cfg.checkpoint_steps
-                    and self.step % self.cfg.checkpoint_steps == 0
-                ):
-                    self._checkpoint()
+                with trace.span("train.step", step=self.step):
+                    with trace.span("train.next_batch", step=self.step):
+                        batch = self._next_batch(1)
+                        rngs = self._rngs(self.step)
+                    with profile.step():
+                        loss, metric, args = self._dispatch(step_fn, rngs, batch)
+                    self.step += 1
+                    profile.stop_if_done(loss)
+                    if log and self.step % self.cfg.log_steps == 0:
+                        loss_v = float(loss)
+                        dt = time.time() - t0
+                        print(
+                            f"step {self.step}: loss={loss_v:.4f} "
+                            f"metric={float(metric):.4f} "
+                            f"({self.step / dt:.1f} it/s)"
+                        )
+                    # keep losses (and the model's metric) on device — a
+                    # float() here would force a blocking device→host
+                    # round trip every step and serialize the pipeline
+                    history.append((loss, metric, args))
+                    if len(history) >= drain_every:
+                        self._drain(history, fetched, concat=False)
+                    if (
+                        self.cfg.checkpoint_steps
+                        and self.step % self.cfg.checkpoint_steps == 0
+                    ):
+                        self._checkpoint()
         finally:
             # a raising loop (dead shard, OOM, poisoned batch) must still
             # surface the losses fetched so far and leave a best-effort
@@ -866,44 +872,46 @@ class Estimator:
         profile = _ProfileWindow(self, min_steps=k)
         try:
             for _ in range(calls):
-                with trace.span("train.next_batch", step=self.step):
-                    batch = self._next_batch(k)
-                    rngs = self._rngs_stacked(self.step, k)
-                with profile.step():
-                    losses, metric = self._dispatch(step_fn, rngs, batch)
-                self.step += k
-                profile.stop_if_done(losses)
-                if log and self.step % max(self.cfg.log_steps, 1) < k:
-                    dt = time.time() - t0
-                    print(
-                        f"step {self.step}: loss={float(losses[-1]):.4f} "
-                        f"metric={float(metric):.4f} "
-                        f"({self.step / dt:.1f} it/s)"
-                    )
-                history.append(losses)
-                if len(history) >= drain_every:
-                    self._drain(history, fetched, concat=True)
-                if (
-                    self.cfg.checkpoint_steps
-                    and self.step % self.cfg.checkpoint_steps < k
-                ):
-                    self._checkpoint()
+                with trace.span("train.step", step=self.step, steps=k):
+                    with trace.span("train.next_batch", step=self.step):
+                        batch = self._next_batch(k)
+                        rngs = self._rngs_stacked(self.step, k)
+                    with profile.step():
+                        losses, metric, args = self._dispatch(step_fn, rngs, batch)
+                    self.step += k
+                    profile.stop_if_done(losses)
+                    if log and self.step % max(self.cfg.log_steps, 1) < k:
+                        dt = time.time() - t0
+                        print(
+                            f"step {self.step}: loss={float(losses[-1]):.4f} "
+                            f"metric={float(metric):.4f} "
+                            f"({self.step / dt:.1f} it/s)"
+                        )
+                    history.append((losses, metric, args))
+                    if len(history) >= drain_every:
+                        self._drain(history, fetched, concat=True)
+                    if (
+                        self.cfg.checkpoint_steps
+                        and self.step % self.cfg.checkpoint_steps < k
+                    ):
+                        self._checkpoint()
             profile.stop(self.params)
             if remainder:
                 single = self._train_step()
-                with trace.span("train.next_batch", step=self.step):
-                    item = (
-                        (self._flow_keys(self.step, remainder),)
-                        if self._device_flow is not None
-                        else self._put(self.batch_fn(), stacked=True)
-                    )
-                for i in range(remainder):
-                    batch = jax.tree_util.tree_map(lambda x: x[i], item)
-                    loss, _ = self._dispatch(
-                        single, self._rngs(self.step), batch
-                    )
-                    self.step += 1
-                    history.append(loss[None])
+                with trace.span("train.step", step=self.step, steps=remainder):
+                    with trace.span("train.next_batch", step=self.step):
+                        item = (
+                            (self._flow_keys(self.step, remainder),)
+                            if self._device_flow is not None
+                            else self._put(self.batch_fn(), stacked=True)
+                        )
+                    for i in range(remainder):
+                        batch = jax.tree_util.tree_map(lambda x: x[i], item)
+                        loss, metric, args = self._dispatch(
+                            single, self._rngs(self.step), batch
+                        )
+                        self.step += 1
+                        history.append((loss[None], metric, args))
         finally:
             # same contract as train(): a raising loop still drains the
             # fetched losses and leaves a best-effort checkpoint

@@ -1,17 +1,25 @@
 """The layers' names: `euler.*` scopes in both step programs' HLO
 metadata, and `utils/trace.py`'s host spans and their record."""
 
+import gc
+import hashlib
+import json
+import os
 import re
 import threading
 import time
 
+import jax
 import pytest
 
 from euler_tpu.dataflow import DeviceSageFlow, DeviceWalkFlow
+from euler_tpu.dataflow.device import DeviceSequenceFlow
 from euler_tpu.datasets.synthetic import random_graph
 from euler_tpu.estimator import DeviceFeatureCache, Estimator, EstimatorConfig
 from euler_tpu.models import GraphSAGESupervised
 from euler_tpu.models.embedding_models import SkipGramModel
+from euler_tpu.models.sequence_lm import Qwen3NextLM
+from euler_tpu.estimator import estimator as estimator_module
 from euler_tpu.utils import trace
 
 SCOPES = {
@@ -37,6 +45,20 @@ def _estimator(kind, graph, tmp_path, **cfg):
             dims=[8, 8], label_dim=2, encoder_dim=8, max_id=120
         )
         cache = DeviceFeatureCache(graph, ["feat"])
+    elif kind == "sequence":
+        flow = DeviceSequenceFlow(graph, batch_size=2, seq_len=32, doc_len=8)
+        model = Qwen3NextLM(
+            vocab_size=120, hidden_size=32, num_layers=2,
+            full_attention_interval=2, num_heads=2, num_kv_heads=1,
+            head_dim=16, rope_theta=1e4, partial_rotary_factor=0.25,
+            attention_block=16, linear_num_key_heads=1,
+            linear_num_value_heads=2, linear_key_head_dim=16,
+            linear_value_head_dim=16, linear_conv_kernel_dim=4, chunk=8,
+            num_experts=4, num_experts_per_tok=2, moe_intermediate_size=16,
+            shared_expert_intermediate_size=16, norm_topk_prob=True,
+            experts_here=(0, 2), rms_norm_eps=1e-6, loss_chunks=2,
+        )
+        cache = None
     else:
         flow = DeviceWalkFlow(graph, batch_size=4, walk_len=3, window=1)
         model = SkipGramModel(num_nodes=120, dim=8)
@@ -152,7 +174,10 @@ def test_record_is_bounded_and_keeps_set_up_spans():
     got = trace.spans()
     steps = [s for s in got if not s.name.startswith(trace.SETUP_PREFIXES)]
     assert len(steps) == trace.MAX_SPANS
-    assert steps[0].step == 10  # the oldest went
+    # the oldest went: ten, and one more for each run of the collector
+    # that the loop set off (a `gc` span of the same kind)
+    mine = [s for s in steps if s.name == "t.step"]
+    assert mine[0].step == 10 + len(steps) - len(mine)
     kept = [s.name for s in _mine(since) if s.name.startswith(trace.SETUP_PREFIXES)]
     assert kept == ["stage.t_bounded", "step.first_call"]
     assert len(got) <= trace.MAX_SPANS + trace.MAX_SETUP_SPANS
@@ -217,9 +242,11 @@ def test_train_records_set_up_and_steps(graph, tmp_path, kind, k):
     steps = [0, 1, 2, 3] if k == 1 else [0, 2, 4, 6, 8]
     assert [s.step for s in dispatches] == steps
     assert [s.step for s in batches] == steps
-    assert all(by_id[s.parent].name == "train" for s in dispatches + batches)
+    assert all(by_id[s.parent].name == "train.step" for s in dispatches + batches)
+    assert all(by_id[by_id[s.parent].parent].name == "train" for s in dispatches)
     drains = [s for s in got if s.name == "train.drain"]
     assert [s.step for s in drains] == [2 * k, est.step]
+    assert all(by_id[s.parent].name == "train" for s in drains)
     assert est.step == 5 * k - 1
     assert "train.save" not in names
 
@@ -259,3 +286,200 @@ def test_profiled_stretch_has_steps_spans_and_scopes(graph, tmp_path):
     # a second call takes no second trace
     est.train(2, log=False, save=False)
     assert len(list((tmp_path / "prof").rglob("*.xplane.pb"))) == 1
+
+
+# -- what interrupts the host ----------------------------------------------
+
+
+@pytest.fixture
+def no_collector_of_its_own():
+    """Only the runs a test plants: the collector's own are off."""
+    was = gc.isenabled()
+    gc.disable()
+    yield
+    if was:
+        gc.enable()
+
+
+def test_a_counted_span_keeps_only_the_counters_that_moved(monkeypatch):
+    since = time.perf_counter_ns()
+    readings = iter([(5, 7, 0, 100), (5, 9, 0, 103), (5, 9, 0, 103), (5, 9, 0, 103)])
+    monkeypatch.setattr(trace, "_interruptions", lambda: next(readings))
+    monkeypatch.setattr(trace, "INTERRUPTIONS_COUNTED", True)
+    with trace.counted("t.counted", step=3) as moved:
+        pass
+    with trace.counted("t.counted", step=4) as idle:
+        pass
+    assert moved.args == {"step": 3, "nvcsw": 2, "minflt": 3}
+    assert idle.args == {"step": 4}
+    kept = [s for s in _mine(since) if s.name == "t.counted"]
+    assert [s.args for s in kept] == [moved.args, idle.args]
+    assert [s.step for s in kept] == [3, 4]
+
+
+def test_a_counted_span_is_a_plain_one_where_the_host_counts_nothing(monkeypatch):
+    """gVisor has `getrusage(RUSAGE_THREAD)` and fills no counter: the
+    call is not made there (6 us each on the chip tool's machine)."""
+    def never():
+        raise AssertionError("getrusage asked on a host that counts nothing")
+
+    monkeypatch.setattr(trace, "_interruptions", never)
+    monkeypatch.setattr(trace, "INTERRUPTIONS_COUNTED", False)
+    with trace.counted("t.uncounted", step=1) as quiet:
+        bytearray(1 << 20)
+    assert quiet.args == {"step": 1}
+
+
+@pytest.mark.skipif(not trace.INTERRUPTIONS_COUNTED, reason="this host counts none")
+def test_a_counted_span_sees_its_own_threads_page_faults():
+    with trace.counted("t.faults") as touched:
+        pages = bytearray(32 << 20)
+        pages[::4096] = b"\x01" * len(pages[::4096])
+    assert touched.args.get("minflt", 0) >= 1
+    assert set(touched.args) <= {"nivcsw", "nvcsw", "majflt", "minflt"}
+
+
+def test_a_collector_run_is_a_span_under_the_span_it_interrupted(
+    no_collector_of_its_own,
+):
+    since = time.perf_counter_ns()
+    with trace.span("t.outer") as outer:
+        with trace.span("t.inner") as inner:
+            gc.collect()
+        gc.collect(0)
+    gc.collect()  # under no span: nobody's interruption
+    with trace.span("stage.t_gc"):
+        gc.collect()  # set-up allocates by the million: not kept
+    got = _mine(since)
+    by_id = {s.id: s for s in got}
+    runs = [s for s in got if s.name == "gc"]
+    assert [(r.parent, r.args["generation"]) for r in runs] == [
+        (inner.id, 2), (outer.id, 0),
+    ]
+    for run in runs:
+        assert set(run.args) == {"generation"}
+        held = by_id[run.parent]
+        assert held.start_ns <= run.start_ns <= run.end_ns <= held.end_ns
+        assert run.thread == held.thread
+
+
+def test_a_compile_outside_a_first_call_is_a_late_compile():
+    import jax.numpy as jnp
+
+    since = time.perf_counter_ns()
+    x = jnp.ones(3)
+    with trace.span("t.late") as late:
+        jax.jit(lambda v: v * 3 + 1)(x)
+    with trace.span("t.collecting") as collecting, trace.compiles() as events:
+        jax.jit(lambda v: v * 5 + 2)(x)
+    with trace.span("stage.t_compile"):
+        jax.jit(lambda v: v * 7 + 3)(x)  # in set-up nothing is late
+    jax.jit(lambda v: v * 9 + 4)(x)  # under no span
+    found = [s for s in _mine(since) if s.name == "late_compile"]
+    assert {s.parent for s in found} == {late.id}
+    assert {"trace", "lower", "compile"} <= {s.args["event"] for s in found}
+    for s in found:
+        assert set(s.args) == {"event"} and late._t0 <= s.start_ns < s.end_ns
+    assert {"trace", "lower", "compile"} <= {kind for kind, _, _ in events}
+    assert all(collecting._t0 <= lo <= hi for _, lo, hi in events)
+
+
+def test_innermost_gives_every_instant_to_the_interval_that_began_last():
+    nested = [(0, 100, "a"), (10, 60, "b"), (20, 30, "c"), (70, 80, "b"), (10, 15, "d")]
+    assert trace.innermost(nested) == [
+        (0, 10, "a"), (10, 15, "d"), (15, 20, "b"), (20, 30, "c"), (30, 60, "b"),
+        (60, 70, "a"), (70, 80, "b"), (80, 100, "a"),
+    ]
+    # what no interval holds is nobody's, and one key's neighbours are joined
+    assert trace.innermost([(0, 10, "a"), (10, 20, "a"), (30, 40, "b")]) == [
+        (0, 20, "a"), (30, 40, "b"),
+    ]
+    assert trace.innermost([]) == []
+
+
+def test_self_stretches_of_nested_events_are_disjoint():
+    events = [
+        ("trace", 10, 30),      # an inner jit, traced inside the outer trace
+        ("lower", 30, 40),      # ... lowered
+        ("cache_fetch", 42, 58),
+        ("compile", 40, 60),    # ... and compiled there
+        ("trace", 0, 100),      # the step's own trace
+        ("trace", 110, 120),    # a kernel's body, traced while lowering
+        ("lower", 100, 150),
+        ("cache_fetch", 160, 390),
+        ("compile", 150, 400),
+    ]
+    got = trace.self_stretches(events, ("trace", "lower", "compile"))
+    assert got == {
+        "trace": [(0, 30), (60, 100), (110, 120)],
+        "lower": [(30, 40), (100, 110), (120, 150)],
+        "compile": [(40, 60), (150, 400)],
+    }
+    assert sum(hi - lo for parts in got.values() for lo, hi in parts) == 400
+    # the old record, first start to last end a kind, summed to 100 + 120 + 360
+    assert trace.self_stretches(events, ("cache_fetch",)) == {
+        "cache_fetch": [(42, 58), (160, 390)]
+    }
+    assert trace.self_stretches([], ("trace",)) == {"trace": []}
+
+
+def test_first_call_children_cover_disjoint_time():
+    """An inner `jax.jit` is traced, lowered and compiled inside the
+    outer trace: the children still add up to no more than the call."""
+    import jax.numpy as jnp
+
+    since = time.perf_counter_ns()
+
+    @jax.jit
+    def outer(v):
+        # a value made eagerly inside the trace compiles a program there
+        with jax.ensure_compile_time_eval():
+            table = jax.jit(lambda n: jnp.arange(n) * 2.5, static_argnums=0)(3)
+        return jax.jit(lambda w: w * table)(v)
+
+    with estimator_module._first_call("t_nested", {}):
+        jax.block_until_ready(outer(jnp.ones(3)))
+    got = _mine(since)
+    (call,) = [s for s in got if s.name == "step.first_call"]
+    kids = [s for s in got if s.parent == call.id]
+    assert {s.name for s in kids} >= {
+        "step.first_call.trace", "step.first_call.lower", "step.first_call.compile",
+    }
+    staged = sorted(
+        (s.start_ns, s.end_ns) for s in kids
+        if not s.name.endswith("cache_fetch")
+    )
+    for (_, end), (start, _) in zip(staged, staged[1:]):
+        assert end <= start
+    total = sum(hi - lo for lo, hi in staged)
+    assert 0 < total <= call.end_ns - call.start_ns
+    traced = [s for s in kids if s.name.endswith(".trace")]
+    assert len(traced) >= 2  # the inner program's compile splits the trace
+    assert not [s for s in got if s.name == "late_compile" and s.parent == call.id]
+
+
+# -- the change is the host's ------------------------------------------------
+
+STEP_HASHES = os.path.join(os.path.dirname(__file__), "step_program_hashes.json")
+
+
+def step_program_hash(est) -> str:
+    est._ensure_init()
+    args = (est.params, est.opt_state, est._tables(), est._rngs(0), *est._next_batch(1))
+    text = est._train_step().lower(*args).as_text()
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("kind", ["sage", "sequence"])
+def test_the_step_program_is_the_recorded_one(kind, graph, tmp_path):
+    """Spans, counters and the drain's fetch are the host's: the lowered
+    `train_step` of a graph model and of a sequence model hash as
+    `step_program_hashes.json` says (written at PR 38's parent). A PR
+    that changes the step program on purpose writes the hashes this
+    test's failure shows into that file."""
+    with open(STEP_HASHES) as f:
+        want = json.load(f)
+    if want["jax"] != jax.__version__:
+        pytest.skip(f"hashes are of JAX {want['jax']}, this is {jax.__version__}")
+    est, _ = _estimator(kind, graph, tmp_path)
+    assert step_program_hash(est) == want[kind]

@@ -5,6 +5,9 @@ cluster-separable, so a couple of GNN layers must drive the loss down —
 the automated stand-in for the reference's manual example regression tables.
 """
 
+import gc
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -460,3 +463,157 @@ def test_model_key_structural_not_repr(tmp_path):
     )
     assert _structural_key(m4) != k1
     hash(_structural_key(m4))
+
+
+# -- the train loop's record (euler_tpu/utils/trace.py) ----------------------
+
+
+def _recorded_train(cluster_graph, tmp_path, steps, steps_per_call=1, batch_hook=None):
+    """`est.train(steps)` over host batches, the collector's own runs
+    off; `batch_hook(est)` runs inside every `batch_fn()`. Returns the
+    Estimator and the spans the call left."""
+    from euler_tpu.estimator import stack_batches
+    from euler_tpu.utils import trace
+
+    rng = np.random.default_rng(0)
+    flow = SageDataFlow(
+        cluster_graph, ["feat"], fanouts=[2], label_feature="label", rng=rng
+    )
+    batches = node_batches(cluster_graph, flow, 8, rng=rng)
+    held = []
+
+    def batch_fn():
+        if batch_hook is not None and held:
+            batch_hook(held[0])
+        return batches()
+
+    cfg = EstimatorConfig(
+        model_dir=str(tmp_path / "rec"), learning_rate=0.05, log_steps=10**9,
+        steps_per_call=steps_per_call,
+    )
+    source = stack_batches(batch_fn, steps_per_call) if steps_per_call > 1 else batch_fn
+    est = Estimator(SuperviseModel(conv="gcn", dims=[8], label_dim=2), source, cfg)
+    held.append(est)
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        since = time.perf_counter_ns()
+        est.train(steps, log=False, save=False)
+    finally:
+        if was:
+            gc.enable()
+    return est, [s for s in trace.spans() if s.start_ns >= since]
+
+
+def test_a_collector_run_in_batch_fn_is_a_gc_span_of_that_step(cluster_graph, tmp_path):
+    def collect_at_step_two(est):
+        if est.step == 2 and est.params is not None:
+            gc.collect()
+
+    _, got = _recorded_train(cluster_graph, tmp_path, 5, batch_hook=collect_at_step_two)
+    by_id = {s.id: s for s in got}
+    (run,) = [s for s in got if s.name == "gc"]  # and nowhere else
+    batch = by_id[run.parent]
+    assert (batch.name, batch.step) == ("train.next_batch", 2)
+    assert (by_id[batch.parent].name, by_id[batch.parent].step) == ("train.step", 2)
+    assert run.args == {"generation": 2}
+    assert batch.start_ns <= run.start_ns <= run.end_ns <= batch.end_ns
+
+
+@pytest.mark.parametrize("steps_per_call,steps", [(1, 5), (4, 8), (4, 10)])
+def test_every_dispatch_has_a_model_metric_after_the_call(
+    cluster_graph, tmp_path, steps_per_call, steps
+):
+    est, got = _recorded_train(cluster_graph, tmp_path, steps, steps_per_call)
+    dispatches = [s for s in got if s.name == "train.dispatch"]
+    whole, rest = divmod(steps, steps_per_call)
+    assert len(dispatches) == whole + rest
+    for s in dispatches:
+        assert isinstance(s.args["model_metric"], float)
+        assert 0.0 <= s.args["model_metric"] <= 1.0  # an f1
+        assert "metric" not in s.args  # no profiler session was live
+    assert len(est.last_losses) == steps
+    assert all(isinstance(x, float) for x in est.last_losses)
+
+
+def test_the_call_and_its_drain_count_what_moved_and_a_step_counts_nothing(
+    cluster_graph, tmp_path, monkeypatch
+):
+    """The thread's counters are read where a stall was found, around
+    the call and around its drain: twice a call, and the loop's body
+    pays nothing for them."""
+    from euler_tpu.utils import trace
+
+    reads = []
+    monkeypatch.setattr(trace, "INTERRUPTIONS_COUNTED", True)
+    monkeypatch.setattr(
+        trace, "_interruptions", lambda: reads.append(0) or (0, len(reads), 0, 0)
+    )
+    _, got = _recorded_train(cluster_graph, tmp_path, 3)
+    named = {s.name: s for s in got}
+    assert len(reads) == 4  # none of them a step's
+    # train opens (1), the drain opens (2) and closes (3), train closes (4)
+    assert named["train"].args == {"steps": 3, "nvcsw": 3}
+    assert named["train.drain"].args == {"step": 3, "nvcsw": 1}
+    steps = [s for s in got if s.name == "train.step"]
+    assert [s.args for s in steps] == [{"step": 0}, {"step": 1}, {"step": 2}]
+    # an undisturbed call's and drain's args are what they were given
+    monkeypatch.setattr(trace, "_interruptions", lambda: (1, 2, 3, 4))
+    _, got = _recorded_train(cluster_graph, tmp_path, 3)
+    named = {s.name: s for s in got}
+    assert named["train"].args == {"steps": 3}
+    assert named["train.drain"].args == {"step": 3}
+
+
+def test_the_drain_says_what_it_waited_for(cluster_graph, tmp_path):
+    _, got = _recorded_train(cluster_graph, tmp_path, 5)
+    (drain,) = [s for s in got if s.name == "train.drain"]
+    assert drain.step == 5
+    kids = sorted((s for s in got if s.parent == drain.id), key=lambda s: s.start_ns)
+    # the join's own compile, the first time, is the drain's too
+    kids = [s for s in kids if s.name != "late_compile"]
+    assert [s.name for s in kids] == ["train.drain.wait", "train.drain.copy"]
+    assert drain.start_ns <= kids[0].start_ns
+    assert kids[0].end_ns <= kids[1].start_ns <= kids[1].end_ns <= drain.end_ns
+
+
+def test_a_jit_first_called_in_batch_fn_is_a_late_compile(cluster_graph, tmp_path):
+    late = jax.jit(lambda x: x * 2.0 + 1.0)
+
+    def compile_at_step_three(est):
+        if est.step == 3 and est.params is not None:
+            late(jnp.ones(4))
+
+    _, got = _recorded_train(cluster_graph, tmp_path, 5, batch_hook=compile_at_step_three)
+    by_id = {s.id: s for s in got}
+    found = [s for s in got if s.name == "late_compile"]
+    in_batches = [s for s in found if by_id[s.parent].name == "train.next_batch"]
+    assert {by_id[s.parent].step for s in in_batches} == {3}
+    assert {"trace", "lower", "compile"} <= {s.args["event"] for s in in_batches}
+    # the step program's own compile is its first call's, and no late one
+    (first,) = [s for s in got if s.name == "step.first_call"]
+    kids = [s for s in got if s.parent == first.id]
+    assert kids and all(s.name.startswith("step.first_call.") for s in kids)
+    assert not [s for s in found if by_id[s.parent].name == "train.dispatch"]
+
+
+def test_both_drivers_leave_the_same_span_tree(cluster_graph, tmp_path):
+    def tree(steps_per_call):
+        _, got = _recorded_train(cluster_graph, tmp_path, 8, steps_per_call)
+        by_id = {s.id: s for s in got}
+        return {
+            (s.name, by_id[s.parent].name if s.parent in by_id else None)
+            for s in got
+            if s.name != "late_compile" and not s.name.startswith("step.first_call")
+        }
+
+    single, scanned = tree(1), tree(4)
+    assert single == scanned == {
+        ("train", None),
+        ("train.step", "train"),
+        ("train.next_batch", "train.step"),
+        ("train.dispatch", "train.step"),
+        ("train.drain", "train"),
+        ("train.drain.wait", "train.drain"),
+        ("train.drain.copy", "train.drain"),
+    }
