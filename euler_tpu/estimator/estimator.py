@@ -371,6 +371,7 @@ _NO_SPAN = contextlib.nullcontext()
 _TRACED_FORMS = (
     "agg_grid", "agg_scatter", "draw_rows", "draw_elements",
     "dsa_layers", "dsa_topk", "dsa_core_masked", "dsa_core_kernel",
+    "attn_core_dense", "attn_core_kernel",
     "mixer_core_kept",
     "swa_layers", "swa_window", "attn_full_layers", "dense_layers",
     "router_sigmoid",
@@ -403,7 +404,12 @@ def _first_call(program: str, tables: dict):
     mixers whose layer keeps their attention core's output through its
     rematerialisation, so that the core's loop of query blocks runs
     twice a step and not three times (`layers/sequence.py:_keep_core`:
-    every softmax mixer; a `GatedDeltaNet` keeps nothing), `swa_layers`
+    every softmax mixer; a `GatedDeltaNet` keeps nothing),
+    `attn_core_kernel` the `GatedAttention` layers whose causal or
+    sliding-window core is the Pallas kernels, one call a layer whose
+    forward runs once a step, and `attn_core_dense` those whose core is
+    the loop of dense query blocks (`seq_ops.causal_tile`: the shapes
+    decide), `swa_layers`
     the `GatedAttention` layers with a window, `swa_window` their windows
     summed, `attn_full_layers` those without one, `dense_layers` the
     decoder layers whose feed-forward is a `DenseMLP`, `router_sigmoid`

@@ -14,8 +14,8 @@ its parts for a trace: `euler.gdn.{proj,conv,scan,out}`,
 `euler.dsa.{proj,index,select,core,aux,out}`. Every mixer is called as
 `(x, positions) -> (y, its own loss or None)`. The two softmax mixers
 name their core's output `CORE_OUTPUT` (`_keep_core`): a rematerialised
-decoder layer keeps that one value of its forward
-(models/sequence_lm.py).
+decoder layer keeps that value of its forward, and the causal kernels'
+logsumexp with it (models/sequence_lm.py).
 """
 
 from __future__ import annotations
@@ -36,17 +36,25 @@ _MATRIX = nn.initializers.normal(stddev=0.02)
 CORE_OUTPUT = "mixer_core"
 
 
-def _keep_core(o):
-    """Names a mixer's core output [B, G, R, T, d], the one thing the
-    rest of its layer wants from the loop over query blocks. The blocks
-    are checkpointed one by one: their residuals are their inputs, which
-    the projections remake, so a layer rematerialised under
-    `save_only_these_names(CORE_OUTPUT)` has no use for a second run of
-    the loop and each block's forward runs twice a step (forward, and
-    before its own backward), not three times. Tallied as
-    `mixer_core_kept`, once a mixer that is traced."""
-    trace.count("mixer_core_kept")
-    return checkpoint_name(o, CORE_OUTPUT)
+def _keep_core(a):
+    """Names what a rematerialised layer keeps of its mixer's core
+    (models/sequence_lm.py: `save_only_these_names(CORE_OUTPUT)`): the
+    core's output [B, G, R, T, d], the one thing the rest of the layer
+    wants from it, and where the core is the causal kernels
+    (`seq_ops.causal_tile`) their logsumexp [B, G, R, T] as well. How
+    often the core then runs a step:
+
+    - by dense blocks, and `IndexedSparseAttention` in either form: the
+      blocks are checkpointed one by one and their residuals are their
+      inputs, which the projections remake, so the layer's second forward
+      has no use for the loop over the blocks and each block's forward
+      runs twice (forward, and before its own backward), not three times;
+    - `GatedAttention` by tiles: output and logsumexp are all the
+      backward kernels read of the forward kernel, so that one runs once
+      and each backward kernel once.
+
+    A mixer that keeps its core counts itself as `mixer_core_kept`."""
+    return checkpoint_name(a, CORE_OUTPUT)
 
 
 def rms(x, eps: float):
@@ -177,9 +185,14 @@ class GatedAttention(nn.Module):
     [query | gate]). With a `window`, a query sees that many keys, itself
     among them, and the layer's scopes are `euler.swa.*`, so that a trace
     tells the two kinds of layer apart; without one it sees every earlier
-    key, under `euler.attn.*`. The softmax runs block by block
-    (`seq_ops.blockwise_causal_attention`); what it returns, before the
-    gate, is the layer's `CORE_OUTPUT`.
+    key, under `euler.attn.*`. The softmax
+    (`seq_ops.blockwise_causal_attention`) runs tile by tile in Pallas
+    kernels, one call a layer over the whole sequence, where length,
+    `block` and head are whole 128-wide tiles of the chip
+    (`seq_ops.causal_tile`: the shapes decide), and block by block as
+    dense float32 tensors everywhere else; a layer is tallied
+    `attn_core_kernel` or `attn_core_dense`. What it returns, before the
+    gate, is the layer's `CORE_OUTPUT` (`_keep_core`).
     x [B, T, H] -> (y [B, T, H], None: no loss of its own)."""
 
     num_heads: int
@@ -200,6 +213,7 @@ class GatedAttention(nn.Module):
         w_v = self.param("v_proj", _MATRIX, (hidden, nkv * d), jnp.float32)
         w_o = self.param("o_proj", _MATRIX, (nq * d, hidden), jnp.float32)
         kind = "attn" if self.window is None else "swa"
+        trace.count("mixer_core_kept")
         if self.window is None:
             trace.count("attn_full_layers")
         else:
@@ -218,10 +232,12 @@ class GatedAttention(nn.Module):
             k = turn(RMSNorm(self.eps, name="k_norm")(k))
         with trace.scope(f"{kind}.core"):
             q = q.reshape(batch, length, nkv, nq // nkv, d).transpose(0, 2, 3, 1, 4)
-            o = _keep_core(seq_ops.blockwise_causal_attention(
+            by_tiles = seq_ops.causal_tile(q, self.block)
+            trace.count("attn_core_kernel" if by_tiles else "attn_core_dense")
+            o = seq_ops.blockwise_causal_attention(
                 q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
-                scale=d**-0.5, block=self.block, window=self.window,
-            ))
+                scale=d**-0.5, block=self.block, window=self.window, keep=_keep_core,
+            )
         with trace.scope(f"{kind}.out"):
             o = o.transpose(0, 3, 1, 2, 4).reshape(batch, length, nq, d)
             o = o * jax.nn.sigmoid(gate.astype(jnp.float32))
@@ -315,6 +331,7 @@ class IndexedSparseAttention(nn.Module):
         i_bias = self.param("index_k_norm_b", nn.initializers.zeros, (di,), jnp.float32)
         trace.count("dsa_layers")
         trace.count("dsa_topk", self.topk)
+        trace.count("mixer_core_kept")
         with trace.scope("dsa.proj"):
             q = (x @ w_q).reshape(batch, length, nq, d)
             k = (x @ w_k).reshape(batch, length, nkv, d)

@@ -1,23 +1,36 @@
-"""Softmax attention of a block of queries under a mask of picked keys,
-tile by tile on the chip (Pallas TPU): `seq_ops.masked_attention` and
-`seq_ops.attention_share` where every extent is whole tiles.
+"""Softmax attention tile by tile on the chip (Pallas TPU), where every
+extent is whole tiles: of a block of queries under a mask of picked keys
+(`seq_ops.masked_attention`, `seq_ops.attention_share`), and of a whole
+sequence under the causal line and a sliding window
+(`seq_ops.blockwise_causal_attention`).
 
-A block's scores [B, G, R, t, S] never exist outside a tile: the forward
+Scores [B, G, R, t, S] never exist outside a tile: a forward kernel
 keeps a running max, sum and output per row and head (online softmax)
-and leaves the output and the logsumexp; the backward makes a tile's
-probabilities again from the logsumexp (flash attention), once, for all
-three cotangents; a third, forward-only kernel sums a tile's
-probabilities over the heads, which is the target of the indexer's KL.
-One grid step holds one tile of keys and the R query heads of one
-key/value head, so the mask's tile (int8, one for all heads) is read
-once a group and becomes the additive float32 tile the heads share; a
-tile the mask empties (`live` 0: at the cell, a run's tiles beyond the
-block's own diagonal) runs nothing.
+and leaves the output and the logsumexp; a backward kernel makes a tile's
+probabilities again from the logsumexp (flash attention). One grid step
+holds one tile of keys and the R query heads of one key/value head, so
+what the heads share of a tile (its keys, its values, what its mask adds
+to a score) is fetched or made once a group.
 
-Arithmetic: q, k, v, the probabilities and the cotangents enter the MXU
-as bfloat16 and accumulate in float32 — what XLA's default precision
-makes of a float32 `einsum` on the TPU; max, sum, logsumexp, the output
-accumulator, the shares and every cotangent are float32.
+Under a mask of picked keys the mask's tile (int8, one for all heads) is
+read once a group and becomes the additive float32 tile the heads share;
+a tile the mask empties (`live` 0: at the cell, a run's tiles beyond the
+block's own diagonal) runs nothing; one backward kernel makes all three
+cotangents and holds a head's dq [R, t, d] meanwhile; a third,
+forward-only kernel sums a tile's probabilities over the heads, which is
+the target of the indexer's KL.
+
+Under the causal line and a window no mask is read and no `live` table
+made: which tiles of keys a tile of queries is given, which of them are
+run bare and which get an additive tile made from two iotas follows from
+the tiles' places in the grid (`_reach`, `_by_case`); one call covers the
+whole sequence, and the backward is two kernels (dk and dv; dq), so that
+nothing sequence-long is held on the chip.
+
+Arithmetic, everywhere: q, k, v, the probabilities and the cotangents
+enter the MXU as bfloat16 and accumulate in float32 — what XLA's default
+precision makes of a float32 `einsum` on the TPU; max, sum, logsumexp,
+the output accumulator, the shares and every cotangent are float32.
 
 Off the TPU the same kernels run through the Pallas interpreter: which
 lowering is used follows the platform the computation is placed on
@@ -50,16 +63,19 @@ def _tile(extent: int, asked: int | None) -> int:
     return asked or next(t for t in TILES if extent % t == 0)
 
 
-def _call(kernel, *, name, grid, in_specs, out_specs, out_shape, scratch=(), reduced=1):
-    """`kernel` over `grid` with the `live` table prefetched into scalar
-    memory: compiled by Mosaic on a TPU, interpreted anywhere else. The
-    last `reduced` axes of the grid carry an accumulator."""
+def _call(
+    kernel, *, name, grid, in_specs, out_specs, out_shape, scratch=(), reduced=1, prefetch=1,
+):
+    """`kernel` over `grid` with its first `prefetch` arguments (the
+    `live` table) prefetched into scalar memory: compiled by Mosaic on a
+    TPU, interpreted anywhere else. The last `reduced` axes of the grid
+    carry an accumulator."""
 
     def build(interpret):
         return pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+                num_scalar_prefetch=prefetch, grid=grid, in_specs=in_specs,
                 out_specs=out_specs, scratch_shapes=list(scratch),
             ),
             out_shape=out_shape,
@@ -110,45 +126,68 @@ def _across(column, width: int):
 # -- forward ---------------------------------------------------------------
 
 
+def _start(m_ref, l_ref, acc_ref):
+    """Before a tile of queries' first tile of keys."""
+    m_ref[...] = jnp.full_like(m_ref, _DROPPED)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _attend(q_ref, k_ref, v_ref, bias_ref, m_ref, l_ref, acc_ref, scale: float):
+    """One tile of keys into every head's running max, sum and output;
+    `bias_ref` is added to each head's scores, None where the tile drops
+    no key."""
+    heads, _, head_dim = q_ref.shape
+    keys = k_ref.shape[0]
+    k, v = k_ref[...], v_ref[...]
+    for h in range(heads):
+        s = jax.lax.dot_general(
+            q_ref[h], k, _NT, preferred_element_type=jnp.float32
+        ) * scale
+        if bias_ref is not None:
+            s = s + bias_ref[...]
+        m_prev = m_ref[h]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - _across(m_next, keys))
+        alpha = jnp.exp(m_prev - m_next)
+        l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[h] = m_next
+        acc_ref[h] = _across(alpha, head_dim) * acc_ref[h] + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32
+        )
+
+
+def _leave(o_ref, lse_ref, m_ref, l_ref, acc_ref, lse_along_lanes: bool = False):
+    """After a tile of queries' last tile of keys. The logsumexp is left
+    as it was kept, every lane a row's value [rows, LANES], or one row a
+    head [1, rows], along the lanes as a backward kernel reads it."""
+    heads, _, head_dim = o_ref.shape
+    for h in range(heads):
+        total = l_ref[h]
+        o_ref[h] = acc_ref[h] / _across(total, head_dim)
+        lse = m_ref[h] + jnp.log(total)
+        lse_ref[h] = lse.T[:1] if lse_along_lanes else lse
+
+
 def _forward_kernel(
     live_ref, q_ref, k_ref, v_ref, keep_ref, o_ref, lse_ref,
     m_ref, l_ref, acc_ref, bias_ref, *, scale: float,
 ):
     b, qi, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     nq, nk = pl.num_programs(2), pl.num_programs(3)
-    heads, _, head_dim = q_ref.shape
-    keys = k_ref.shape[0]
 
     @pl.when(ki == 0)
     def _():
-        m_ref[...] = jnp.full_like(m_ref, _DROPPED)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _start(m_ref, l_ref, acc_ref)
 
     @pl.when(live_ref[(b * nq + qi) * nk + ki] != 0)
     def _():
         bias_ref[...] = _bias(keep_ref)
-        k, v = k_ref[...], v_ref[...]
-        for h in range(heads):
-            s = jax.lax.dot_general(
-                q_ref[h], k, _NT, preferred_element_type=jnp.float32
-            ) * scale + bias_ref[...]
-            m_prev = m_ref[h]
-            m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - _across(m_next, keys))
-            alpha = jnp.exp(m_prev - m_next)
-            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=-1, keepdims=True)
-            m_ref[h] = m_next
-            acc_ref[h] = _across(alpha, head_dim) * acc_ref[h] + jnp.dot(
-                p.astype(v.dtype), v, preferred_element_type=jnp.float32
-            )
+        _attend(q_ref, k_ref, v_ref, bias_ref, m_ref, l_ref, acc_ref, scale)
 
     @pl.when(ki == nk - 1)
     def _():
-        for h in range(heads):
-            total = l_ref[h]
-            o_ref[h] = acc_ref[h] / _across(total, head_dim)
-            lse_ref[h] = m_ref[h] + jnp.log(total)
+        _leave(o_ref, lse_ref, m_ref, l_ref, acc_ref)
 
 
 @_traced_once
@@ -403,3 +442,281 @@ def share(
         jnp.broadcast_to(lse[..., None], lse.shape + (LANES,)),
         float(scale), bq, bk,
     )
+
+
+# -- the mask from positions ---------------------------------------------------
+#
+# Causal attention of a whole sequence, with or without a window: query t
+# sees key s iff `t - window < s <= t`. Queries and keys are cut into
+# tiles of one size; a tile of queries sees the `_reach` tiles of keys
+# that end at its own, and how a tile of keys lies to it follows from
+# `delta`, the query tile's index minus the key tile's: before key 0 (not
+# run, nothing fetched: the index map stays on the tile it held), cut by
+# the causal line (`delta` 0) or by the window's far edge (`delta >=
+# window // tile`): an additive tile made from two iotas, or wholly seen:
+# nothing added at all (`_by_case`). A row's own key is in the last tile it is
+# given, so a row a cut tile leaves with no key is put right there.
+
+
+def _reach(length: int, tile: int, window: int) -> int:
+    """Tiles of keys a tile of queries sees, its own among them."""
+    return min(length // tile, -(-(window - 1) // tile) + 1)
+
+
+def _position_bias(bias_ref, delta, window: int, keys_by_rows: bool):
+    """What a tile adds to its scores, 0 or `_DROPPED`: [queries, keys],
+    or [keys, queries] with `keys_by_rows`."""
+    tile = bias_ref.shape[0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, bias_ref.shape, 0)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, bias_ref.shape, 1)
+    # the key's position minus the query's
+    ahead = (rows - lanes if keys_by_rows else lanes - rows) - delta * tile
+    bias_ref[...] = jnp.where((ahead <= 0) & (ahead > -window), 0.0, _DROPPED)
+
+
+def _by_case(live, delta, window: int, bias_ref, keys_by_rows: bool, tile_of):
+    """`tile_of(bias_ref or None)` for a tile that is run (`live`), the
+    one `delta` before the queries' own: under the bias of its positions
+    where it is cut, bare where it is wholly seen."""
+    cut = (delta == 0) | (delta >= window // bias_ref.shape[0])
+
+    @pl.when(live & cut)
+    def _():
+        _position_bias(bias_ref, delta, window, keys_by_rows)
+        tile_of(bias_ref)
+
+    @pl.when(live & jnp.logical_not(cut))
+    def _():
+        tile_of(None)
+
+
+def _causal_forward_kernel(
+    q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, bias_ref,
+    *, scale: float, window: int,
+):
+    qi, j, reach = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+    delta = reach - 1 - j  # the farthest tile first, the queries' own last
+
+    @pl.when(j == 0)
+    def _():
+        _start(m_ref, l_ref, acc_ref)
+
+    _by_case(
+        delta <= qi, delta, window, bias_ref, False,
+        lambda bias: _attend(q_ref, k_ref, v_ref, bias, m_ref, l_ref, acc_ref, scale),
+    )
+
+    @pl.when(j == reach - 1)
+    def _():
+        _leave(o_ref, lse_ref, m_ref, l_ref, acc_ref, lse_along_lanes=True)
+
+
+_causal_once = functools.partial(jax.jit, static_argnames=("scale", "tile", "window"))
+
+
+@_causal_once
+def _causal_forward(q, k, v, scale, tile, window):
+    """q [B, G, R, T, d], k, v [B, G, T, d] bfloat16 -> o [B, G, R, T, d],
+    the logsumexp [B, G, R, 1, T] (as the backward kernels cut it),
+    float32."""
+    batch, groups, heads, length, head_dim = q.shape
+    reach = _reach(length, tile, window)
+    per_rows = lambda b, g, qi, j: (b, g, 0, qi, 0)  # noqa: E731
+    per_keys = lambda b, g, qi, j: (b, g, jnp.maximum(qi - (reach - 1) + j, 0), 0)  # noqa: E731
+    return _call(
+        functools.partial(_causal_forward_kernel, scale=scale, window=window),
+        name="causal_core_forward",
+        grid=(batch, groups, length // tile, reach),
+        prefetch=0,
+        in_specs=[
+            pl.BlockSpec((None, None, heads, tile, head_dim), per_rows),
+            pl.BlockSpec((None, None, tile, head_dim), per_keys),
+            pl.BlockSpec((None, None, tile, head_dim), per_keys),
+        ],
+        out_specs=[
+            pl.BlockSpec((None, None, heads, tile, head_dim), per_rows),
+            pl.BlockSpec((None, None, heads, 1, tile), lambda b, g, qi, j: (b, g, 0, 0, qi)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, jnp.float32),
+            jax.ShapeDtypeStruct(q.shape[:3] + (1, length), jnp.float32),
+        ],
+        scratch=[
+            pltpu.VMEM((heads, tile, LANES), jnp.float32),  # running max
+            pltpu.VMEM((heads, tile, LANES), jnp.float32),  # running sum
+            pltpu.VMEM((heads, tile, head_dim), jnp.float32),  # running output
+            pltpu.VMEM((tile, tile), jnp.float32),  # a cut tile's bias
+        ],
+    )(q, k, v)
+
+
+def _score_cotangents(q, do, k, v, bias_ref, lse, di, scale: float):
+    """A head's probabilities and the cotangent of its scores on one
+    tile, both [keys, queries] (`_backward_kernel` says why)."""
+    s_t = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32) * scale
+    if bias_ref is not None:
+        s_t = s_t + bias_ref[...]
+    p_t = jnp.exp(s_t - lse)
+    dp_t = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
+    return p_t, p_t * (dp_t - di)
+
+
+def _causal_dkv_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref, dv_ref, bias_ref,
+    *, scale: float, window: int,
+):
+    """A tile of keys against the tiles of queries that see it, its own
+    first: dk, dv add up where they are written."""
+    ki, delta, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(2)
+
+    @pl.when(delta == 0)
+    def _():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
+
+    def tile_of(bias):
+        k, v = k_ref[...], v_ref[...]
+        dk = jnp.zeros(dk_ref.shape, jnp.float32)
+        dv = jnp.zeros(dv_ref.shape, jnp.float32)
+        for h in range(q_ref.shape[0]):
+            q, do = q_ref[h], do_ref[h]
+            p_t, ds_t = _score_cotangents(q, do, k, v, bias, lse_ref[h], di_ref[h], scale)
+            dv += jnp.dot(p_t.astype(do.dtype), do, preferred_element_type=jnp.float32)
+            dk += jnp.dot(ds_t.astype(q.dtype), q, preferred_element_type=jnp.float32)
+        dk_ref[...] += dk * scale
+        dv_ref[...] += dv
+
+    _by_case(ki + delta < nk, delta, window, bias_ref, True, tile_of)
+
+
+def _causal_dq_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, bias_ref,
+    *, scale: float, window: int,
+):
+    """A tile of queries against the tiles of keys it sees: dq adds up
+    where it is written."""
+    qi, j, reach = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+    delta = reach - 1 - j
+
+    @pl.when(j == 0)
+    def _():
+        dq_ref[...] = jnp.zeros_like(dq_ref)
+
+    def tile_of(bias):
+        k, v = k_ref[...], v_ref[...]
+        for h in range(q_ref.shape[0]):
+            _, ds_t = _score_cotangents(
+                q_ref[h], do_ref[h], k, v, bias, lse_ref[h], di_ref[h], scale
+            )
+            dq_ref[h] += jnp.dot(
+                ds_t.T.astype(k.dtype), k, preferred_element_type=jnp.float32
+            )
+
+    _by_case(delta <= qi, delta, window, bias_ref, True, tile_of)
+
+    @pl.when(j == reach - 1)
+    def _():
+        dq_ref[...] *= scale
+
+
+def _cotangent_call(kernel, name, q, tile, reach, query_tile, key_tile, out):
+    """One of the two backward kernels over the grid [B, G, tiles, reach]
+    whose step (i, j) holds the tiles `query_tile(i, j)` of q, do, the
+    logsumexp and di and `key_tile(i, j)` of k and v; each of `out` is
+    a result cut as "rows" (q is) or as "keys", which adds up over j."""
+    batch, groups, heads, length, head_dim = q.shape
+    rows = pl.BlockSpec(
+        (None, None, heads, tile, head_dim), lambda b, g, i, j: (b, g, 0, query_tile(i, j), 0)
+    )
+    row = pl.BlockSpec(
+        (None, None, heads, 1, tile), lambda b, g, i, j: (b, g, 0, 0, query_tile(i, j))
+    )
+    keys = pl.BlockSpec(
+        (None, None, tile, head_dim), lambda b, g, i, j: (b, g, key_tile(i, j), 0)
+    )
+    cut = {"rows": (rows, q.shape), "keys": (keys, (batch, groups, length, head_dim))}
+    return _call(
+        kernel, name=name, prefetch=0,
+        grid=(batch, groups, length // tile, reach),
+        in_specs=[rows, keys, keys, rows, row, row],
+        out_specs=[cut[kind][0] for kind in out],
+        out_shape=[jax.ShapeDtypeStruct(cut[kind][1], jnp.float32) for kind in out],
+        scratch=[pltpu.VMEM((tile, tile), jnp.float32)],  # a cut tile's bias
+    )
+
+
+@_causal_once
+def _causal_dkv(q, k, v, do, lse, di, scale, tile, window):
+    """dk, dv [B, G, T, d] float32: a tile of keys against the query
+    tiles from its own on (past the last one, the last again, not run)."""
+    tiles, reach = q.shape[3] // tile, _reach(q.shape[3], tile, window)
+    return _cotangent_call(
+        functools.partial(_causal_dkv_kernel, scale=scale, window=window),
+        "causal_core_dkv", q, tile, reach,
+        lambda ki, delta: jnp.minimum(ki + delta, tiles - 1), lambda ki, delta: ki,
+        ("keys", "keys"),
+    )(q, k, v, do, lse, di)
+
+
+@_causal_once
+def _causal_dq(q, k, v, do, lse, di, scale, tile, window):
+    """dq [B, G, R, T, d] float32: a tile of queries against the key
+    tiles up to its own (before key 0, tile 0, not run)."""
+    reach = _reach(q.shape[3], tile, window)
+    (dq,) = _cotangent_call(
+        functools.partial(_causal_dq_kernel, scale=scale, window=window),
+        "causal_core_dq", q, tile, reach,
+        lambda qi, j: qi, lambda qi, j: jnp.maximum(qi - (reach - 1) + j, 0),
+        ("rows",),
+    )(q, k, v, do, lse, di)
+    return dq
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _causal(q, k, v, scale, tile, window, keep):
+    return _causal_fwd(q, k, v, scale, tile, window, keep)[0]
+
+
+def _causal_fwd(q, k, v, scale, tile, window, keep):
+    q, k, v = (a.astype(jnp.bfloat16) for a in (q, k, v))
+    o, lse = (keep(a) for a in _causal_forward(q, k, v, scale, tile, window))
+    return o, (q, k, v, o, lse)
+
+
+def _causal_bwd(scale, tile, window, keep, residuals, do):
+    """Two kernels, so that what adds up over a grid's last axis is one
+    tile: dk, dv over the queries that see a tile of keys, dq over the
+    keys a tile of queries sees. Each makes the probabilities again from
+    the logsumexp: 7 products a tile, where `_backward_kernel` makes 5
+    and holds a head's whole dq (64 MiB at 16,384 rows of 8 heads)."""
+    q, k, v, o, lse = residuals
+    di = jnp.sum(o * do, axis=-1)[..., None, :]
+    args = (q, k, v, do.astype(jnp.bfloat16), lse, di)
+    dk, dv = _causal_dkv(*args, scale, tile, window)
+    return _causal_dq(*args, scale, tile, window), dk, dv
+
+
+_causal.defvjp(_causal_fwd, _causal_bwd)
+
+
+def causal_attention(
+    q: Array, k: Array, v: Array, scale: float, tile: int,
+    window: int | None = None, keep=lambda a: a,
+) -> Array:
+    """softmax(`scale` q k^T over the keys s with `t - window < s <= t`)
+    v for a whole sequence, in one forward and two backward kernels whose
+    grids are the tiles; differentiable in q, k, v.
+
+    q [B, G, R, T, d], k, v [B, G, T, d] float32, T whole `tile`s, `tile`
+    and d whole 128-lane tiles (`seq_ops.causal_tile`); no window, or one
+    that holds the sequence, is every earlier key. Returns float32
+    [B, G, R, T, d].
+
+    Of the forward the backward reads the output and the logsumexp
+    [B, G, R, 1, T]; both pass through `keep` first. A caller rematerialised
+    under a policy that saves what `keep` names has them when its
+    backward starts, and its second forward runs no kernel.
+    """
+    length = q.shape[3]
+    window = length if window is None else min(window, length)
+    return _causal(q, k, v, float(scale), tile, window, keep)

@@ -7,9 +7,10 @@ a mask, softmax attention under that mask, the heads' probabilities
 averaged, the indexer's KL term), and the grouped matmul over rows sorted
 by expert.
 
-Plain XLA over static shapes, but for the attention under a mask where
-its block is whole tiles of the chip: that is two Pallas kernels
-(ops/masked_flash.py, imported when such a block is first traced).
+Plain XLA over static shapes, but for the two softmax attentions where
+their shapes are whole tiles of the chip: the one under a mask and the
+causal one are Pallas kernels (ops/masked_flash.py, imported when such a
+shape is first traced).
 Matmuls run at the backend's default precision (bf16 inputs, float32
 accumulation on the TPU) unless said. Inputs and results are float32; so
 are the delta rule's state and gates and the attention's softmax.
@@ -168,31 +169,72 @@ def chunk_gated_delta_rule(
     return out[:, :, :length]
 
 
+def causal_tile(q: Array, block: int) -> int:
+    """Which of its two forms `blockwise_causal_attention` takes for
+    queries q [B, G, R, T, d] cut by `block`: the tile of the kernels
+    (ops/masked_flash.py) — the largest of 512, 256, 128 that divides T
+    and `block` and at which a tile of a key/value head's R query heads,
+    [R, tile, d] float32, is at most 4 MiB (the cells': 512, at 2 and
+    4 MiB) — where T, `block` and d are whole 128-wide tiles of the chip;
+    0, the dense blocks, everywhere else: a head of 16 or 64, blocks of
+    16, a length of 576. The shapes decide; nothing a caller sets."""
+    heads, length, head_dim = q.shape[2:]
+    if head_dim % 128:
+        return 0
+    block = min(block, length)
+    return next(
+        (
+            tile for tile in (512, 256, 128)
+            if length % tile == 0 and block % tile == 0
+            and heads * tile * head_dim * 4 <= 4 * 2**20
+        ),
+        0,
+    )
+
+
 def blockwise_causal_attention(
     q: Array, k: Array, v: Array, scale: float, block: int = 512,
-    window: int | None = None,
+    window: int | None = None, keep=lambda a: a,
 ) -> Array:
-    """Causal softmax attention, grouped queries, one block of queries at
-    a time: a block's scores against the keys up to its end are the
-    largest tensor there is ([B, G, R, block, end] float32), never the
-    whole [T, T] square, and the upper half beyond a block's own diagonal
-    square is not computed at all. Each block is rematerialised before
-    its own backward, so no block's scores outlive it and a block's
-    residuals are its inputs: a caller that is itself rematerialised
-    and keeps the result (`layers/sequence.py:_keep_core`) never runs
-    the blocks in its second forward.
+    """Causal softmax attention, grouped queries, over every earlier key
+    or, with a `window` shorter than the sequence, over the keys s with
+    `t - window < s <= t` (`window` of them, the query's own among them).
+    A window that holds the whole sequence is no window: the same
+    program, to the letter. In neither of its two forms (`causal_tile`)
+    does the whole [T, T] square of scores exist, and what lies beyond
+    the causal line or before the window is not computed.
 
-    With a `window` shorter than the sequence, query t sees the keys s
-    with `t - window < s <= t` (`window` of them, itself among them), and
-    a block is scored against the keys from `window - 1` before its first
-    row — rounded down to a whole 128-key tile, so that the stretch
-    starts where a tile does — to its own end, not from key 0. The first
-    blocks' stretches begin at key 0 and grow, one program each as
-    without a window; from the block whose stretch no longer reaches key
-    0 on, every whole block sees a stretch of one length and the blocks
-    are one program run in a loop (`jax.lax.map`); a last block that is
-    not whole is a program of its own. A window that holds the whole
-    sequence is no window: the same program, to the letter.
+    By tiles, where the shapes are whole tiles of the chip: one forward
+    and two backward Pallas kernels a call, over the whole sequence
+    (`masked_flash.causal_attention`). The mask is arithmetic on a
+    tile's place: a tile of queries is given the tiles of keys it sees
+    and no other (5 under a window of 2,048 at tiles of 512), the tiles
+    wholly inside are run bare, the two the causal line and the window's
+    far edge cut get an additive tile made from iotas. Scores and
+    probabilities exist one [tile, tile] a head at a time, on the chip;
+    q, k, v, the probabilities and the cotangents enter the MXU as
+    bfloat16, everything else is float32. Of the forward the backward
+    reads the output and the logsumexp [B, G, R, T], and both pass
+    through `keep`: a caller that is rematerialised and saves what `keep`
+    names (`layers/sequence.py:_keep_core`) runs the forward kernel once
+    a step and each backward kernel once.
+
+    By dense blocks, everywhere else (`keep` is applied to the result):
+    one block of `block` queries at a time, a block's scores against the
+    keys up to its end the largest tensor there is
+    ([B, G, R, block, end] float32).
+    Each block is rematerialised before its own backward, so no block's
+    scores outlive it and a block's residuals are its inputs: a caller
+    that is itself rematerialised and keeps the result never runs the
+    blocks in its second forward, and a block's forward runs twice a
+    step. Under a window a block is scored against the keys from
+    `window - 1` before its first row — rounded down to a whole 128-key
+    tile, so that the stretch starts where a tile does — to its own end,
+    not from key 0. The first blocks' stretches begin at key 0 and grow,
+    one program each as without a window; from the block whose stretch
+    no longer reaches key 0 on, every whole block sees a stretch of one
+    length and the blocks are one program run in a loop (`jax.lax.map`);
+    a last block that is not whole is a program of its own.
 
     q [B, G, R, T, d] (G key/value heads, R query heads to each),
     k, v [B, G, T, d]. Returns [B, G, R, T, d].
@@ -201,6 +243,10 @@ def blockwise_causal_attention(
     block = min(block, length)
     if window is not None and window >= length:
         window = None
+    if by_tiles := causal_tile(q, block):
+        from euler_tpu.ops import masked_flash
+
+        return masked_flash.causal_attention(q, k, v, scale, by_tiles, window, keep)
 
     def one(q_b, k_b, v_b, first, key0):
         """Rows `first ..` against the keys `key0 ..`."""
@@ -252,7 +298,7 @@ def blockwise_causal_attention(
         outs.append(o_r.reshape(o_r.shape[:3] + (len(run) * block, o_r.shape[-1])))
     # a last block that is not whole
     outs += [alone(first) for first in firsts if first >= back and first not in run]
-    return jnp.concatenate(outs, axis=3)
+    return keep(jnp.concatenate(outs, axis=3))
 
 
 def indexer_scores(q: Array, k: Array, w: Array, first: int) -> Array:

@@ -1,8 +1,9 @@
-"""The kernels of indexed sparse attention (`ops/masked_flash.py`)
-compiled by Mosaic for a described TPU v5e at the `keye` cell's own
-widths — nothing runs, no chip is needed: what the interpreter cannot
-show (a tile Mosaic refuses, more fast memory than a kernel may use),
-and the names the device trace will carry.
+"""The kernels of `ops/masked_flash.py` — indexed sparse attention's, and
+the causal and sliding-window ones of `GatedAttention` — compiled by
+Mosaic for a described TPU v5e at the `keye`, `trinity` and `qwen3`
+cells' own widths — nothing runs, no chip is needed: what the
+interpreter cannot show (a tile Mosaic refuses, more fast memory than a
+kernel may use), and the names the device trace will carry.
 
 The topology is described inside a fixture, never at import, and every
 such compile of the repo lives in this one file
@@ -80,3 +81,41 @@ def test_a_block_of_the_cell_compiles_and_its_kernels_bear_their_scopes(one_chip
         assert scopes == ["dsa.aux" if "dsa_aux" in name else "dsa.core"], name
         assert ("transpose(" in name) == ("jvp(euler" not in name), name
     assert not re.findall(rf"f32\[1,4,8,512,{keys}\]", text)
+
+
+@pytest.mark.parametrize(
+    "batch,groups,length,head_dim,window",
+    # `trinity`'s window layers and its full layer; `qwen3`'s attention layer
+    [(1, 4, 16384, 128, 2048), (1, 4, 16384, 128, None), (2, 2, 8192, 256, None)],
+)
+def test_a_layers_causal_core_compiles_and_its_kernels_bear_their_scope(
+    one_chip, quiet_cache, batch, groups, length, head_dim, window
+):
+    """A layer's core over the whole sequence, forward and backward, is
+    three Mosaic calls within the kernels' limit of fast memory, each
+    named by `euler.swa.core` (`euler.attn.core` without a window) alone,
+    the two backward ones under `transpose(`: `swa_ms`, `attn_ms` and the
+    two roofline readers sum device time by exactly that. No float32
+    tensor of a block's scores is left in the program."""
+    from euler_tpu.ops import seq_ops
+    from euler_tpu.utils import trace
+
+    shape = lambda s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)  # noqa: E731
+    q, kv = shape((batch, groups, 8, length, head_dim)), shape((batch, groups, length, head_dim))
+    assert seq_ops.causal_tile(q, 512) == 512
+    scope = "attn.core" if window is None else "swa.core"
+
+    def loss(q, k, v):
+        with trace.scope(scope):
+            o = seq_ops.blockwise_causal_attention(q, k, v, head_dim**-0.5, 512, window)
+        return jnp.sum(jnp.sin(o))
+
+    step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+    text = step.lower(q, kv, kv).compile().as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
+    kernels = sorted(name.split("/")[-2] for name in calls)
+    assert kernels == ["causal_core_dkv", "causal_core_dq", "causal_core_forward"]
+    for name in calls:
+        assert re.findall(r"euler\.([a-z_.]+)", name) == [scope], name
+        assert ("transpose(" in name) == ("causal_core_forward" not in name), name
+    assert not re.findall(rf"f32\[{batch},{groups},8,512,\d+\]", text)

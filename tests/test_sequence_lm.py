@@ -505,6 +505,7 @@ def test_first_call_span_counts_the_one_core_kept(bench, config):
         s.args for s in trace.spans() if s.name == "step.first_call" and s.start_ns >= since
     ]
     assert args["program"] == "train_step" and args["mixer_core_kept"] == 1
+    assert (args["attn_core_dense"], args["attn_core_kernel"]) == (1, 0)  # a head of 16
     assert (args["dsa_layers"], args["draw_elements"]) == (0, 1)
 
 

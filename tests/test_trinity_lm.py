@@ -198,6 +198,8 @@ def test_first_call_span_carries_the_layers_forms(config, three_steps):
     assert (args["swa_layers"], args["swa_window"], args["attn_full_layers"]) == (2, 2 * 24, 1)
     assert (args["dense_layers"], args["router_sigmoid"]) == (1, 2)
     assert args["mixer_core_kept"] == 3  # every layer's mixer is a softmax attention
+    # heads of 16 in blocks of 16 at the rehearsal's size: no whole tile
+    assert (args["attn_core_dense"], args["attn_core_kernel"]) == (3, 0)
     assert (args["dsa_layers"], args["agg_grid"], args["draw_elements"]) == (0, 0, 1)
 
 

@@ -3,7 +3,12 @@ dense masked softmax, values and gradients: windows that are whole
 blocks and not, a window that holds the whole sequence (the program
 without a window, bit for bit), a last block that is not whole; keys
 outside every window of the queries that got a cotangent get none; the
-window layers' `euler.swa.*` scopes are named and none nests."""
+window layers' `euler.swa.*` scopes are named and none nests. And the
+same function where the shapes are whole tiles of the chip, as Pallas
+kernels through the interpreter: against a plain oracle, the exact zeros,
+which form which shapes take, a layer by tiles against the layer by dense
+blocks under the decoder's rematerialisation, and what the kernel form
+never makes."""
 
 import re
 
@@ -174,3 +179,165 @@ def test_a_layer_without_rotary_knows_no_position():
         a, b = layer.apply(params, x)[0], layer.apply(params, swapped)[0]
         close = np.allclose(a[:, 12:], b[:, 12:], rtol=1e-4, atol=1e-6)
         assert close == same
+
+
+# -- by tiles: the causal kernels (ops/masked_flash.py), interpreted here --------
+
+
+def _whole_tile_inputs(length, head_dim, per_group, groups=1, seed=0):
+    """q, k, v in whole bf16 numbers, so that the scores of the kernels
+    (bf16 operands) and of the oracle (float32) agree to float32
+    rounding, and a weight for the output."""
+    ks = jax.random.split(jax.random.PRNGKey(seed + length + head_dim + per_group), 4)
+    whole = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
+    q = whole(jax.random.normal(ks[0], (1, groups, per_group, length, head_dim)))
+    k = whole(jax.random.normal(ks[1], (1, groups, length, head_dim)))
+    v = whole(jax.random.normal(ks[2], (1, groups, length, head_dim)))
+    return q, k, v, jax.random.normal(ks[3], q.shape)
+
+
+@pytest.mark.parametrize("per_group", [1, 8])
+@pytest.mark.parametrize("head_dim", [128, 256])
+@pytest.mark.parametrize(
+    "window",
+    # causal; a window of whole tiles; one cut inside a tile; one under a
+    # tile; one that holds the sequence
+    [None, 256, 200, 100, 512],
+)
+def test_causal_kernels_match_a_plain_oracle(window, head_dim, per_group):
+    """Output and the cotangents of q, k, v, four tiles of 128 so that
+    every kind of tile occurs (wholly seen, cut by the causal line, cut
+    by the window's far edge, before key 0). The probabilities and the
+    score cotangents enter the MXU as bf16 in the kernels and as float32
+    in the oracle: that is the tolerance."""
+    q, k, v, weight = _whole_tile_inputs(512, head_dim, per_group)
+    scale = head_dim**-0.5
+    assert seq_ops.causal_tile(q, 128) == 128
+    (_, got), g_got = _value_and_grads(
+        lambda q, k, v: seq_ops.blockwise_causal_attention(q, k, v, scale, 128, window), weight
+    )(q, k, v)
+    (_, want), g_want = _value_and_grads(
+        lambda q, k, v: _dense(q, k, v, scale, window or 512), weight
+    )(q, k, v)
+    assert got.shape == q.shape
+    for name, a, b in zip("oqkv", (got, *g_got), (want, *g_want)):
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-2 * float(jnp.max(jnp.abs(b))), name
+
+
+def test_by_tiles_keys_outside_every_window_get_a_zero_cotangent():
+    """As by dense blocks: not a small number but none, through tiles
+    that are run (a cut tile's dropped pairs) and tiles that are not."""
+    q, k, v, _ = _whole_tile_inputs(1024, 128, 2)
+    window, since = 200, 1024 - 20
+    assert seq_ops.causal_tile(q, 128) == 128
+
+    def total(q, k, v):
+        out = seq_ops.blockwise_causal_attention(q, k, v, 0.1, 128, window)
+        return jnp.sum(jnp.sin(out[:, :, :, since:]))
+
+    dq, dk, dv = jax.jit(jax.grad(total, (0, 1, 2)))(q, k, v)
+    first_seen = since - window + 1
+    for grad in (dk, dv):
+        np.testing.assert_array_equal(grad[:, :, :first_seen], 0.0)
+        assert float(jnp.min(jnp.max(jnp.abs(grad[:, :, first_seen:]), axis=-1))) > 0
+    np.testing.assert_array_equal(dq[:, :, :, :since], 0.0)
+
+
+def _tiled_layer(length=512, head_dim=128, block=128, window=200):
+    from euler_tpu.layers.sequence import GatedAttention
+
+    layer = GatedAttention(
+        num_heads=4, num_kv_heads=2, head_dim=head_dim, rope_theta=1e4,
+        rotary_dim=head_dim // 2, block=block, window=window,
+    )
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, length, 32))
+    params = layer.init(jax.random.PRNGKey(1), x)
+    params = jax.tree_util.tree_map(
+        lambda p: p + 0.1 * jax.random.normal(jax.random.PRNGKey(2), p.shape), params
+    )
+    return layer, params, x
+
+
+@pytest.mark.parametrize(
+    "length,head_dim,block,kernel,dense",
+    # whole tiles; a head that is half a tile; blocks of 16; a length
+    # that is no whole tile
+    [(512, 128, 128, 1, 0), (512, 64, 128, 0, 1), (64, 128, 16, 0, 1), (576, 128, 128, 0, 1)],
+)
+@pytest.mark.parametrize("window", [None, 200])
+def test_the_form_follows_the_shapes_and_the_counter_says_so(length, head_dim, block, kernel, dense, window):
+    from euler_tpu.utils import trace
+
+    layer, params, x = _tiled_layer(length, head_dim, block, window)
+    before = trace.counts()
+    text = str(jax.make_jaxpr(lambda p, x: layer.apply(p, x))(params, x))
+    after = trace.counts()
+    counted = {
+        name: after.get(name, 0) - before.get(name, 0)
+        for name in ("attn_core_kernel", "attn_core_dense")
+    }
+    assert counted == {"attn_core_kernel": kernel, "attn_core_dense": dense}
+    assert ("pallas_call" in text) == bool(kernel)
+
+
+def _through_the_decoders_remat(layer, policy):
+    import flax.linen as nn
+
+    kept = nn.remat(type(layer), policy=policy)(
+        **{f: getattr(layer, f) for f in layer.__dataclass_fields__ if f not in ("parent", "name")}
+    )
+
+    def scalar(params, x):
+        y, _ = kept.apply(params, x)
+        return jnp.sum(jnp.sin(y)), y
+
+    return jax.value_and_grad(scalar, argnums=(0, 1), has_aux=True)
+
+
+@pytest.mark.parametrize("window", [None, 200])
+def test_the_layer_by_tiles_is_the_layer_by_dense_blocks(monkeypatch, window):
+    """Loss and every gradient through `nn.remat(policy=_KEEP_CORE)`, as
+    a decoder layer runs its mixer, at the tolerance of bf16 operands
+    (the dense form's products are whole float32 here on the CPU); and
+    the forward kernel is traced once where a layer rematerialised whole
+    traces it twice."""
+    from euler_tpu.models.sequence_lm import _KEEP_CORE
+
+    layer, params, x = _tiled_layer(window=window)
+    step = _through_the_decoders_remat(layer, _KEEP_CORE)
+    forwards = lambda step: str(jax.make_jaxpr(step)(params, x)).count("name=causal_core_forward")  # noqa: E731
+    once, twice = forwards(step), forwards(_through_the_decoders_remat(layer, None))
+    assert once > 0 and twice == 2 * once
+    (loss, y), grads = jax.jit(step)(params, x)
+    monkeypatch.setattr(seq_ops, "causal_tile", lambda q, block: 0)
+    step = _through_the_decoders_remat(layer, _KEEP_CORE)  # traced anew: a trace is kept by function
+    assert forwards(step) == 0
+    (loss_want, y_want), grads_want = jax.jit(step)(params, x)
+    # a sum of sines cancels: against the sum of their sizes
+    assert abs(float(loss - loss_want)) < 2e-3 * float(jnp.sum(jnp.abs(jnp.sin(y_want))))
+    np.testing.assert_allclose(y, y_want, atol=2e-2 * float(jnp.max(jnp.abs(y_want))))
+    for (path, got), want in zip(
+        jax.tree_util.tree_leaves_with_path(grads), jax.tree_util.tree_leaves(grads_want)
+    ):
+        assert float(jnp.max(jnp.abs(want))) > 0, path
+        assert float(jnp.max(jnp.abs(got - want))) < 2e-2 * float(jnp.max(jnp.abs(want))), path
+
+
+@pytest.mark.parametrize("window", [None, 200])
+def test_by_tiles_no_blocks_scores_are_made(monkeypatch, window):
+    """The kernel form makes no float32 value of five axes with a
+    block's 128 rows, forward or backward (a tile's scores are
+    [128, 128], inside a kernel); the dense form, at the same shapes,
+    makes a block's [B, G, R, 128, keys]."""
+    from test_indexed_sparse_attention import _float32_shapes
+
+    layer, params, x = _tiled_layer(window=window)
+
+    def scores_made():
+        grad = jax.grad(lambda p, x: jnp.sum(layer.apply(p, x)[0]))
+        made = _float32_shapes(jax.make_jaxpr(grad)(params, x).jaxpr, set())
+        return sorted(s for s in made if len(s) == 5 and s[-2] == 128)
+
+    assert scores_made() == []
+    monkeypatch.setattr(seq_ops, "causal_tile", lambda q, block: 0)
+    assert (1, 2, 2, 128, 128) in scores_made()
