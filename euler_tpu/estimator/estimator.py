@@ -374,7 +374,7 @@ _TRACED_FORMS = (
     "attn_core_dense", "attn_core_kernel",
     "mixer_core_kept",
     "swa_layers", "swa_window", "attn_full_layers", "dense_layers",
-    "router_sigmoid",
+    "router_sigmoid", "router_on_input", "experts_relu", "attn_ungated",
 )
 
 
@@ -413,7 +413,11 @@ def _first_call(program: str, tables: dict):
     the `GatedAttention` layers with a window, `swa_window` their windows
     summed, `attn_full_layers` those without one, `dense_layers` the
     decoder layers whose feed-forward is a `DenseMLP`, `router_sigmoid`
-    the expert layers that route by sigmoid scores (`layers/moe.py`)."""
+    the expert layers that route by sigmoid scores, `experts_relu` those
+    whose experts' gate is ReLU (`layers/moe.py`), `router_on_input` the
+    expert layers whose router reads the decoder layer's input, ahead of
+    its mixer (`models/sequence_lm.py:DecoderLayer`), `attn_ungated` the
+    `GatedAttention` layers that have no output gate."""
     table_arg_bytes = sum(
         leaf.nbytes for leaf in jax.tree_util.tree_leaves(tables)
     )

@@ -46,8 +46,14 @@ from euler_tpu.utils import trace
 _MATRIX = nn.initializers.normal(stddev=0.02)
 
 
-def _swiglu(x, w_gate, w_up, w_down, matmul):
-    return matmul(jax.nn.silu(matmul(x, w_gate)) * matmul(x, w_up), w_down)
+# an expert's gate activation, by the model's name for it
+_GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _glu(activation, x, w_gate, w_up, w_down, matmul):
+    """`W_down (act(W_gate x) * W_up x)`: SwiGLU under "silu", ReGLU
+    under "relu"."""
+    return matmul(_GATES[activation](matmul(x, w_gate)) * matmul(x, w_up), w_down)
 
 
 def tile_rows(tokens: int, top_k: int, count: int, num_experts: int) -> int:
@@ -65,7 +71,7 @@ def tile_rows(tokens: int, top_k: int, count: int, num_experts: int) -> int:
     )
 
 
-def _tile(top_k, step, start, order, ends, x, weight, w_gate, w_up, w_down):
+def _tile(top_k, step, activation, start, order, ends, x, weight, w_gate, w_up, w_down):
     """What the held experts add to every token from the sorted
     assignments `start .. start + step`: [N, H]."""
     tokens = x.shape[0]
@@ -78,8 +84,8 @@ def _tile(top_k, step, start, order, ends, x, weight, w_gate, w_up, w_down):
         # leaves them as zeros, forward and transposed
         sizes = jnp.diff(jnp.clip(ends, start, start + step), prepend=start)
     with trace.scope("moe.experts"):
-        out = _swiglu(
-            rows, w_gate, w_up, w_down,
+        out = _glu(
+            activation, rows, w_gate, w_up, w_down,
             lambda a, w: seq_ops.grouped_matmul(a, w, sizes),
         )
     with trace.scope("moe.combine"):
@@ -91,8 +97,8 @@ def _tiles(step, ends):
     return (ends[-1] + step - 1) // step
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def held_experts(top_k, step, order, ends, x, weight, w_gate, w_up, w_down):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def held_experts(top_k, step, activation, order, ends, x, weight, w_gate, w_up, w_down):
     """sum over the assignments (token n, expert e held here) of
     `weight[n, e] E_e(x[n])`, per token: [N, H].
 
@@ -104,21 +110,23 @@ def held_experts(top_k, step, order, ends, x, weight, w_gate, w_up, w_down):
     through: the backward pass is the same loop over each tile's own vjp."""
     return jax.lax.fori_loop(
         0, _tiles(step, ends),
-        lambda t, y: y + _tile(top_k, step, t * step, order, ends, x, weight, w_gate, w_up, w_down),
+        lambda t, y: y + _tile(
+            top_k, step, activation, t * step, order, ends, x, weight, w_gate, w_up, w_down
+        ),
         jnp.zeros_like(x),
     )
 
 
-def _held_experts_fwd(top_k, step, order, ends, *inputs):
-    return held_experts(top_k, step, order, ends, *inputs), (order, ends, inputs)
+def _held_experts_fwd(top_k, step, activation, order, ends, *inputs):
+    return held_experts(top_k, step, activation, order, ends, *inputs), (order, ends, inputs)
 
 
-def _held_experts_bwd(top_k, step, kept, dy):
+def _held_experts_bwd(top_k, step, activation, kept, dy):
     order, ends, inputs = kept
 
     def tile_grads(t, grads):
         _, pull = jax.vjp(
-            functools.partial(_tile, top_k, step, t * step, order, ends), *inputs
+            functools.partial(_tile, top_k, step, activation, t * step, order, ends), *inputs
         )
         return jax.tree_util.tree_map(jnp.add, grads, pull(dy))
 
@@ -133,14 +141,22 @@ held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 class SparseMoE(nn.Module):
-    """x [N, H] -> (y [N, H], the number of token-expert assignments that
-    landed on held experts).
+    """x [N, H], route_on [N, H] or None -> (y [N, H], the number of
+    token-expert assignments that landed on held experts).
 
-    `p = softmax(x W_r)` over all experts in float32; the `top_k` largest
-    are kept and, with `norm_topk`, divided by their sum;
+    `p = softmax(r W_r)` over all experts in float32, `r` the tensor the
+    router reads: `route_on` where the caller hands one (a model whose
+    router stands ahead of its attention hands the layer's input), else
+    `x`, the experts' own input; the `top_k` largest are kept and, with
+    `norm_topk`, divided by their sum — which is also the softmax over
+    the `top_k` kept logits alone, so a model that states its router so
+    (SmallThinker: `moe_primary_router_apply_softmax`) is this `score`
+    and no third;
     `y = sum_{e in top_k, e held} p_e E_e(x) + sigmoid(x . w_s) E_shared(x)`,
-    `E(x) = W_down (SiLU(W_gate x) * W_up x)`. Every assignment to a held
-    expert is computed, however many there are (`held_experts`).
+    `E(x) = W_down (act(W_gate x) * W_up x)`, `act` the model's
+    `activation`: "silu" (SwiGLU) or "relu" (ReGLU; a layer counts itself
+    `experts_relu`). Every assignment to a held expert is computed,
+    however many there are (`held_experts`).
     `shared_dim` 0 is a layer with no shared expert: no such term and no
     such parameters; `shared_gated` False adds the shared expert as it is,
     with no sigmoid mix and no `w_s`.
@@ -163,9 +179,10 @@ class SparseMoE(nn.Module):
     score: str = "softmax"  # or "sigmoid"
     route_scale: float = 1.0
     shared_gated: bool = True
+    activation: str = "silu"  # or "relu": the experts' gate
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, route_on=None):
         hidden = x.shape[1]
         first, count = self.held
         count = count or self.num_experts
@@ -183,7 +200,7 @@ class SparseMoE(nn.Module):
             # float32 for real: a top-k pick that flips on a bf16-rounded
             # logit would send a token to other experts
             logits = jnp.matmul(
-                x.astype(jnp.float32), w_router,
+                (x if route_on is None else route_on).astype(jnp.float32), w_router,
                 precision=jax.lax.Precision.HIGHEST,
             )
             if self.score == "sigmoid":
@@ -210,7 +227,11 @@ class SparseMoE(nn.Module):
             ends = jnp.cumsum(jnp.bincount(slot, length=count + 1)[:count])
             ends = ends.astype(jnp.int32)
         step = tile_rows(x.shape[0], k, count, self.num_experts)
-        y = held_experts(k, step, order, ends, x, top_p.reshape(-1), w_gate, w_up, w_down)
+        if self.activation == "relu":
+            trace.count("experts_relu")
+        y = held_experts(
+            k, step, self.activation, order, ends, x, top_p.reshape(-1), w_gate, w_up, w_down
+        )
         if self.shared_dim:
             shape = (hidden, self.shared_dim)
             s_gate = self.param("shared_gate", _MATRIX, shape, jnp.float32)
@@ -221,9 +242,9 @@ class SparseMoE(nn.Module):
             with trace.scope("moe.shared"):
                 if self.shared_gated:
                     mix = jax.nn.sigmoid((x @ s_mix).astype(jnp.float32))
-                    y = y + mix * _swiglu(x, s_gate, s_up, s_down, jnp.matmul)
+                    y = y + mix * _glu(self.activation, x, s_gate, s_up, s_down, jnp.matmul)
                 else:
-                    y = y + _swiglu(x, s_gate, s_up, s_down, jnp.matmul)
+                    y = y + _glu(self.activation, x, s_gate, s_up, s_down, jnp.matmul)
         return y, ends[-1]
 
 
@@ -243,4 +264,4 @@ class DenseMLP(nn.Module):
         w_down = self.param("down", _MATRIX, shape[::-1], jnp.float32)
         trace.count("dense_layers")
         with trace.scope("mlp"):
-            return _swiglu(x, w_gate, w_up, w_down, jnp.matmul), jnp.zeros((), jnp.int32)
+            return _glu("silu", x, w_gate, w_up, w_down, jnp.matmul), jnp.zeros((), jnp.int32)

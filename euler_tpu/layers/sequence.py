@@ -182,7 +182,11 @@ class GatedAttention(nn.Module):
     `rotary_dim` of the head (0: no rotary, and the layer knows no
     position at all), and a sigmoid gate on the output, computed from
     the same projection as the query (W_q holds, head by head,
-    [query | gate]). With a `window`, a query sees that many keys, itself
+    [query | gate]). Gate and head norms are the model's choice: with
+    `gated` False W_q is [hidden, heads * d], no sigmoid is applied and
+    the layer counts itself `attn_ungated`; with `head_norms` False
+    there are no `q_norm` / `k_norm` leaves. With a `window`, a query
+    sees that many keys, itself
     among them, and the layer's scopes are `euler.swa.*`, so that a trace
     tells the two kinds of layer apart; without one it sees every earlier
     key, under `euler.attn.*`. The softmax
@@ -203,12 +207,15 @@ class GatedAttention(nn.Module):
     block: int = 512
     eps: float = 1e-6
     window: int | None = None
+    gated: bool = True
+    head_norms: bool = True
 
     @nn.compact
     def __call__(self, x, positions=None):
         batch, length, hidden = x.shape
         nq, nkv, d = self.num_heads, self.num_kv_heads, self.head_dim
-        w_q = self.param("q_proj", _MATRIX, (hidden, nq * d * 2), jnp.float32)
+        q_cols = 2 * d if self.gated else d  # a head's [query | gate], or its query
+        w_q = self.param("q_proj", _MATRIX, (hidden, nq * q_cols), jnp.float32)
         w_k = self.param("k_proj", _MATRIX, (hidden, nkv * d), jnp.float32)
         w_v = self.param("v_proj", _MATRIX, (hidden, nkv * d), jnp.float32)
         w_o = self.param("o_proj", _MATRIX, (nq * d, hidden), jnp.float32)
@@ -219,17 +226,21 @@ class GatedAttention(nn.Module):
         else:
             trace.count("swa_layers")
             trace.count("swa_window", self.window)
+        if not self.gated:
+            trace.count("attn_ungated")
         with trace.scope(f"{kind}.proj"):
-            qg = (x @ w_q).reshape(batch, length, nq, 2 * d)
-            q, gate = qg[..., :d], qg[..., d:]
+            q = (x @ w_q).reshape(batch, length, nq, q_cols)
+            if self.gated:
+                q, gate = q[..., :d], q[..., d:]
             k = (x @ w_k).reshape(batch, length, nkv, d)
             v = (x @ w_v).reshape(batch, length, nkv, d)
 
-            def turn(a):
+            def turn(a, norm):
+                if self.head_norms:
+                    a = RMSNorm(self.eps, name=norm)(a)
                 return rotary(a, self.rope_theta, self.rotary_dim) if self.rotary_dim else a
 
-            q = turn(RMSNorm(self.eps, name="q_norm")(q))
-            k = turn(RMSNorm(self.eps, name="k_norm")(k))
+            q, k = turn(q, "q_norm"), turn(k, "k_norm")
         with trace.scope(f"{kind}.core"):
             q = q.reshape(batch, length, nkv, nq // nkv, d).transpose(0, 2, 3, 1, 4)
             by_tiles = seq_ops.causal_tile(q, self.block)
@@ -240,7 +251,8 @@ class GatedAttention(nn.Module):
             )
         with trace.scope(f"{kind}.out"):
             o = o.transpose(0, 3, 1, 2, 4).reshape(batch, length, nq, d)
-            o = o * jax.nn.sigmoid(gate.astype(jnp.float32))
+            if self.gated:
+                o = o * jax.nn.sigmoid(gate.astype(jnp.float32))
             return o.reshape(batch, length, nq * d) @ w_o, None
 
 

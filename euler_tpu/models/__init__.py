@@ -18,5 +18,10 @@ from euler_tpu.models.kg import (  # noqa: F401
 from euler_tpu.models.layerwise_models import LayerwiseGCN  # noqa: F401
 from euler_tpu.models.rgcn import RGCNSupervised  # noqa: F401
 from euler_tpu.models.autoencoders import DGI, GAE, dgi_batches, gae_batches  # noqa: F401
-from euler_tpu.models.sequence_lm import KeyeVL2LM, Qwen3NextLM, TrinityLM  # noqa: F401
+from euler_tpu.models.sequence_lm import (  # noqa: F401
+    KeyeVL2LM,
+    Qwen3NextLM,
+    SmallThinkerLM,
+    TrinityLM,
+)
 from euler_tpu.models.scalable import ScalableGNN, ScalableTrainer  # noqa: F401

@@ -1,6 +1,6 @@
 """Decoder-only language models as `Estimator` models: one decoder,
 `DecoderLM`, whose layers differ in their mixer and in whether their
-feed-forward is dense, and the three architectures that plan their
+feed-forward is dense, and the four architectures that plan their
 layers on it.
 
 Decoder layer l: `h += Mixer_l(norm(h))`, then `h += FFN(norm(h))`; with
@@ -14,7 +14,10 @@ shared expert; `KeyeVL2LM` (Keye-VL-2.0's language model) plans
 `IndexedSparseAttention` in every layer, no shared expert, and adds the
 mean of the layers' indexer losses to the loss; `TrinityLM` (Arcee's
 Trinity, HF `afmoe`) plans `GatedAttention` by `layer_types`, with a
-window and rotary or with neither, behind a sigmoid-scored router
+window and rotary or with neither, behind a sigmoid-scored router;
+`SmallThinkerLM` (PowerInfer's SmallThinker) plans it by two layouts,
+window and rotary, with no output gate and no head norms, ReLU-gated
+experts and a router that reads the layer's input ahead of the attention
 (layers/sequence.py). Embedding (`nn/encoders.py:Embedding`,
 so `euler.embed` and the table's scatter-add gradient are the ones every
 embedding model here has) times `embed_scale`, the layers, a final norm,
@@ -64,7 +67,11 @@ class DecoderLayer(nn.Module):
     loss or None). `mixer` is called as `(x, positions) -> (y, its own
     loss or None)`; the feed-forward, `mlp` where the layer has one and
     else `moe`, as `x [N, H] -> (y, assignments routed)`. With `sandwich`
-    each sublayer's output is normalised before it is added. Run under
+    each sublayer's output is normalised before it is added. With
+    `route_on_input` the experts' router is handed the stream as it
+    entered the layer, before `input_norm` and the mixer (a router that
+    stands ahead of the attention: nothing of its pick waits for the
+    mixer), and the layer counts itself `router_on_input`. Run under
     `nn.remat` (`DecoderLM._layer`): nothing in here outlives the forward
     but what `_KEEP_CORE` names."""
 
@@ -73,16 +80,24 @@ class DecoderLayer(nn.Module):
     eps: float = 1e-6
     mlp: nn.Module | None = None  # a dense feed-forward in the experts' place
     sandwich: bool = False
+    route_on_input: bool = False  # the experts' router reads the layer's input
 
     @nn.compact
     def __call__(self, h, positions):
+        entered = h
         mixed, aux = self.mixer(RMSNorm(self.eps, name="input_norm")(h), positions)
         if self.sandwich:
             mixed = RMSNorm(self.eps, name="mixer_out_norm")(mixed)
         h = h + mixed
         x = RMSNorm(self.eps, name="post_norm")(h)
-        feed_forward = self.moe if self.mlp is None else self.mlp
-        y, routed = feed_forward(x.reshape(-1, x.shape[-1]))
+        x = x.reshape(-1, x.shape[-1])
+        if self.mlp is not None:
+            y, routed = self.mlp(x)
+        elif self.route_on_input:
+            trace.count("router_on_input")
+            y, routed = self.moe(x, route_on=entered.reshape(x.shape))
+        else:
+            y, routed = self.moe(x)
         y = y.reshape(h.shape)
         if self.sandwich:
             y = RMSNorm(self.eps, name="ffn_out_norm")(y)
@@ -115,6 +130,8 @@ class DecoderLM(nn.Module):
     router_score: str = "softmax"  # or "sigmoid" (layers/moe.py)
     route_scale: float = 1.0
     shared_expert_gated: bool = True
+    expert_activation: str = "silu"  # the experts' gate: or "relu"
+    route_on_input: bool = False  # routers read their layer's input, not the experts'
     experts_here: tuple = (0, 0)  # (first, count); count 0 = all
     # the first layers' feed-forward is dense, of this width
     num_dense_layers: int = 0
@@ -144,11 +161,12 @@ class DecoderLM(nn.Module):
                 score=self.router_score,
                 route_scale=self.route_scale,
                 shared_gated=self.shared_expert_gated,
+                activation=self.expert_activation,
                 parent=None,
             )
         return nn.remat(DecoderLayer, policy=_KEEP_CORE)(
             self.mixer(index), moe, self.rms_norm_eps, mlp, self.sandwich_norms,
-            name=f"layer_{index}",
+            self.route_on_input, name=f"layer_{index}",
         )
 
     @nn.compact
@@ -321,5 +339,48 @@ class TrinityLM(DecoderLM):
             block=self.attention_block,
             eps=self.rms_norm_eps,
             window=self.sliding_window if local else None,
+            parent=None,  # adopted by the layer, as its `mixer`
+        )
+
+
+class SmallThinkerLM(DecoderLM):
+    """PowerInfer's SmallThinker: plain grouped-query attention in every
+    layer — no output gate, no head norms —, a window of
+    `sliding_window_size` keys where `sliding_window_layout[l]` is 1 and
+    every earlier key elsewhere, rotary over the whole head where
+    `rope_layout[l]` is 1 and no position at all elsewhere (the two
+    layouts need not agree here; published, they do: a full layer that
+    knows no position, then three window layers with rotary). Every
+    feed-forward is routed, ReLU-gated experts (ReGLU) with no shared
+    one, and its router reads the layer's input, ahead of `input_norm`
+    and the attention (`route_on_input`): the softmax over the kept
+    logits, which is `router_score` "softmax" with `norm_topk_prob`."""
+
+    num_heads: int = 28
+    num_kv_heads: int = 4
+    head_dim: int = 128
+    rope_theta: float = 1.5e6
+    sliding_window_layout: tuple = (0, 1, 1, 1)
+    rope_layout: tuple = (0, 1, 1, 1)
+    sliding_window_size: int = 4096
+    num_experts: int = 64
+    num_experts_per_tok: int = 6
+    moe_intermediate_size: int = 768
+    shared_expert_intermediate_size: int = 0
+    expert_activation: str = "relu"
+    route_on_input: bool = True
+
+    def mixer(self, index: int):
+        return GatedAttention(
+            num_heads=self.num_heads,
+            num_kv_heads=self.num_kv_heads,
+            head_dim=self.head_dim,
+            rope_theta=self.rope_theta,
+            rotary_dim=self.head_dim if self.rope_layout[index] else 0,
+            block=self.attention_block,
+            eps=self.rms_norm_eps,
+            window=self.sliding_window_size if self.sliding_window_layout[index] else None,
+            gated=False,
+            head_norms=False,
             parent=None,  # adopted by the layer, as its `mixer`
         )

@@ -1,7 +1,7 @@
 """The kernels of `ops/masked_flash.py` — indexed sparse attention's, and
 the causal and sliding-window ones of `GatedAttention` — compiled by
-Mosaic for a described TPU v5e at the `keye`, `trinity` and `qwen3`
-cells' own widths — nothing runs, no chip is needed: what the
+Mosaic for a described TPU v5e at the `keye`, `trinity`, `qwen3` and
+`smallthinker` cells' own widths — nothing runs, no chip is needed: what the
 interpreter cannot show (a tile Mosaic refuses, more fast memory than a
 kernel may use), and the names the device trace will carry.
 
@@ -84,12 +84,17 @@ def test_a_block_of_the_cell_compiles_and_its_kernels_bear_their_scopes(one_chip
 
 
 @pytest.mark.parametrize(
-    "batch,groups,length,head_dim,window",
-    # `trinity`'s window layers and its full layer; `qwen3`'s attention layer
-    [(1, 4, 16384, 128, 2048), (1, 4, 16384, 128, None), (2, 2, 8192, 256, None)],
+    "batch,groups,heads,length,head_dim,window",
+    # `trinity`'s window layers and its full layer; `qwen3`'s attention
+    # layer; `smallthinker`'s window layers (7 query heads a key head, a
+    # tile of queries given 9 tiles of keys) and its full layer
+    [
+        (1, 4, 8, 16384, 128, 2048), (1, 4, 8, 16384, 128, None), (2, 2, 8, 8192, 256, None),
+        (1, 4, 7, 16384, 128, 4096), (1, 4, 7, 16384, 128, None),
+    ],
 )
 def test_a_layers_causal_core_compiles_and_its_kernels_bear_their_scope(
-    one_chip, quiet_cache, batch, groups, length, head_dim, window
+    one_chip, quiet_cache, batch, groups, heads, length, head_dim, window
 ):
     """A layer's core over the whole sequence, forward and backward, is
     three Mosaic calls within the kernels' limit of fast memory, each
@@ -101,7 +106,7 @@ def test_a_layers_causal_core_compiles_and_its_kernels_bear_their_scope(
     from euler_tpu.utils import trace
 
     shape = lambda s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)  # noqa: E731
-    q, kv = shape((batch, groups, 8, length, head_dim)), shape((batch, groups, length, head_dim))
+    q, kv = shape((batch, groups, heads, length, head_dim)), shape((batch, groups, length, head_dim))
     assert seq_ops.causal_tile(q, 512) == 512
     scope = "attn.core" if window is None else "swa.core"
 
@@ -118,4 +123,4 @@ def test_a_layers_causal_core_compiles_and_its_kernels_bear_their_scope(
     for name in calls:
         assert re.findall(r"euler\.([a-z_.]+)", name) == [scope], name
         assert ("transpose(" in name) == ("causal_core_forward" not in name), name
-    assert not re.findall(rf"f32\[{batch},{groups},8,512,\d+\]", text)
+    assert not re.findall(rf"f32\[{batch},{groups},{heads},512,\d+\]", text)

@@ -224,6 +224,32 @@ def test_causal_kernels_match_a_plain_oracle(window, head_dim, per_group):
         assert float(jnp.max(jnp.abs(a - b))) < 1e-2 * float(jnp.max(jnp.abs(b))), name
 
 
+@pytest.mark.parametrize("window", [1024, None])
+def test_causal_kernels_at_seven_query_heads_and_a_reach_of_nine(window):
+    """SmallThinker's group and its window in tiles: 7 query heads a key
+    head (no power of two) and a window of 8 tiles, so that a tile of
+    queries is given 9 tiles of keys, its own among them (`_reach`; the
+    cell's 4,096 over tiles of 512) — here 10 tiles of 128, so that the
+    last query tile's window starts past key 0 —, and the same group
+    over every earlier key."""
+    from euler_tpu.ops import masked_flash
+
+    q, k, v, weight = _whole_tile_inputs(1280, 128, 7)
+    scale = 128**-0.5
+    assert seq_ops.causal_tile(q, 128) == 128
+    assert masked_flash._reach(1280, 128, window or 1280) == (9 if window else 10)
+    assert masked_flash._reach(16384, 512, 4096) == 9
+    (_, got), g_got = _value_and_grads(
+        lambda q, k, v: seq_ops.blockwise_causal_attention(q, k, v, scale, 128, window), weight
+    )(q, k, v)
+    (_, want), g_want = _value_and_grads(
+        lambda q, k, v: _dense(q, k, v, scale, window or 1280), weight
+    )(q, k, v)
+    assert got.shape == q.shape == (1, 1, 7, 1280, 128)
+    for name, a, b in zip("oqkv", (got, *g_got), (want, *g_want)):
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-2 * float(jnp.max(jnp.abs(b))), name
+
+
 def test_by_tiles_keys_outside_every_window_get_a_zero_cotangent():
     """As by dense blocks: not a small number but none, through tiles
     that are run (a cut tile's dropped pairs) and tiles that are not."""
