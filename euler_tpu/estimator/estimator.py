@@ -370,7 +370,7 @@ _NO_SPAN = contextlib.nullcontext()
 # program's `step.first_call` span carries how often each was taken.
 _TRACED_FORMS = (
     "agg_grid", "agg_scatter", "draw_rows", "draw_elements",
-    "dsa_layers", "dsa_topk", "dsa_core_masked", "dsa_core_kernel",
+    "dsa_layers", "dsa_topk", "dsa_core_masked", "dsa_core_kernel", "dsa_index_vjp",
     "attn_core_dense", "attn_core_kernel",
     "mixer_core_kept",
     "swa_layers", "swa_window", "attn_full_layers", "dense_layers",
@@ -400,7 +400,10 @@ def _first_call(program: str, tables: dict):
     kernels and `dsa_core_masked` how many as dense blocks under the
     pick's mask — the shapes of a layer's runs decide, and a layer whose
     runs differ counts under both
-    (`layers/sequence.py:IndexedSparseAttention`), `mixer_core_kept` the
+    (`layers/sequence.py:IndexedSparseAttention`), `dsa_index_vjp` how
+    many of them score their keys through `seq_ops.indexer_scores`' own
+    backward (head by head, no [B, J, rows, keys] cotangent: all of
+    them), `mixer_core_kept` the
     mixers whose layer keeps their attention core's output through its
     rematerialisation, so that the core's loop of query blocks runs
     twice a step and not three times (`layers/sequence.py:_keep_core`:

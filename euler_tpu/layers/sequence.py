@@ -303,7 +303,11 @@ class IndexedSparseAttention(nn.Module):
     averaged, KL. The blocks of a run (`query_runs`) see the same stretch
     of keys and are one loop over one program; a block is rematerialised
     before its own backward, so its [block, keys] index scores do not
-    outlive it, and its residuals are its inputs. Where a run's blocks
+    outlive it, and its residuals are its inputs. That backward remakes
+    the indexer's [B, index_heads, block, keys] products a second time,
+    a head at a time (`seq_ops.indexer_scores` has a backward of its
+    own, tallied `dsa_index_vjp`): forward and in the block's second
+    forward they exist whole, in the backward never. Where a run's blocks
     are whole tiles of the chip (rows, keys and head multiples of 128:
     `seq_ops.attends_by_tiles`) the attention and the average are Pallas
     kernels and a block's [B, G, R, block, keys] scores and probabilities
@@ -343,6 +347,7 @@ class IndexedSparseAttention(nn.Module):
         i_bias = self.param("index_k_norm_b", nn.initializers.zeros, (di,), jnp.float32)
         trace.count("dsa_layers")
         trace.count("dsa_topk", self.topk)
+        trace.count("dsa_index_vjp")  # `seq_ops.indexer_scores` brings its own backward
         trace.count("mixer_core_kept")
         with trace.scope("dsa.proj"):
             q = (x @ w_q).reshape(batch, length, nq, d)

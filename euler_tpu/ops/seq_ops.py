@@ -301,19 +301,64 @@ def blockwise_causal_attention(
     return keep(jnp.concatenate(outs, axis=3))
 
 
+def _seen(first, rows: int, keys: int) -> Array:
+    """[rows, keys] bool: key s is no later than row r's position
+    `first + r`."""
+    return jnp.arange(keys)[None, :] <= first + jnp.arange(rows)[:, None]
+
+
+@jax.custom_vjp
 def indexer_scores(q: Array, k: Array, w: Array, first: int) -> Array:
     """A block of queries' index scores against the keys up to its end:
     `I[t, s] = sum_j w[t, j] relu(q[t, j] . k[s])`, -inf where s > t.
 
     q [B, R, J, d] (J indexer heads), k [B, S, d] (one key for all of
     them), w [B, R, J]; row r is position `first + r`. Returns float32
-    [B, R, S]; the [B, J, R, S] products are the largest tensor alive.
+    [B, R, S]. Forward, the [B, J, R, S] products are the largest tensor
+    alive. The backward is written by hand (`_indexer_cotangents`): it
+    keeps q, k and w and makes the products again a head at a time, so
+    no value of it holds all the heads' products.
     """
     dots = jnp.einsum("brjd,bsd->bjrs", q, k).astype(jnp.float32)
     weight = jnp.moveaxis(w.astype(jnp.float32), 2, 1)[..., None]
     scores = jnp.sum(weight * jax.nn.relu(dots), axis=1)
-    rows = first + jnp.arange(q.shape[1])[:, None]
-    return jnp.where(jnp.arange(k.shape[1])[None, :] <= rows, scores, -jnp.inf)
+    return jnp.where(_seen(first, q.shape[1], k.shape[1]), scores, -jnp.inf)
+
+
+def _indexer_cotangents(kept, d_scores):
+    """The cotangents of `indexer_scores`' q, k and w (`first` has none)
+    from its result's, dI [B, R, S]: with `g_j = w_j (q_j . k > 0) dI`
+    under the causal line, `dq_j = g_j k`, `dk = sum_j g_j^T q_j`, `dw_j
+    = sum_s relu(q_j . k) dI`. A loop over the heads, each making its own
+    products [B, R, S] again, keys along the lanes: what `jax.grad`
+    makes of the forward's three lines is the whole [B, J, R, S]
+    cotangent, laid out anew for each of the three (on the TPU a fifth
+    of the rate these products reach here)."""
+    q, k, w, first = kept
+    d_scores = jnp.where(_seen(first, q.shape[1], k.shape[1]), d_scores, 0.0)
+
+    def head(dk, q_w):
+        q_j, w_j = q_w  # [B, R, d], [B, R]
+        dots = jnp.einsum("brd,bsd->brs", q_j, k).astype(jnp.float32)
+        g = jnp.where(dots > 0, w_j[..., None] * d_scores, 0.0)
+        dk = dk + jnp.einsum("brs,brd->bsd", g, q_j)
+        dw_j = jnp.sum(jax.nn.relu(dots) * d_scores, axis=-1)
+        return dk, (jnp.einsum("brs,bsd->brd", g, k), dw_j)
+
+    dk, (dq, dw) = jax.lax.scan(
+        head, jnp.zeros(k.shape, jnp.float32),
+        (jnp.moveaxis(q, 2, 0), jnp.moveaxis(w.astype(jnp.float32), 2, 0)),
+    )
+    return (
+        jnp.moveaxis(dq, 0, 2).astype(q.dtype), dk.astype(k.dtype),
+        jnp.moveaxis(dw, 0, 2).astype(w.dtype), None,
+    )
+
+
+indexer_scores.defvjp(
+    lambda q, k, w, first: (indexer_scores(q, k, w, first), (q, k, w, first)),
+    _indexer_cotangents,
+)
 
 
 def _ordered_bits(x: Array) -> Array:

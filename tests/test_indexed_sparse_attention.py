@@ -617,6 +617,7 @@ def test_first_call_span_carries_the_mixers_forms(config, three_steps):
     layers, topk = config["num_hidden_layers"], config["sa_config"]["topk"]
     assert (args["dsa_layers"], args["dsa_topk"], args["dsa_core_masked"]) == (layers, layers * topk, layers)
     assert args["dsa_core_kernel"] == 0  # blocks of 8 at the rehearsal's size: no whole tile
+    assert args["dsa_index_vjp"] == layers  # every layer's indexer brings its own backward
     assert args["mixer_core_kept"] == layers  # every layer's mixer is a softmax attention
     assert (args["attn_core_dense"], args["attn_core_kernel"]) == (0, 0)  # no `GatedAttention` here
     assert (args["agg_grid"], args["draw_rows"], args["draw_elements"]) == (0, 0, 1)

@@ -200,7 +200,8 @@ def test_first_call_span_carries_the_layers_forms(config, three_steps):
     assert args["mixer_core_kept"] == 3  # every layer's mixer is a softmax attention
     # heads of 16 in blocks of 16 at the rehearsal's size: no whole tile
     assert (args["attn_core_dense"], args["attn_core_kernel"]) == (3, 0)
-    assert (args["dsa_layers"], args["agg_grid"], args["draw_elements"]) == (0, 0, 1)
+    assert (args["dsa_layers"], args["dsa_index_vjp"]) == (0, 0)  # no indexer here
+    assert (args["agg_grid"], args["draw_elements"]) == (0, 1)
 
 
 def test_the_model_is_its_embedding_scale_and_its_sandwich_norms(bench, config):
