@@ -375,6 +375,7 @@ _TRACED_FORMS = (
     "mixer_core_kept",
     "swa_layers", "swa_window", "attn_full_layers", "dense_layers",
     "router_sigmoid", "router_on_input", "experts_relu", "attn_ungated",
+    "sconv_layers", "sconv_taps", "attn_head_64", "head_tied",
 )
 
 
@@ -420,7 +421,10 @@ def _first_call(program: str, tables: dict):
     whose experts' gate is ReLU (`layers/moe.py`), `router_on_input` the
     expert layers whose router reads the decoder layer's input, ahead of
     its mixer (`models/sequence_lm.py:DecoderLayer`), `attn_ungated` the
-    `GatedAttention` layers that have no output gate."""
+    `GatedAttention` layers that have no output gate, `attn_head_64`
+    those whose kernels run at a head of 64, `sconv_layers` the layers
+    whose mixer is a `GatedShortConv` and `sconv_taps` their taps summed,
+    `head_tied` the models whose head is their embedding table."""
     table_arg_bytes = sum(
         leaf.nbytes for leaf in jax.tree_util.tree_leaves(tables)
     )

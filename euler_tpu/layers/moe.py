@@ -166,8 +166,9 @@ class SparseMoE(nn.Module):
     the pick is made on `p + b`, `b` an `expert_bias` that no gradient
     reaches (whoever balances the load moves it; it starts at zero), and
     the kept weights are the `p` themselves, without it, divided by their
-    sum + 1e-20. Either router's kept weights are multiplied by
-    `route_scale`.
+    sum + `norm_eps` (1e-20: a guard against 0 / 0 and no more; a model
+    whose router states its own, LFM2's 1e-6, passes it). Either router's
+    kept weights are multiplied by `route_scale`.
     """
 
     num_experts: int
@@ -180,6 +181,7 @@ class SparseMoE(nn.Module):
     route_scale: float = 1.0
     shared_gated: bool = True
     activation: str = "silu"  # or "relu": the experts' gate
+    norm_eps: float = 1e-20  # added to the sigmoid router's divisor
 
     @nn.compact
     def __call__(self, x, route_on=None):
@@ -212,7 +214,7 @@ class SparseMoE(nn.Module):
                 _, top_e = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), k)
                 top_p = jnp.take_along_axis(scores, top_e, axis=-1)
                 if self.norm_topk:
-                    top_p = top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
+                    top_p = top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + self.norm_eps)
             else:
                 top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
                 if self.norm_topk:

@@ -1,7 +1,8 @@
 """Sequence mixers of decoder-only language models and what they share:
 `GatedDeltaNet` (linear attention by the gated delta rule),
-`GatedAttention` (causal softmax attention with an output gate, over
-every earlier key or over a sliding window of them),
+`GatedShortConv` (a causal depthwise convolution of a few taps between
+two gates), `GatedAttention` (causal softmax attention with an output
+gate, over every earlier key or over a sliding window of them),
 `IndexedSparseAttention` (causal softmax attention over the keys a
 learned indexer picks for each query), the zero-centred `RMSNorm` and
 the rotary embedding, by one position or by three.
@@ -10,7 +11,7 @@ Inputs are [B, T, H] float32. Projections run at the backend's default
 matmul precision; norms, gates, the delta rule's state, every softmax
 and the indexer's KL term are float32 (ops/seq_ops.py). Each mixer names
 its parts for a trace: `euler.gdn.{proj,conv,scan,out}`,
-`euler.attn.{proj,core,out}` (`euler.swa.*` where the layer has a window),
+`euler.sconv.{proj,mix,out}`, `euler.attn.{proj,core,out}` (`euler.swa.*` where the layer has a window),
 `euler.dsa.{proj,index,select,core,aux,out}`. Every mixer is called as
 `(x, positions) -> (y, its own loss or None)`. The two softmax mixers
 name their core's output `CORE_OUTPUT` (`_keep_core`): a rematerialised
@@ -176,6 +177,42 @@ class GatedDeltaNet(nn.Module):
             return o.reshape(batch, length, value_dim) @ w_out, None
 
 
+class GatedShortConv(nn.Module):
+    """LFM2's short convolution (HF `Lfm2ShortConv`) as a layer's whole
+    mixer: `[B | C | x~] = x W_in` (columns in that order), `u = B * x~`,
+    `c_t = sum_j w[:, j] u_{t-(taps-1)+j}` with zeros before the
+    sequence (`seq_ops.causal_conv1d`: depthwise, no bias), `y = (C * c)
+    W_out`. No activation and no norm inside, no state beyond the
+    `taps - 1` earlier steps; the order of the sequence is all the
+    position it knows. `euler.sconv.mix` holds the two gates and the
+    taps and nothing else: element-wise passes over [T, H], bound by
+    memory. It names no `CORE_OUTPUT`, so a rematerialised layer makes
+    it again whole. Counts itself `sconv_layers` and its `sconv_taps`.
+    x [B, T, H] -> (y [B, T, H], None: no loss of its own)."""
+
+    taps: int = 3
+
+    @nn.compact
+    def __call__(self, x, positions=None):
+        hidden = x.shape[-1]
+        w_in = self.param("in_proj", _MATRIX, (hidden, 3 * hidden), jnp.float32)
+        # torch's default for a depthwise `Conv1d`: uniform +-fan_in^-0.5
+        w_conv = self.param(
+            "conv", nn.initializers.normal(stddev=(3 * self.taps) ** -0.5),
+            (hidden, self.taps), jnp.float32,
+        )
+        w_out = self.param("out_proj", _MATRIX, (hidden, hidden), jnp.float32)
+        trace.count("sconv_layers")
+        trace.count("sconv_taps", self.taps)
+        with trace.scope("sconv.proj"):
+            gates = x @ w_in
+        with trace.scope("sconv.mix"):
+            b, c, fed = jnp.split(gates, 3, axis=-1)
+            mixed = c * seq_ops.causal_conv1d(b * fed, w_conv)
+        with trace.scope("sconv.out"):
+            return mixed @ w_out, None
+
+
 class GatedAttention(nn.Module):
     """Causal softmax attention with grouped queries, a zero-centred
     RMSNorm on each query and key head, rotary embedding on the first
@@ -191,11 +228,12 @@ class GatedAttention(nn.Module):
     tells the two kinds of layer apart; without one it sees every earlier
     key, under `euler.attn.*`. The softmax
     (`seq_ops.blockwise_causal_attention`) runs tile by tile in Pallas
-    kernels, one call a layer over the whole sequence, where length,
-    `block` and head are whole 128-wide tiles of the chip
-    (`seq_ops.causal_tile`: the shapes decide), and block by block as
-    dense float32 tensors everywhere else; a layer is tallied
-    `attn_core_kernel` or `attn_core_dense`. What it returns, before the
+    kernels, one call a layer over the whole sequence, where length and
+    `block` are whole 128-wide tiles of the chip and the head is such
+    tiles or the half tile, 64 (`seq_ops.causal_tile`: the shapes
+    decide), and block by block as dense float32 tensors everywhere
+    else; a layer is tallied `attn_core_kernel` or `attn_core_dense`, and
+    `attn_head_64` where the kernels run at that head. What it returns, before the
     gate, is the layer's `CORE_OUTPUT` (`_keep_core`).
     x [B, T, H] -> (y [B, T, H], None: no loss of its own)."""
 
@@ -245,6 +283,8 @@ class GatedAttention(nn.Module):
             q = q.reshape(batch, length, nkv, nq // nkv, d).transpose(0, 2, 3, 1, 4)
             by_tiles = seq_ops.causal_tile(q, self.block)
             trace.count("attn_core_kernel" if by_tiles else "attn_core_dense")
+            if by_tiles and d == 64:
+                trace.count("attn_head_64")
             o = seq_ops.blockwise_causal_attention(
                 q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
                 scale=d**-0.5, block=self.block, window=self.window, keep=_keep_core,

@@ -20,6 +20,7 @@ from euler_tpu.models.rgcn import RGCNSupervised  # noqa: F401
 from euler_tpu.models.autoencoders import DGI, GAE, dgi_batches, gae_batches  # noqa: F401
 from euler_tpu.models.sequence_lm import (  # noqa: F401
     KeyeVL2LM,
+    Lfm2MoeLM,
     Qwen3NextLM,
     SmallThinkerLM,
     TrinityLM,

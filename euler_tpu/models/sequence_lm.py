@@ -1,6 +1,6 @@
 """Decoder-only language models as `Estimator` models: one decoder,
 `DecoderLM`, whose layers differ in their mixer and in whether their
-feed-forward is dense, and the four architectures that plan their
+feed-forward is dense, and the five architectures that plan their
 layers on it.
 
 Decoder layer l: `h += Mixer_l(norm(h))`, then `h += FFN(norm(h))`; with
@@ -17,11 +17,17 @@ Trinity, HF `afmoe`) plans `GatedAttention` by `layer_types`, with a
 window and rotary or with neither, behind a sigmoid-scored router;
 `SmallThinkerLM` (PowerInfer's SmallThinker) plans it by two layouts,
 window and rotary, with no output gate and no head norms, ReLU-gated
-experts and a router that reads the layer's input ahead of the attention
-(layers/sequence.py). Embedding (`nn/encoders.py:Embedding`,
+experts and a router that reads the layer's input ahead of the attention;
+`Lfm2MoeLM` (LiquidAI's LFM2, HF `lfm2_moe`) plans `GatedShortConv` or
+`GatedAttention` by `layer_types`, behind a sigmoid-scored router, and
+ties its head to the embedding (layers/sequence.py). Embedding
+(`nn/encoders.py:Embedding`,
 so `euler.embed` and the table's scatter-add gradient are the ones every
 embedding model here has) times `embed_scale`, the layers, a final norm,
-an untied head and the mean next-token cross-entropy in float32. Every layer is
+a head — a leaf of its own, or with `tie_embeddings` the embedding table
+transposed, whose gradient is then that scatter-add plus the head's
+dense product in one leaf — and the mean next-token cross-entropy in
+float32. Every layer is
 rematerialised in the backward pass: what is kept of the forward is each
 layer's input and, where the mixer names one, its attention core's output
 (`_KEEP_CORE`).
@@ -46,6 +52,7 @@ from euler_tpu.layers.sequence import (
     CORE_OUTPUT,
     GatedAttention,
     GatedDeltaNet,
+    GatedShortConv,
     IndexedSparseAttention,
     RMSNorm,
 )
@@ -105,11 +112,14 @@ class DecoderLayer(nn.Module):
 
 
 class DecoderLM(nn.Module):
-    """The decoder the architectures share; a subclass plans `mixer(l)`.
-    Returns `(emb, loss, "routed_share", share)`: the final hidden states
-    [B, T, H], the loss, and the share of the step's token-expert
-    assignments that landed on experts held here (`experts_here[1] /
-    num_experts` when the router is even)."""
+    """The decoder the five architectures share; a subclass plans
+    `mixer(l)`. With `tie_embeddings` there is no `head` leaf: the logits
+    are `x @ table[:vocab].T`, part by part as with a head of its own,
+    and the model counts itself `head_tied`. Returns `(emb, loss,
+    "routed_share", share)`: the final hidden states [B, T, H], the loss,
+    and the share of the step's token-expert assignments that landed on
+    experts held here (`experts_here[1] / num_experts` when the router is
+    even)."""
 
     vocab_size: int
     hidden_size: int
@@ -128,6 +138,7 @@ class DecoderLM(nn.Module):
     shared_expert_intermediate_size: int = 512  # 0: no shared expert
     norm_topk_prob: bool = True
     router_score: str = "softmax"  # or "sigmoid" (layers/moe.py)
+    router_norm_eps: float = 1e-20  # the sigmoid router's divisor is sum + this
     route_scale: float = 1.0
     shared_expert_gated: bool = True
     expert_activation: str = "silu"  # the experts' gate: or "relu"
@@ -140,6 +151,7 @@ class DecoderLM(nn.Module):
     embed_scale: float = 1.0
     rms_norm_eps: float = 1e-6
     loss_chunks: int = 1  # the head and loss run over T in this many parts
+    tie_embeddings: bool = False  # the head is the embedding table transposed
 
     def mixer(self, index: int) -> nn.Module:
         raise NotImplementedError
@@ -162,6 +174,7 @@ class DecoderLM(nn.Module):
                 route_scale=self.route_scale,
                 shared_gated=self.shared_expert_gated,
                 activation=self.expert_activation,
+                norm_eps=self.router_norm_eps,
                 parent=None,
             )
         return nn.remat(DecoderLayer, policy=_KEEP_CORE)(
@@ -176,7 +189,8 @@ class DecoderLM(nn.Module):
             positions = jnp.broadcast_to(
                 jnp.arange(tokens.shape[1]), (len(self.rope_sections),) + tokens.shape
             )
-        h = Embedding(self.vocab_size, self.hidden_size, name="embed")(tokens)
+        embed = Embedding(self.vocab_size, self.hidden_size, name="embed")
+        h = embed(tokens)
         if self.embed_scale != 1.0:
             with trace.scope("embed"):
                 h = h * self.embed_scale
@@ -186,10 +200,15 @@ class DecoderLM(nn.Module):
             routed = routed + here
             own += [] if aux is None else [aux]
         emb = RMSNorm(self.rms_norm_eps, name="final_norm")(h)
-        w_head = self.param(
-            "head", nn.initializers.normal(stddev=0.02),
-            (self.hidden_size, self.vocab_size), jnp.float32,
-        )
+        if self.tie_embeddings:
+            trace.count("head_tied")
+            table = nn.meta.unbox(embed.get_variable("params", "table"))
+            w_head = table[: self.vocab_size].T  # the rows past it are padding
+        else:
+            w_head = self.param(
+                "head", nn.initializers.normal(stddev=0.02),
+                (self.hidden_size, self.vocab_size), jnp.float32,
+            )
 
         @jax.checkpoint
         def part_loss(x, y, w):
@@ -382,5 +401,54 @@ class SmallThinkerLM(DecoderLM):
             window=self.sliding_window_size if self.sliding_window_layout[index] else None,
             gated=False,
             head_norms=False,
+            parent=None,  # adopted by the layer, as its `mixer`
+        )
+
+
+class Lfm2MoeLM(DecoderLM):
+    """LiquidAI's LFM2 mixture of experts (HF `lfm2_moe`): the mixer of
+    layer l is what `layer_types[l]` names — "conv", a `GatedShortConv`
+    of `conv_L_cache` taps (published: three layers in four), or
+    "full_attention", grouped-query attention at a head of hidden / heads
+    (64) over every earlier key, RMSNorm on each query and key head,
+    rotary over the whole head, no output gate —; anything else raises.
+    The first `num_dense_layers` feed-forwards are dense SwiGLUs; the
+    others route by sigmoid scores, pick on `s + expert_bias`, weigh by
+    the `s` themselves over `sum + 1e-6` and have no shared expert. No
+    sandwich norms; the head is the embedding table transposed. The
+    router's `expert_bias` stays where it starts, as `TrinityLM`'s does."""
+
+    num_heads: int = 32
+    num_kv_heads: int = 8
+    head_dim: int = 64
+    rope_theta: float = 1e6
+    layer_types: tuple = ("full_attention", "conv", "conv", "conv")
+    conv_L_cache: int = 3
+    num_experts: int = 64
+    num_experts_per_tok: int = 4
+    moe_intermediate_size: int = 1536
+    shared_expert_intermediate_size: int = 0
+    router_score: str = "sigmoid"
+    router_norm_eps: float = 1e-6
+    num_dense_layers: int = 2
+    intermediate_size: int = 11776
+    rms_norm_eps: float = 1e-5
+    tie_embeddings: bool = True
+
+    def mixer(self, index: int):
+        kind = self.layer_types[index]
+        if kind == "conv":
+            return GatedShortConv(taps=self.conv_L_cache, parent=None)
+        if kind != "full_attention":
+            raise ValueError(f"layer {index} is of no known kind: {kind!r}")
+        return GatedAttention(
+            num_heads=self.num_heads,
+            num_kv_heads=self.num_kv_heads,
+            head_dim=self.head_dim,
+            rope_theta=self.rope_theta,
+            rotary_dim=self.head_dim,
+            block=self.attention_block,
+            eps=self.rms_norm_eps,
+            gated=False,
             parent=None,  # adopted by the layer, as its `mixer`
         )

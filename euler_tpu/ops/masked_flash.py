@@ -2,7 +2,8 @@
 extent is whole tiles: of a block of queries under a mask of picked keys
 (`seq_ops.masked_attention`, `seq_ops.attention_share`), and of a whole
 sequence under the causal line and a sliding window
-(`seq_ops.blockwise_causal_attention`).
+(`seq_ops.blockwise_causal_attention`; there the head may also be the
+half tile, 64).
 
 Scores [B, G, R, t, S] never exist outside a tile: a forward kernel
 keeps a running max, sum and output per row and head (online softmax)
@@ -119,8 +120,11 @@ def _bias(keep_ref):
 
 
 def _across(column, width: int):
-    """[rows, LANES], every lane the row's value -> [rows, width]."""
-    return column if width == LANES else jnp.tile(column, (1, width // LANES))
+    """[rows, LANES], every lane the row's value -> [rows, width]: whole
+    tiles of lanes, or the half tile a head of 64 is."""
+    if width <= LANES:
+        return column if width == LANES else column[:, :width]
+    return jnp.tile(column, (1, width // LANES))
 
 
 # -- forward ---------------------------------------------------------------
@@ -708,9 +712,9 @@ def causal_attention(
     grids are the tiles; differentiable in q, k, v.
 
     q [B, G, R, T, d], k, v [B, G, T, d] float32, T whole `tile`s, `tile`
-    and d whole 128-lane tiles (`seq_ops.causal_tile`); no window, or one
-    that holds the sequence, is every earlier key. Returns float32
-    [B, G, R, T, d].
+    whole 128-lane tiles and d such tiles or the half tile, 64
+    (`seq_ops.causal_tile`); no window, or one that holds the sequence,
+    is every earlier key. Returns float32 [B, G, R, T, d].
 
     Of the forward the backward reads the output and the logsumexp
     [B, G, R, 1, T]; both pass through `keep` first. A caller rematerialised

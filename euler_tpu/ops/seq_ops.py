@@ -174,12 +174,15 @@ def causal_tile(q: Array, block: int) -> int:
     queries q [B, G, R, T, d] cut by `block`: the tile of the kernels
     (ops/masked_flash.py) — the largest of 512, 256, 128 that divides T
     and `block` and at which a tile of a key/value head's R query heads,
-    [R, tile, d] float32, is at most 4 MiB (the cells': 512, at 2 and
-    4 MiB) — where T, `block` and d are whole 128-wide tiles of the chip;
-    0, the dense blocks, everywhere else: a head of 16 or 64, blocks of
-    16, a length of 576. The shapes decide; nothing a caller sets."""
+    [R, tile, d] float32, is at most 4 MiB (the cells': 512, at 0.5 to
+    4 MiB) — where T and `block` are whole 128-wide tiles of the chip
+    and d is such tiles or the half tile, 64 (the kernels' blocks then
+    end in an extent of 64: Mosaic takes them, and a score's product is
+    half as deep); 0, the dense blocks, everywhere else: a head of 16 or
+    96, blocks of 16, a length of 576. The shapes decide; nothing a
+    caller sets."""
     heads, length, head_dim = q.shape[2:]
-    if head_dim % 128:
+    if head_dim % 128 and head_dim != 64:
         return 0
     block = min(block, length)
     return next(
@@ -204,7 +207,8 @@ def blockwise_causal_attention(
     does the whole [T, T] square of scores exist, and what lies beyond
     the causal line or before the window is not computed.
 
-    By tiles, where the shapes are whole tiles of the chip: one forward
+    By tiles, where the shapes are whole tiles of the chip (or the head
+    half a tile, 64): one forward
     and two backward Pallas kernels a call, over the whole sequence
     (`masked_flash.causal_attention`). The mask is arithmetic on a
     tile's place: a tile of queries is given the tiles of keys it sees

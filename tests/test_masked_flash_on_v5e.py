@@ -1,7 +1,7 @@
 """The kernels of `ops/masked_flash.py` — indexed sparse attention's, and
 the causal and sliding-window ones of `GatedAttention` — compiled by
-Mosaic for a described TPU v5e at the `keye`, `trinity`, `qwen3` and
-`smallthinker` cells' own widths — nothing runs, no chip is needed: what the
+Mosaic for a described TPU v5e at the `keye`, `trinity`, `qwen3`,
+`smallthinker` and `lfm2` cells' own widths — nothing runs, no chip is needed: what the
 interpreter cannot show (a tile Mosaic refuses, more fast memory than a
 kernel may use), and the names the device trace will carry.
 
@@ -87,10 +87,13 @@ def test_a_block_of_the_cell_compiles_and_its_kernels_bear_their_scopes(one_chip
     "batch,groups,heads,length,head_dim,window",
     # `trinity`'s window layers and its full layer; `qwen3`'s attention
     # layer; `smallthinker`'s window layers (7 query heads a key head, a
-    # tile of queries given 9 tiles of keys) and its full layer
+    # tile of queries given 9 tiles of keys) and its full layer; `lfm2`'s
+    # full layer at a head of 64 (the kernels' blocks end in an extent of
+    # 64), and that head under a window
     [
         (1, 4, 8, 16384, 128, 2048), (1, 4, 8, 16384, 128, None), (2, 2, 8, 8192, 256, None),
         (1, 4, 7, 16384, 128, 4096), (1, 4, 7, 16384, 128, None),
+        (1, 8, 4, 16384, 64, None), (1, 8, 4, 16384, 64, 4096),
     ],
 )
 def test_a_layers_causal_core_compiles_and_its_kernels_bear_their_scope(

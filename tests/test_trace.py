@@ -18,7 +18,7 @@ from euler_tpu.datasets.synthetic import random_graph
 from euler_tpu.estimator import DeviceFeatureCache, Estimator, EstimatorConfig
 from euler_tpu.models import GraphSAGESupervised
 from euler_tpu.models.embedding_models import SkipGramModel
-from euler_tpu.models.sequence_lm import Qwen3NextLM
+from euler_tpu.models.sequence_lm import Lfm2MoeLM, Qwen3NextLM
 from euler_tpu.estimator import estimator as estimator_module
 from euler_tpu.utils import trace
 
@@ -57,6 +57,17 @@ def _estimator(kind, graph, tmp_path, **cfg):
             num_experts=4, num_experts_per_tok=2, moe_intermediate_size=16,
             shared_expert_intermediate_size=16, norm_topk_prob=True,
             experts_here=(0, 2), rms_norm_eps=1e-6, loss_chunks=2,
+        )
+        cache = None
+    elif kind == "lfm2":
+        flow = DeviceSequenceFlow(graph, batch_size=2, seq_len=32, doc_len=8)
+        model = Lfm2MoeLM(
+            vocab_size=120, hidden_size=32, num_layers=3,
+            layer_types=("conv", "full_attention", "conv"), num_heads=2,
+            num_kv_heads=1, head_dim=16, attention_block=16,
+            num_dense_layers=1, intermediate_size=48, num_experts=4,
+            num_experts_per_tok=2, moe_intermediate_size=16,
+            experts_here=(0, 2), loss_chunks=2,
         )
         cache = None
     else:
@@ -470,10 +481,12 @@ def step_program_hash(est) -> str:
     return hashlib.sha256(text.encode()).hexdigest()
 
 
-@pytest.mark.parametrize("kind", ["sage", "sequence"])
+@pytest.mark.parametrize("kind", ["sage", "sequence", "lfm2"])
 def test_the_step_program_is_the_recorded_one(kind, graph, tmp_path):
     """Spans, counters and the drain's fetch are the host's: the lowered
-    `train_step` of a graph model and of a sequence model hash as
+    `train_step` of a graph model and of two sequence models (a DeltaNet
+    / attention decoder with a head of its own; a convolution / attention
+    decoder with a tied head, since PR 42) hash as
     `step_program_hashes.json` says (written at PR 38's parent). A PR
     that changes the step program on purpose writes the hashes this
     test's failure shows into that file."""

@@ -250,6 +250,36 @@ def test_causal_kernels_at_seven_query_heads_and_a_reach_of_nine(window):
         assert float(jnp.max(jnp.abs(a - b))) < 1e-2 * float(jnp.max(jnp.abs(b))), name
 
 
+@pytest.mark.parametrize("window", [None, 200, 256])
+def test_causal_kernels_at_a_head_of_64_and_four_query_heads(window):
+    """LFM2's group: 4 query heads a key head at a head of 64, half a
+    tile of lanes, so that the kernels' blocks end in an extent of 64 and
+    a row's max and sum are cut to it (`masked_flash._across`): every
+    earlier key, a window cut inside a tile and one of whole tiles,
+    output and all three cotangents against the plain oracle and against
+    the dense blocks the same shapes took before."""
+    q, k, v, weight = _whole_tile_inputs(512, 64, 4, groups=2)
+    scale = 64**-0.5
+    assert seq_ops.causal_tile(q, 128) == 128
+
+    def by_blocks(q, k, v):
+        # a block of 16 is no whole tile: the dense float32 blocks
+        assert seq_ops.causal_tile(q, 16) == 0
+        return seq_ops.blockwise_causal_attention(q, k, v, scale, 16, window)
+
+    (_, got), g_got = _value_and_grads(
+        lambda q, k, v: seq_ops.blockwise_causal_attention(q, k, v, scale, 128, window), weight
+    )(q, k, v)
+    (_, want), g_want = _value_and_grads(
+        lambda q, k, v: _dense(q, k, v, scale, window or 512), weight
+    )(q, k, v)
+    (_, blocks), g_blocks = _value_and_grads(by_blocks, weight)(q, k, v)
+    assert got.shape == q.shape == (1, 2, 4, 512, 64)
+    for name, a, b, c in zip("oqkv", (got, *g_got), (want, *g_want), (blocks, *g_blocks)):
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-2 * float(jnp.max(jnp.abs(b))), name
+        assert float(jnp.max(jnp.abs(a - c))) < 1e-2 * float(jnp.max(jnp.abs(c))), name
+
+
 def test_by_tiles_keys_outside_every_window_get_a_zero_cotangent():
     """As by dense blocks: not a small number but none, through tiles
     that are run (a cut tile's dropped pairs) and tiles that are not."""
@@ -286,9 +316,12 @@ def _tiled_layer(length=512, head_dim=128, block=128, window=200):
 
 @pytest.mark.parametrize(
     "length,head_dim,block,kernel,dense",
-    # whole tiles; a head that is half a tile; blocks of 16; a length
-    # that is no whole tile
-    [(512, 128, 128, 1, 0), (512, 64, 128, 0, 1), (64, 128, 16, 0, 1), (576, 128, 128, 0, 1)],
+    # whole tiles; a head that is half a tile, which the kernels take
+    # too; a head of a quarter; blocks of 16; a length that is no whole tile
+    [
+        (512, 128, 128, 1, 0), (512, 64, 128, 1, 0), (512, 32, 128, 0, 1),
+        (64, 128, 16, 0, 1), (576, 128, 128, 0, 1),
+    ],
 )
 @pytest.mark.parametrize("window", [None, 200])
 def test_the_form_follows_the_shapes_and_the_counter_says_so(length, head_dim, block, kernel, dense, window):
