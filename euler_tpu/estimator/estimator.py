@@ -405,10 +405,12 @@ def _first_call(program: str, tables: dict):
     many of them score their keys through `seq_ops.indexer_scores`' own
     backward (head by head, no [B, J, rows, keys] cotangent: all of
     them), `mixer_core_kept` the
-    mixers whose layer keeps their attention core's output through its
-    rematerialisation, so that the core's loop of query blocks runs
-    twice a step and not three times (`layers/sequence.py:_keep_core`:
-    every softmax mixer; a `GatedDeltaNet` keeps nothing),
+    mixers whose layer keeps their core's output through its
+    rematerialisation, so that the core's loop of query blocks, or of
+    chunks, runs twice a step and not three times
+    (`layers/sequence.py:_keep_core`: every softmax mixer, and a
+    `GatedDeltaNet`, which keeps its groups' start states too; a
+    `GatedShortConv` keeps nothing),
     `attn_core_kernel` the `GatedAttention` layers whose causal or
     sliding-window core is the Pallas kernels, one call a layer whose
     forward runs once a step, and `attn_core_dense` those whose core is

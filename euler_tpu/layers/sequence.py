@@ -14,9 +14,10 @@ its parts for a trace: `euler.gdn.{proj,conv,scan,out}`,
 `euler.sconv.{proj,mix,out}`, `euler.attn.{proj,core,out}` (`euler.swa.*` where the layer has a window),
 `euler.dsa.{proj,index,select,core,aux,out}`. Every mixer is called as
 `(x, positions) -> (y, its own loss or None)`. The two softmax mixers
-name their core's output `CORE_OUTPUT` (`_keep_core`): a rematerialised
-decoder layer keeps that value of its forward, and the causal kernels'
-logsumexp with it (models/sequence_lm.py).
+and `GatedDeltaNet` name their core's output `CORE_OUTPUT`
+(`_keep_core`): a rematerialised decoder layer keeps that value of its
+forward, and with it the causal kernels' logsumexp and the delta rule's
+state at each group's start (models/sequence_lm.py).
 """
 
 from __future__ import annotations
@@ -41,9 +42,10 @@ def _keep_core(a):
     """Names what a rematerialised layer keeps of its mixer's core
     (models/sequence_lm.py: `save_only_these_names(CORE_OUTPUT)`): the
     core's output [B, G, R, T, d], the one thing the rest of the layer
-    wants from it, and where the core is the causal kernels
-    (`seq_ops.causal_tile`) their logsumexp [B, G, R, T] as well. How
-    often the core then runs a step:
+    wants from it, where the core is the causal kernels
+    (`seq_ops.causal_tile`) their logsumexp [B, G, R, T] as well, and
+    where it is the delta rule the state [groups, B, H, dk, dv] at each
+    group's start. How often the core then runs a step:
 
     - by dense blocks, and `IndexedSparseAttention` in either form: the
       blocks are checkpointed one by one and their residuals are their
@@ -52,7 +54,13 @@ def _keep_core(a):
       runs twice (forward, and before its own backward), not three times;
     - `GatedAttention` by tiles: output and logsumexp are all the
       backward kernels read of the forward kernel, so that one runs once
-      and each backward kernel once.
+      and each backward kernel once;
+    - `GatedDeltaNet`: the rule's own backward
+      (`seq_ops.chunk_gated_delta_rule`) makes a group again from the
+      state at its start, which only a scan from the sequence's start
+      gives: with output and start states kept the layer's second
+      forward runs no scan, and each group's forward runs twice
+      (forward, and before its own backward), not three times.
 
     A mixer that keeps its core counts itself as `mixer_core_kept`."""
     return checkpoint_name(a, CORE_OUTPUT)
@@ -168,8 +176,9 @@ class GatedDeltaNet(nn.Module):
             k = jnp.repeat(k, nv // nk, axis=1)
             o = seq_ops.chunk_gated_delta_rule(
                 q, k, v, g.transpose(0, 2, 1), beta.transpose(0, 2, 1),
-                chunk=self.chunk,
+                chunk=self.chunk, keep=_keep_core,
             )
+            trace.count("mixer_core_kept")
         with trace.scope("gdn.out"):
             o = o.transpose(0, 2, 1, 3)  # [B, T, nv, dv]
             gate = jax.nn.silu(z.reshape(batch, length, nv, dv))

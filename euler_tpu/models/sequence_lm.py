@@ -60,12 +60,14 @@ from euler_tpu.nn.encoders import Embedding
 from euler_tpu.utils import trace
 
 # What a rematerialised layer keeps of its forward besides its input: the
-# value its mixer names `CORE_OUTPUT` (`layers/sequence.py:_keep_core`
+# values its mixer names `CORE_OUTPUT` (`layers/sequence.py:_keep_core`
 # says why), so that the layer's second forward skips the mixer's loop of
-# query blocks, by far its longest part. The price is that value from
-# forward to backward: [B, T, heads * head_dim] float32 a layer, 268 MB
-# at 16,384 tokens of 32 x 128. A mixer that names nothing
-# (`GatedDeltaNet`) is rematerialised whole.
+# query blocks or of chunks, by far its longest part. The price is those
+# values from forward to backward: [B, T, heads * head_dim] float32 a
+# layer, 268 MB at 16,384 tokens of 32 x 128, and under a `GatedDeltaNet`
+# its groups' start states as well (a [dk, dv] float32 a head and a group:
+# 134 MB at 2 x 8,192 tokens in groups of 256). Every mixer but
+# `GatedShortConv` names its core; that one is rematerialised whole.
 _KEEP_CORE = jax.checkpoint_policies.save_only_these_names(CORE_OUTPUT)
 
 

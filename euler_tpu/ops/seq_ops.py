@@ -18,6 +18,7 @@ are the delta rule's state and gates and the attention's softmax.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -114,9 +115,58 @@ def _chunk_step(state, xs):
     return state, out
 
 
+def _one_group(state, xs):
+    """A group of chunks [group, B, H, C, ...] against the state at its
+    start -> (the state at its end, its output [group, B, H, C, dv])."""
+    return jax.lax.scan(_chunk_step, state, _chunk_local(*xs))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _grouped_rule(q, k, v, g, beta, keep):
+    return _grouped_rule_fwd(q, k, v, g, beta, keep)[0]
+
+
+def _grouped_rule_fwd(q, k, v, g, beta, keep):
+    """The groups [groups, group, B, H, C, ...] in turn -> the output
+    [groups, group, B, H, C, dv]. Of this forward the backward reads the
+    state at every group's start, [groups, B, H, dk, dv], and nothing
+    else that its arguments do not remake; that and the output pass
+    through `keep`."""
+    xs = (q, k, v, g, beta)
+
+    def forward(state, xs):
+        end, out = _one_group(state, xs)
+        return end, (state, out)
+
+    state = jnp.zeros(q.shape[2:4] + (q.shape[-1], v.shape[-1]), jnp.float32)
+    _, (starts, out) = jax.lax.scan(forward, state, xs)
+    starts = keep(starts)
+    return keep(out), (xs, starts)
+
+
+def _grouped_rule_bwd(keep, residuals, d_out):
+    """The groups from the last to the first, the cotangent of the state
+    carried: each is made again from its start (its chunk-local matrices
+    and its scan) and `(d_state, d_out)` pulled back through it."""
+    xs, starts = residuals
+
+    def backward(d_state, group):
+        state, xs, d_out = group
+        _, pull = jax.vjp(_one_group, state, xs)
+        return pull((d_state, d_out))
+
+    _, d_xs = jax.lax.scan(
+        backward, jnp.zeros_like(starts[0]), (starts, xs, d_out), reverse=True
+    )
+    return d_xs
+
+
+_grouped_rule.defvjp(_grouped_rule_fwd, _grouped_rule_bwd)
+
+
 def chunk_gated_delta_rule(
     q: Array, k: Array, v: Array, g: Array, beta: Array,
-    chunk: int = 64, group: int = 16,
+    chunk: int = 64, group: int = 4, keep=lambda a: a,
 ) -> Array:
     """The gated delta rule, chunk by chunk (Yang et al., Gated Delta
     Networks; the WY form of HF `torch_chunk_gated_delta_rule`).
@@ -136,11 +186,24 @@ def chunk_gated_delta_rule(
     sequential: a `lax.scan` over the chunks' states [dk, dv], in float32.
 
     The chunks are taken `group` at a time: a group's chunk-local
-    matrices are made together (batched matmuls), its states scanned, and
-    the group is rematerialised in the backward pass, so only one group's
-    [C, C] matrices and states are ever alive — they are several times
-    the inputs. A length that is not a whole number of groups is padded
-    with steps that leave the state as it is (beta = 0, g = 0).
+    matrices are made together (batched matmuls) and its states scanned.
+    The backward is the rule's own (`_grouped_rule`): it keeps the state
+    at each group's start and makes one group again at a time, from the
+    last to the first, so only one group's [C, C] matrices and states
+    are ever alive — they are several times the inputs — and a group's
+    forward runs twice a step: forward, and before its own backward. The
+    output and the groups' start states [groups, B, H, dk, dv] pass
+    through `keep`: a caller that is rematerialised and saves what `keep`
+    names (`layers/sequence.py:_keep_core`) has all the backward reads
+    that its own second forward does not remake, so that one runs no
+    scan; a caller that keeps nothing runs every group a third time.
+    With the starts kept a group is no unit of rematerialisation any
+    more, only of batching, and on the TPU four chunks are the fastest
+    (value and gradient of [2, 32, 8192, 128] at chunk 64: 52.7 ms at 4,
+    55.3 at 2, 61.1 at 8, 67.3 at 16, 85.3 at 32; PERF.md section 6,
+    PR 43); the results are the same to the bit. A length that is not a
+    whole number of groups is padded with steps that leave the state as
+    it is (beta = 0, g = 0).
     Returns o [B, H, T, dv].
     """
     length = q.shape[2]
@@ -155,14 +218,7 @@ def chunk_gated_delta_rule(
         a = a.reshape(a.shape[:2] + (groups, group, chunk) + a.shape[3:])
         return jnp.moveaxis(a, (2, 3), (0, 1))
 
-    @jax.checkpoint
-    def one_group(state, xs):
-        return jax.lax.scan(_chunk_step, state, _chunk_local(*xs))
-
-    state = jnp.zeros(q.shape[:2] + (q.shape[-1], v.shape[-1]), jnp.float32)
-    _, out = jax.lax.scan(
-        one_group, state, tuple(split(a) for a in (q, k, v, g, beta))
-    )
+    out = _grouped_rule(*(split(a) for a in (q, k, v, g, beta)), keep)
     # [groups, group, B, H, C, dv] -> [B, H, T, dv]
     out = jnp.moveaxis(out, (0, 1), (2, 3))
     out = out.reshape(out.shape[:2] + (-1, out.shape[-1]))

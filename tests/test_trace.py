@@ -487,7 +487,8 @@ def test_the_step_program_is_the_recorded_one(kind, graph, tmp_path):
     `train_step` of a graph model and of two sequence models (a DeltaNet
     / attention decoder with a head of its own; a convolution / attention
     decoder with a tied head, since PR 42) hash as
-    `step_program_hashes.json` says (written at PR 38's parent). A PR
+    `step_program_hashes.json` says (written at PR 38's parent;
+    `sequence` again at PR 43, whose delta rule brings its own backward). A PR
     that changes the step program on purpose writes the hashes this
     test's failure shows into that file."""
     with open(STEP_HASHES) as f:
