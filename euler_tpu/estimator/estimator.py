@@ -376,6 +376,7 @@ _TRACED_FORMS = (
     "swa_layers", "swa_window", "attn_full_layers", "dense_layers",
     "router_sigmoid", "router_on_input", "experts_relu", "attn_ungated",
     "sconv_layers", "sconv_taps", "attn_head_64", "head_tied",
+    "gdn_rule_kernel", "gdn_rule_xla",
 )
 
 
@@ -426,7 +427,11 @@ def _first_call(program: str, tables: dict):
     `GatedAttention` layers that have no output gate, `attn_head_64`
     those whose kernels run at a head of 64, `sconv_layers` the layers
     whose mixer is a `GatedShortConv` and `sconv_taps` their taps summed,
-    `head_tied` the models whose head is their embedding table."""
+    `head_tied` the models whose head is their embedding table,
+    `gdn_rule_kernel` the `GatedDeltaNet` layers whose delta rule runs
+    its groups of chunks in the Pallas kernels and `gdn_rule_xla` those
+    whose groups are XLA's (`seq_ops.delta_rule_by_kernels`: the shapes
+    decide)."""
     table_arg_bytes = sum(
         leaf.nbytes for leaf in jax.tree_util.tree_leaves(tables)
     )

@@ -174,6 +174,8 @@ class GatedDeltaNet(nn.Module):
             k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
             q = jnp.repeat(q * dk**-0.5, nv // nk, axis=1)
             k = jnp.repeat(k, nv // nk, axis=1)
+            by_kernels = seq_ops.delta_rule_by_kernels(q, v, self.chunk)
+            trace.count("gdn_rule_kernel" if by_kernels else "gdn_rule_xla")
             o = seq_ops.chunk_gated_delta_rule(
                 q, k, v, g.transpose(0, 2, 1), beta.transpose(0, 2, 1),
                 chunk=self.chunk, keep=_keep_core,

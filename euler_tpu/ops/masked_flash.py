@@ -66,15 +66,15 @@ def _tile(extent: int, asked: int | None) -> int:
 
 def _call(
     kernel, *, name, grid, in_specs, out_specs, out_shape, scratch=(), reduced=1, prefetch=1,
+    interpreted=None,
 ):
-    """`kernel` over `grid` with its first `prefetch` arguments (the
-    `live` table) prefetched into scalar memory: compiled by Mosaic on a
-    TPU, interpreted anywhere else. The last `reduced` axes of the grid
-    carry an accumulator."""
+    """`kernel` over `grid` with its first `prefetch` arguments (the `live` table) prefetched
+    into scalar memory: compiled by Mosaic on a TPU, interpreted anywhere else (`interpreted`
+    there, where given). The last `reduced` axes of the grid carry an accumulator."""
 
     def build(interpret):
         return pl.pallas_call(
-            kernel,
+            interpreted if interpret and interpreted else kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=prefetch, grid=grid, in_specs=in_specs,
                 out_specs=out_specs, scratch_shapes=list(scratch),
@@ -97,14 +97,14 @@ def _call(
     return run
 
 
-def _traced_once(fn):
-    """`fn(*arrays, scale, bq, bk)` under a `jax.jit` of its own: a model's
+def _traced_once(*static: str):
+    """`fn(*arrays, *static)` under a `jax.jit` of its own: a model's
     layers, a `custom_vjp`'s primal and forward rule and a block's
     rematerialisation call a kernel at the same shapes many times (the
     cell: 128 calls of 12 kernels), and a jitted function's trace — and
     its lowering, inside one program — is made once for them all; a bare
     `pallas_call` traces its kernel at every call."""
-    return jax.jit(fn, static_argnames=("scale", "bq", "bk"))
+    return functools.partial(jax.jit, static_argnames=static)
 
 
 def _live(keep: Array, bq: int, bk: int) -> Array:
@@ -194,7 +194,7 @@ def _forward_kernel(
         _leave(o_ref, lse_ref, m_ref, l_ref, acc_ref)
 
 
-@_traced_once
+@_traced_once("scale", "bq", "bk")
 def _forward(live, q, k, v, keep, scale, bq, bk):
     """q [B, G, R, t, d], k, v [B, G, S, d] bfloat16, keep [B, t, S] int8
     and its `_live` -> o [B, G, R, t, d], the logsumexp [B, G, R, t],
@@ -283,7 +283,7 @@ def _backward_kernel(
         dq_ref[...] *= scale
 
 
-@_traced_once
+@_traced_once("scale", "bq", "bk")
 def _backward(live, q, k, v, keep, do, lse, di, scale, bq, bk):
     """The cotangents of q, k, v (float32) from the forward's inputs, the
     output's cotangent (bfloat16), the logsumexp and `di = sum(o * do)`
@@ -404,7 +404,7 @@ def _share_kernel(live_ref, q_ref, k_ref, keep_ref, lse_ref, p_ref, *, scale: fl
         p_ref[...] = jnp.where(keep_ref[...] != 0, p_ref[...] * (1.0 / total), 0.0)
 
 
-@_traced_once
+@_traced_once("scale", "bq", "bk")
 def _share(live, q, k, keep, lse, scale, bq, bk):
     """q [B, G, R, t, d], k [B, G, S, d] bfloat16, keep [B, t, S] int8 and
     its `_live`, lse [B, G, R, t, LANES] -> float32 [B, t, S]."""
@@ -515,7 +515,7 @@ def _causal_forward_kernel(
         _leave(o_ref, lse_ref, m_ref, l_ref, acc_ref, lse_along_lanes=True)
 
 
-_causal_once = functools.partial(jax.jit, static_argnames=("scale", "tile", "window"))
+_causal_once = _traced_once("scale", "tile", "window")
 
 
 @_causal_once

@@ -7,10 +7,11 @@ a mask, softmax attention under that mask, the heads' probabilities
 averaged, the indexer's KL term), and the grouped matmul over rows sorted
 by expert.
 
-Plain XLA over static shapes, but for the two softmax attentions where
-their shapes are whole tiles of the chip: the one under a mask and the
-causal one are Pallas kernels (ops/masked_flash.py, imported when such a
-shape is first traced).
+Plain XLA over static shapes, but where the shapes are whole tiles of
+the chip: the softmax attention under a mask and the causal one are
+Pallas kernels (ops/masked_flash.py), and so are the delta rule's groups
+of chunks (ops/delta_rule_kernels.py), each imported when such a shape
+is first traced.
 Matmuls run at the backend's default precision (bf16 inputs, float32
 accumulation on the TPU) unless said. Inputs and results are float32; so
 are the delta rule's state and gates and the attention's softmax.
@@ -47,18 +48,24 @@ def causal_conv1d(x: Array, weight: Array) -> Array:
 
 @jax.custom_vjp
 def _unit_lower_inverse(lower: Array) -> Array:
-    """(I + L)^-1 for strictly lower-triangular L [..., C, C]. L is
-    nilpotent, so the Neumann series ends and factors into
-    (I - L)(I + L^2)(I + L^4)... — log2(C) squarings on the MXU where a
-    forward substitution would take C dependent steps. Its gradient is
-    taken from the result alone (`d(A^-1) = -A^-1 dA A^-1`), not through
-    the squarings."""
+    """(I + L)^-1 for strictly lower-triangular L [..., C, C]
+    (`_inverse_by_squarings`). Its gradient is taken from the result
+    alone (`d(A^-1) = -A^-1 dA A^-1`), not through the squarings."""
+    return _inverse_by_squarings(lower)
+
+
+def _inverse_by_squarings(lower: Array, order: int | None = None) -> Array:
+    """L is nilpotent, so the Neumann series of (I + L)^-1 ends and
+    factors into (I - L)(I + L^2)(I + L^4)... — log2(C) squarings on the
+    MXU where a forward substitution would take C dependent steps.
+    `order`, where given, is the least n with L^n = 0 (a block-diagonal
+    L of blocks of that size): the squarings stop there."""
     size = lower.shape[-1]
     eye = jnp.eye(size, dtype=lower.dtype)
     power = -lower
     inverse = eye + power
     reach = 2  # `inverse` holds the series up to power reach - 1
-    while reach < size:
+    while reach < (order or size):
         power = jnp.matmul(power, power, precision=_INVERSE_PRECISION)
         inverse = inverse + jnp.matmul(
             inverse, power, precision=_INVERSE_PRECISION
@@ -121,34 +128,47 @@ def _one_group(state, xs):
     return jax.lax.scan(_chunk_step, state, _chunk_local(*xs))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _grouped_rule(q, k, v, g, beta, keep):
-    return _grouped_rule_fwd(q, k, v, g, beta, keep)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _grouped_rule(q, k, v, g, beta, keep, kernels):
+    return _grouped_rule_fwd(q, k, v, g, beta, keep, kernels)[0]
 
 
-def _grouped_rule_fwd(q, k, v, g, beta, keep):
-    """The groups [groups, group, B, H, C, ...] in turn -> the output
-    [groups, group, B, H, C, dv]. Of this forward the backward reads the
-    state at every group's start, [groups, B, H, dk, dv], and nothing
-    else that its arguments do not remake; that and the output pass
-    through `keep`."""
+def _grouped_rule_fwd(q, k, v, g, beta, keep, kernels):
+    """The groups in turn -> the output. Of this forward the backward
+    reads the state at every group's start, [groups, B, H, dk, dv], and
+    nothing else that its arguments do not remake; that and the output
+    pass through `keep`. With `kernels` None the inputs are split into
+    groups [groups, group, B, H, C, ...] and a group is `_one_group`;
+    with `kernels` = (chunk, group) they lie as the rule takes them,
+    [B, H, T, ...], and a group is the forward kernel's grid step
+    (ops/delta_rule_kernels.py)."""
     xs = (q, k, v, g, beta)
+    if kernels:
+        from euler_tpu.ops import delta_rule_kernels
 
-    def forward(state, xs):
-        end, out = _one_group(state, xs)
-        return end, (state, out)
+        out, starts = delta_rule_kernels.forward(*xs, *kernels)
+    else:
 
-    state = jnp.zeros(q.shape[2:4] + (q.shape[-1], v.shape[-1]), jnp.float32)
-    _, (starts, out) = jax.lax.scan(forward, state, xs)
+        def forward(state, xs):
+            end, out = _one_group(state, xs)
+            return end, (state, out)
+
+        state = jnp.zeros(q.shape[2:4] + (q.shape[-1], v.shape[-1]), jnp.float32)
+        _, (starts, out) = jax.lax.scan(forward, state, xs)
     starts = keep(starts)
     return keep(out), (xs, starts)
 
 
-def _grouped_rule_bwd(keep, residuals, d_out):
+def _grouped_rule_bwd(keep, kernels, residuals, d_out):
     """The groups from the last to the first, the cotangent of the state
     carried: each is made again from its start (its chunk-local matrices
-    and its scan) and `(d_state, d_out)` pulled back through it."""
+    and its scan) and `(d_state, d_out)` pulled back through it, by
+    `jax.vjp` of `_one_group` or by the backward kernel."""
     xs, starts = residuals
+    if kernels:
+        from euler_tpu.ops import delta_rule_kernels
+
+        return delta_rule_kernels.backward(*xs, starts, d_out, *kernels)
 
     def backward(d_state, group):
         state, xs, d_out = group
@@ -162,6 +182,16 @@ def _grouped_rule_bwd(keep, residuals, d_out):
 
 
 _grouped_rule.defvjp(_grouped_rule_fwd, _grouped_rule_bwd)
+
+
+def delta_rule_by_kernels(q: Array, v: Array, chunk: int) -> bool:
+    """Which form `chunk_gated_delta_rule` takes for q [B, H, T, dk],
+    v [B, H, T, dv]: a group of chunks is the grid step of two Pallas
+    kernels (ops/delta_rule_kernels.py) where dk and dv are whole
+    128-lane tiles and the chunk a multiple of 8 (the cell: 128, 128,
+    64); `_one_group` in XLA everywhere else (a head of 16). The shapes
+    decide; nothing a caller sets."""
+    return q.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0 and chunk % 8 == 0
 
 
 def chunk_gated_delta_rule(
@@ -204,6 +234,14 @@ def chunk_gated_delta_rule(
     PR 43); the results are the same to the bit. A length that is not a
     whole number of groups is padded with steps that leave the state as
     it is (beta = 0, g = 0).
+
+    Where dk and dv are whole 128-lane tiles and the chunk a multiple of
+    8 (`delta_rule_by_kernels`) a group is one grid step of two Pallas
+    kernels, forward and pull-back, the state carried between steps in
+    the chip's fast memory. They read the inputs where they lie, so the
+    split into groups, a transposing copy of each input and cotangent,
+    is XLA's form's alone; the padding, `keep` and the start states the
+    backward reads are the same in both.
     Returns o [B, H, T, dv].
     """
     length = q.shape[2]
@@ -212,13 +250,15 @@ def chunk_gated_delta_rule(
     if pad:
         q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0))) for a in (q, k, v))
         g, beta = (jnp.pad(a, ((0, 0), (0, 0), (0, pad))) for a in (g, beta))
+    if delta_rule_by_kernels(q, v, chunk):
+        return _grouped_rule(q, k, v, g, beta, keep, (chunk, group))[:, :, :length]
     groups = (length + pad) // (chunk * group)
 
     def split(a):  # [B, H, T, ...] -> [groups, group, B, H, C, ...]
         a = a.reshape(a.shape[:2] + (groups, group, chunk) + a.shape[3:])
         return jnp.moveaxis(a, (2, 3), (0, 1))
 
-    out = _grouped_rule(*(split(a) for a in (q, k, v, g, beta)), keep)
+    out = _grouped_rule(*(split(a) for a in (q, k, v, g, beta)), keep, None)
     # [groups, group, B, H, C, dv] -> [B, H, T, dv]
     out = jnp.moveaxis(out, (0, 1), (2, 3))
     out = out.reshape(out.shape[:2] + (-1, out.shape[-1]))

@@ -127,3 +127,57 @@ def test_a_layers_causal_core_compiles_and_its_kernels_bear_their_scope(
         assert re.findall(r"euler\.([a-z_.]+)", name) == [scope], name
         assert ("transpose(" in name) == ("causal_core_forward" not in name), name
     assert not re.findall(rf"f32\[{batch},{groups},{heads},512,\d+\]", text)
+
+
+def test_the_delta_rules_kernels_compile_once_and_their_bodies_do_not_grow_with_the_sequence(
+    one_chip, quiet_cache
+):
+    """`qwen3`'s delta rule at the cell's widths (q, k, v [2, 32, T, 128],
+    chunk 64, groups of 4): value and gradient are two Mosaic calls
+    (`ops/delta_rule_kernels.py`), named by `euler.gdn.scan` alone, the
+    backward's under `transpose(`, which is how `gdn_ms` finds them, and
+    no `while` of the rule is left in the program. A kernel's body is one
+    group of one head: its lowered text is as long at T 2,048 as at
+    8,192, so its trace and lowering do not grow with the sequence; and
+    three layers' calls at one shape lower each kernel once."""
+    from euler_tpu.ops import seq_ops
+    from euler_tpu.utils import trace
+
+    def bodies(text):
+        return re.findall(r"\\22body\\22: \\22([A-Za-z0-9+/=]+)", text)
+
+    lengths = {}
+    for length in (2048, 8192):
+        shape = lambda s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)  # noqa: E731
+        q, gate = shape((2, 32, length, 128)), shape((2, 32, length))
+        assert seq_ops.delta_rule_by_kernels(q, q, 64)
+
+        def loss(q, k, v, g, beta):
+            with trace.scope("gdn.scan"):
+                o = seq_ops.chunk_gated_delta_rule(q, k, v, g, beta, chunk=64, group=4)
+            return jnp.sum(jnp.sin(o))
+
+        lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            q, q, q, gate, gate
+        )
+        lengths[length] = [len(b) for b in bodies(lowered.as_text())]
+        text = lowered.compile().as_text()
+        calls = re.findall(r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
+        kernels = sorted(name.split("/")[-2] for name in calls)
+        assert kernels == ["gdn_rule_backward", "gdn_rule_forward"]
+        for name in calls:
+            assert re.findall(r"euler\.([a-z_.]+)", name) == ["gdn.scan"], name
+            assert ("transpose(" in name) == ("gdn_rule_backward" in name), name
+        assert " while(" not in text
+    assert len(lengths[2048]) == 2 and lengths[2048] == lengths[8192]
+
+    def three_layers(q, k, v, g, beta):
+        return sum(
+            jnp.sum(jnp.sin(seq_ops.chunk_gated_delta_rule(q * s, k, v, g, beta)))
+            for s in (1.0, 2.0, 3.0)
+        )
+
+    text = jax.jit(jax.value_and_grad(three_layers, argnums=(0, 1, 2, 3, 4))).lower(
+        q, q, q, gate, gate
+    ).as_text()
+    assert len(bodies(text)) == 2

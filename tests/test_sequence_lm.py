@@ -186,6 +186,69 @@ def test_the_rules_own_backward_under_a_rematerialised_caller(bench, length, chu
     )
 
 
+def _both_forms(fn, args, weight, monkeypatch):
+    """Value and the five gradients of `fn` with the rule's groups in
+    the kernels (interpreted here) and in XLA (`_one_group`, `jax.vjp`)."""
+    from euler_tpu.ops import seq_ops
+
+    run = lambda: _value_and_grads(fn, lambda o: o * weight, (0, 1, 2, 3, 4))(*args)  # noqa: E731
+    kernels = run()
+    monkeypatch.setattr(seq_ops, "delta_rule_by_kernels", lambda *_: False)
+    return kernels, run()
+
+
+def _close_in_float32(got, want):
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5 * float(np.abs(b).max()))
+
+
+@pytest.mark.parametrize(
+    "length,group",
+    [(256, 2), (200, 2), (512, 4), (300, 4)],
+    ids=["group_2_whole", "group_2_padded", "group_4_whole", "group_4_padded"],
+)
+def test_the_rules_kernels_match_the_xla_group(length, group, monkeypatch):
+    """Where dk and dv are whole 128-lane tiles the rule's groups are the
+    grid steps of two Pallas kernels (`ops/delta_rule_kernels.py`, the
+    interpreter here, whose products are float32 as XLA's are on the
+    CPU): the output and all five gradients are the XLA group's to
+    float32's rounding, at a length of whole groups and one padded."""
+    from euler_tpu.ops import seq_ops
+
+    args = _rule_inputs(length, batch=1, heads=2, dk=128, dv=128)
+    assert seq_ops.delta_rule_by_kernels(args[0], args[2], 64)
+    weight = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+
+    def rule(q, k, v, g, beta):
+        return seq_ops.chunk_gated_delta_rule(q, k, v, g, beta, chunk=64, group=group)
+
+    kernels, xla = _both_forms(rule, args, weight, monkeypatch)
+    _close_in_float32(kernels, xla)
+
+
+def test_the_rules_kernels_under_a_rematerialised_caller(monkeypatch):
+    """The kernels inside a caller rematerialised as a decoder layer is,
+    saving what `_keep_core` names: gradients as the XLA group's under the
+    same caller, and the kept values are still the output and the groups'
+    start states alone — the output as the kernels leave it, [B, H, T, dv]
+    padded to whole groups, not split into them."""
+    from euler_tpu.layers.sequence import CORE_OUTPUT, _keep_core
+    from euler_tpu.ops import seq_ops
+
+    args = _rule_inputs(300, batch=1, heads=2, dk=128, dv=128)
+    weight = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+
+    def layer(q, k, v, g, beta):
+        o = seq_ops.chunk_gated_delta_rule(q, 0.5 * k, v, g, beta, chunk=64, keep=_keep_core)
+        return jnp.tanh(o)
+
+    kept = jax.checkpoint(layer, policy=jax.checkpoint_policies.save_only_these_names(CORE_OUTPUT))
+    named = sorted(shape for shape, _ in _kept_of_the_forward(lambda *a: jnp.sum(kept(*a)), *args))
+    assert named == sorted([(2, 1, 2, 128, 128), (1, 2, 512, 128)])
+    kernels, xla = _both_forms(kept, args, weight, monkeypatch)
+    _close_in_float32(kernels, xla)
+
+
 # -- (b) gated attention and the partial rotary ----------------------------
 
 
@@ -608,6 +671,7 @@ def test_first_call_span_counts_the_four_cores_kept(bench, config):
     ]
     assert args["program"] == "train_step" and args["mixer_core_kept"] == 4
     assert (args["attn_core_dense"], args["attn_core_kernel"]) == (1, 0)  # a head of 16
+    assert (args["gdn_rule_xla"], args["gdn_rule_kernel"]) == (3, 0)  # dk 16: XLA's groups
     assert (args["dsa_layers"], args["draw_elements"]) == (0, 1)
 
 
