@@ -44,11 +44,9 @@ import time
 
 import numpy as np
 
-from bench import FLAGSHIP
-
-NUM_NODES, OUT_DEGREE = FLAGSHIP["num_nodes"], FLAGSHIP["out_degree"]
-FEAT_DIM, DIMS = FLAGSHIP["feat_dim"], FLAGSHIP["dims"]
-BATCH, FANOUTS = FLAGSHIP["batch_size"], FLAGSHIP["fanouts"]
+# the flagship graph and model
+NUM_NODES, OUT_DEGREE, FEAT_DIM = 200_000, 15, 64
+DIMS, BATCH, FANOUTS = [128, 128], 1024, [10, 10]
 STEPS_PER_CALL = 16
 WARM_DISPATCHES, MORE_DISPATCHES = 2, 3
 # served request sizes: one above the top bucket (chunked 128+128+44), one

@@ -208,14 +208,11 @@ def load_project(
     root: str | None = None,
     exclude=_DEFAULT_EXCLUDE,
 ) -> Project:
-    """Default target: the euler_tpu package plus the repo's top-level
-    tooling scripts (bench.py) — the code the tier-1 gate guards."""
+    """Default target: the euler_tpu package — the code the tier-1 gate
+    guards."""
     root = root or repo_root()
     if paths is None:
         paths = [os.path.join(root, "euler_tpu")]
-        bench = os.path.join(root, "bench.py")
-        if os.path.exists(bench):
-            paths.append(bench)
     modules = []
     for path in iter_py_files(paths, exclude):
         rel = os.path.relpath(path, root)

@@ -1,6 +1,6 @@
 """Fast synthetic graph construction (array-direct, no JSON round-trip).
 
-Used by bench.py and scale tests: builds the columnar shard arrays directly
+Used by chip_smoke.py and scale tests: builds the columnar shard arrays directly
 so multi-million-edge graphs materialize in seconds.
 """
 

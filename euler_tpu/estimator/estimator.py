@@ -4,9 +4,9 @@
 The model contract matches the reference (mp_utils/base.py:24-95): a flax
 module whose __call__ returns (embedding, loss, metric_name, metric). Batches
 come from host-side generator functions (graph sampling + dataflow queries),
-get device_put, and run through one jitted update step. Checkpointing is
-Orbax; inference writes embedding_{worker}.npy / ids_{worker}.npy like
-base_estimator.py:157-179.
+get device_put, and run through one jitted update step. Checkpoints are
+retained step directories (training/checkpoint.py); inference writes
+embedding_{worker}.npy / ids_{worker}.npy like base_estimator.py:157-179.
 """
 
 from __future__ import annotations
@@ -41,8 +41,7 @@ class EstimatorConfig:
     # retained atomic checkpoints (euler_tpu/training/checkpoint.py):
     # save() commits step-numbered ckpt_<step>/ dirs and keeps this many
     # complete ones — a crash mid-save can never lose the previous good
-    # state. restore() picks the newest COMPLETE one (legacy single-path
-    # Orbax "ckpt" dirs still restore).
+    # state. restore() picks the newest COMPLETE one.
     keep_checkpoints: int = 3
     seed: int = 0
     # profiling (BaseEstimator(profiling=True) parity, base_estimator.py:
@@ -366,19 +365,6 @@ def _build_train_steps(model, tx, device_flow, feature_cache):
 
 _NO_SPAN = contextlib.nullcontext()
 
-# `trace.count` names of choices made while a program is traced; the
-# program's `step.first_call` span carries how often each was taken.
-_TRACED_FORMS = (
-    "agg_grid", "agg_scatter", "draw_rows", "draw_elements",
-    "dsa_layers", "dsa_topk", "dsa_core_masked", "dsa_core_kernel", "dsa_index_vjp",
-    "attn_core_dense", "attn_core_kernel",
-    "mixer_core_kept",
-    "swa_layers", "swa_window", "attn_full_layers", "dense_layers",
-    "router_sigmoid", "router_on_input", "experts_relu", "attn_ungated",
-    "sconv_layers", "sconv_taps", "attn_head_64", "head_tied",
-    "gdn_rule_kernel", "gdn_rule_xla",
-)
-
 
 @contextlib.contextmanager
 def _first_call(program: str, tables: dict):
@@ -391,47 +377,9 @@ def _first_call(program: str, tables: dict):
     own (`trace.self_stretches`): the three cover disjoint time, and
     `.cache_fetch` lies inside `.compile`. `table_arg_bytes` is what
     went in as the `tables` argument rather than as constants of the
-    executable;
-    `agg_grid` / `agg_scatter` count the aggregations the program's convs
-    traced in each form (`layers/conv.py:Conv.agg_add`), `draw_rows` /
-    `draw_elements` the neighbour draws that read the plane by whole rows
-    or slot by slot (`dataflow/device.py:_draw_neighbors`), `dsa_layers`
-    the indexed-sparse-attention mixers, `dsa_topk` the keys a query of
-    theirs may pick (summed over those layers), `dsa_core_kernel` how
-    many of them attend over the picked set tile by tile in the Pallas
-    kernels and `dsa_core_masked` how many as dense blocks under the
-    pick's mask — the shapes of a layer's runs decide, and a layer whose
-    runs differ counts under both
-    (`layers/sequence.py:IndexedSparseAttention`), `dsa_index_vjp` how
-    many of them score their keys through `seq_ops.indexer_scores`' own
-    backward (head by head, no [B, J, rows, keys] cotangent: all of
-    them), `mixer_core_kept` the
-    mixers whose layer keeps their core's output through its
-    rematerialisation, so that the core's loop of query blocks, or of
-    chunks, runs twice a step and not three times
-    (`layers/sequence.py:_keep_core`: every softmax mixer, and a
-    `GatedDeltaNet`, which keeps its groups' start states too; a
-    `GatedShortConv` keeps nothing),
-    `attn_core_kernel` the `GatedAttention` layers whose causal or
-    sliding-window core is the Pallas kernels, one call a layer whose
-    forward runs once a step, and `attn_core_dense` those whose core is
-    the loop of dense query blocks (`seq_ops.causal_tile`: the shapes
-    decide), `swa_layers`
-    the `GatedAttention` layers with a window, `swa_window` their windows
-    summed, `attn_full_layers` those without one, `dense_layers` the
-    decoder layers whose feed-forward is a `DenseMLP`, `router_sigmoid`
-    the expert layers that route by sigmoid scores, `experts_relu` those
-    whose experts' gate is ReLU (`layers/moe.py`), `router_on_input` the
-    expert layers whose router reads the decoder layer's input, ahead of
-    its mixer (`models/sequence_lm.py:DecoderLayer`), `attn_ungated` the
-    `GatedAttention` layers that have no output gate, `attn_head_64`
-    those whose kernels run at a head of 64, `sconv_layers` the layers
-    whose mixer is a `GatedShortConv` and `sconv_taps` their taps summed,
-    `head_tied` the models whose head is their embedding table,
-    `gdn_rule_kernel` the `GatedDeltaNet` layers whose delta rule runs
-    its groups of chunks in the Pallas kernels and `gdn_rule_xla` those
-    whose groups are XLA's (`seq_ops.delta_rule_by_kernels`: the shapes
-    decide)."""
+    executable; the other args are the tallies that moved while the
+    program was traced (`trace.count`), each named and described at the
+    site that counts it."""
     table_arg_bytes = sum(
         leaf.nbytes for leaf in jax.tree_util.tree_leaves(tables)
     )
@@ -441,8 +389,9 @@ def _first_call(program: str, tables: dict):
         before = trace.counts()
         yield
         after = trace.counts()
-        for form in _TRACED_FORMS:
-            call.args[form] = after.get(form, 0) - before.get(form, 0)
+        call.args.update(
+            (k, n - before.get(k, 0)) for k, n in after.items() if n != before.get(k, 0)
+        )
         parts = trace.self_stretches(events, ("trace", "lower", "compile"))
         parts.update(trace.self_stretches(events, ("cache_fetch",)))
         for kind, stretches in parts.items():
@@ -1078,81 +1027,38 @@ class Estimator:
 
     def restore(self) -> bool:
         """Restore the newest COMPLETE retained checkpoint (torn dirs —
-        a crash mid-save — are invisible by construction), falling back
-        to a legacy single-path Orbax `ckpt` dir for pre-retained
-        model_dirs."""
+        a crash mid-save — are invisible by construction). False where
+        the model_dir holds none."""
         from euler_tpu.training.checkpoint import CheckpointStore
 
         store = CheckpointStore(
             self.cfg.model_dir, keep=self.cfg.keep_checkpoints
         )
         step = store.latest_step()
-        if step is not None:
-            self._ensure_init()
-            ckpt = store.load(step)
-
-            def onto(saved, live):
-                leaves, tdef = jax.tree_util.tree_flatten(live)
-                if len(saved) != len(leaves):
-                    raise ValueError(
-                        f"checkpoint ckpt_{step:012d} carries {len(saved)} "
-                        f"leaves where the live tree has {len(leaves)} — "
-                        "model/optimizer config drifted from the saved run"
-                    )
-                put = [
-                    jax.device_put(s, x.sharding)
-                    if isinstance(x, jax.Array)
-                    else jnp.asarray(s)
-                    for s, x in zip(saved, leaves)
-                ]
-                return jax.tree_util.tree_unflatten(tdef, put)
-
-            self.params = onto(ckpt["params"], self.params)
-            self.opt_state = onto(ckpt["opt_state"], self.opt_state)
-            self.step = int(ckpt["step"])
-            return True
-        return self._restore_legacy_orbax()
-
-    def _restore_legacy_orbax(self) -> bool:
-        import orbax.checkpoint as ocp
-
-        path = os.path.join(os.path.abspath(self.cfg.model_dir), "ckpt")
-        if not os.path.exists(path):
+        if step is None:
             return False
         self._ensure_init()
-        ckpt = ocp.PyTreeCheckpointer()
-        # pre-opt_state checkpoints carry only params+step: detect by the
-        # checkpoint's own metadata, so genuine restore errors propagate
-        # instead of silently resetting optimizer slots. Orbax returns the
-        # tree metadata as a plain dict (>=0.7) or wrapped in an object
-        # with .item_metadata (older releases).
-        meta = ckpt.metadata(path)
-        if not hasattr(meta, "keys"):
-            meta = meta.item_metadata
-        has_opt = "opt_state" in set(meta.keys())
+        ckpt = store.load(step)
 
-        def _args(tpl):
-            # restore each leaf straight onto the live tree's sharding
-            # (orbax otherwise re-reads it from the sharding file, with a
-            # warning, and the arrays land unsharded on meshes)
-            return jax.tree_util.tree_map(
-                lambda x: ocp.ArrayRestoreArgs(sharding=x.sharding)
+        def onto(saved, live):
+            leaves, tdef = jax.tree_util.tree_flatten(live)
+            if len(saved) != len(leaves):
+                raise ValueError(
+                    f"checkpoint ckpt_{step:012d} carries {len(saved)} "
+                    f"leaves where the live tree has {len(leaves)} — "
+                    "model/optimizer config drifted from the saved run"
+                )
+            put = [
+                jax.device_put(s, x.sharding)
                 if isinstance(x, jax.Array)
-                else ocp.RestoreArgs(),
-                tpl,
-            )
+                else jnp.asarray(s)
+                for s, x in zip(saved, leaves)
+            ]
+            return jax.tree_util.tree_unflatten(tdef, put)
 
-        item = {"params": self.params, "step": 0}
-        if has_opt:
-            item["opt_state"] = self.opt_state
-        restored = ckpt.restore(path, item=item, restore_args=_args(item))
-        self.opt_state = (
-            restored["opt_state"]
-            if has_opt
-            else self.tx.init(restored["params"])
-        )
-        self.params = restored["params"]
-        self.step = int(restored["step"])
+        self.params = onto(ckpt["params"], self.params)
+        self.opt_state = onto(ckpt["opt_state"], self.opt_state)
+        self.step = int(ckpt["step"])
         return True
 
 

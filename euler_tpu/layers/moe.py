@@ -167,8 +167,9 @@ class SparseMoE(nn.Module):
     reaches (whoever balances the load moves it; it starts at zero), and
     the kept weights are the `p` themselves, without it, divided by their
     sum + `norm_eps` (1e-20: a guard against 0 / 0 and no more; a model
-    whose router states its own, LFM2's 1e-6, passes it). Either router's
-    kept weights are multiplied by `route_scale`.
+    whose router states its own, LFM2's 1e-6, passes it); a layer that
+    routes so counts itself `router_sigmoid`. Either router's kept
+    weights are multiplied by `route_scale`.
     """
 
     num_experts: int
@@ -254,7 +255,7 @@ class DenseMLP(nn.Module):
     """The dense feed-forward a model's leading layers have in an expert
     layer's place: `W_down (SiLU(W_gate x) * W_up x)` at `dim`, under
     `euler.mlp`. Called as an expert layer is: x [N, H] -> (y [N, H], 0
-    assignments routed)."""
+    assignments routed). Counts itself `dense_layers`."""
 
     dim: int
 

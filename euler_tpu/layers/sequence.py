@@ -123,7 +123,10 @@ class GatedDeltaNet(nn.Module):
     back. Columns of W_qkvz lie [q | k | v | z], each head by head (HF
     interleaves them by key head: a permutation of columns).
     x [B, T, H] -> (y [B, T, H], None: no loss of its own); the order of
-    the sequence is all the position it knows.
+    the sequence is all the position it knows. A layer is tallied
+    `gdn_rule_kernel` where the rule runs its groups of chunks in the
+    Pallas kernels and `gdn_rule_xla` where they are XLA's
+    (`seq_ops.delta_rule_by_kernels`: the shapes decide).
     """
 
     num_k_heads: int
@@ -237,7 +240,8 @@ class GatedAttention(nn.Module):
     sees that many keys, itself
     among them, and the layer's scopes are `euler.swa.*`, so that a trace
     tells the two kinds of layer apart; without one it sees every earlier
-    key, under `euler.attn.*`. The softmax
+    key, under `euler.attn.*`. A layer counts itself `swa_layers` and its
+    window into `swa_window`, or `attn_full_layers`. The softmax
     (`seq_ops.blockwise_causal_attention`) runs tile by tile in Pallas
     kernels, one call a layer over the whole sequence, where length and
     `block` are whole 128-wide tiles of the chip and the head is such
@@ -365,7 +369,8 @@ class IndexedSparseAttention(nn.Module):
     never exist (23 GB a layer at 16,384 tokens); elsewhere they are
     dense float32 tensors that live as long as the block. A layer is
     tallied `dsa_core_kernel`, `dsa_core_masked`, or both if its runs
-    differ. The runs' outputs put together, [B, G, R, T, d], are the
+    differ; it counts itself `dsa_layers` and its `topk` into
+    `dsa_topk`. The runs' outputs put together, [B, G, R, T, d], are the
     layer's `CORE_OUTPUT`: a decoder layer that is rematerialised keeps
     them, so its second forward remakes the projections and never runs
     the loops.

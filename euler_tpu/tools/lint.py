@@ -71,7 +71,7 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument(
         "paths",
         nargs="*",
-        help="files/dirs to lint (default: euler_tpu/ + bench.py)",
+        help="files/dirs to lint (default: euler_tpu/)",
     )
     ap.add_argument("--json", action="store_true", help="machine output")
     ap.add_argument(

@@ -110,8 +110,7 @@ def _ckpt_signature(model_dir: str) -> tuple:
     COMPLETE checkpoint commits (training/checkpoint.py COMMIT marker),
     so a poll landing mid-write — a trainer still fsync'ing a
     `ckpt_*.tmp-*` dir, or a torn dir left by a kill -9 — can never
-    trigger a swap onto a torn checkpoint. Legacy single-path Orbax
-    dirs keep the old newest-entry-mtime behavior."""
+    trigger a swap onto a torn checkpoint."""
     from euler_tpu.training.checkpoint import watch_signature
 
     return watch_signature(model_dir)

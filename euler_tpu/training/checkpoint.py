@@ -47,7 +47,6 @@ from euler_tpu.graph import format as tformat
 
 PREFIX = "ckpt_"
 MARKER = "COMMIT"
-LEGACY_NAME = "ckpt"  # the pre-retained single Orbax path
 
 
 def _fsync_path(path: str) -> None:
@@ -74,7 +73,7 @@ def _fsync_dir(path: str) -> None:
 
 def step_of(name: str) -> int | None:
     """`ckpt_000000000040` -> 40; None for anything else (tmp dirs,
-    the legacy path, unrelated files)."""
+    unrelated files)."""
     if not name.startswith(PREFIX) or ".tmp-" in name:
         return None
     tail = name[len(PREFIX):]
@@ -257,7 +256,7 @@ class CheckpointStore:
 
 def latest_complete(model_dir: str) -> str | None:
     """Path of the newest COMPLETE retained checkpoint under model_dir,
-    or None (legacy single-path dirs return None — callers fall back)."""
+    or None."""
     store = CheckpointStore(model_dir)
     step = store.latest_step()
     return None if step is None else store._path(step)
@@ -270,24 +269,13 @@ def watch_signature(model_dir: str) -> tuple:
     step, its COMMIT mtime). A half-written `ckpt_*.tmp-*` dir — or a
     torn dir left by a killed trainer — never changes the signature, so
     a watcher poll landing mid-write cannot trigger a swap onto a torn
-    checkpoint. Legacy single-path dirs (pre-retained `ckpt/`) fall back
-    to the old newest-entry-mtime scan so existing deploy flows keep
-    reloading."""
-    root = os.path.abspath(model_dir)
-    store = CheckpointStore(root)
+    checkpoint. `("none", 0, 0.0)` while there is no complete one."""
+    store = CheckpointStore(model_dir)
     step = store.latest_step()
-    if step is not None:
-        marker = os.path.join(store._path(step), MARKER)
-        try:
-            return ("retained", step, os.path.getmtime(marker))
-        except OSError:
-            return ("retained", step, 0.0)
-    legacy = os.path.join(root, LEGACY_NAME)
-    try:
-        mtime = max(
-            os.path.getmtime(os.path.join(legacy, e))
-            for e in os.listdir(legacy)
-        )
-    except (OSError, ValueError):
+    if step is None:
         return ("none", 0, 0.0)
-    return ("legacy", 0, mtime)
+    marker = os.path.join(store._path(step), MARKER)
+    try:
+        return ("retained", step, os.path.getmtime(marker))
+    except OSError:
+        return ("retained", step, 0.0)

@@ -1,6 +1,6 @@
 """Where the persistent XLA compile cache lives.
 
-Every entry point (chip_smoke.py, bench.py, run_model, tools/train,
+Every entry point (chip_smoke.py, benchmarks/run.py, run_model, tools/train,
 tools/serve, tools/retrieve) calls `configure_compile_cache()` before its
 first jit. The directory is part of the cache key, so it must be the same
 on every run: either the one the operator names through

@@ -12,7 +12,9 @@ Three things, used at the layer boundaries of the train path:
   happens before any profiler session exists. `spans()` returns the
   record; whoever asks writes it out.
 - `count(name)` for a choice made at trace time (which form an
-  aggregation took): a tally, which `step.first_call` turns into args.
+  aggregation took): a tally; `step.first_call` keeps, as its args,
+  every name that moved while its program was traced. What a name
+  counts is said where it is counted.
 
 What interrupts the host lands in the record too, under the span of its
 thread that it interrupted, so that a slow call names its cause (not

@@ -2,7 +2,7 @@
 
 Mirrors the driver's multi-chip dry-run environment — sharding/pjit tests run
 against 8 virtual CPU devices; the chip is reached only through chip_smoke.py
-and bench.py.
+and benchmarks/run.py.
 """
 
 import os

@@ -225,6 +225,17 @@ def test_dense_wire_quantized_within_budget(solo, monkeypatch):
         assert rq.wire_bytes_in["get_dense_feature"] < f32_wire, kind
 
 
+def test_dense_bf16_wire_is_lossy_and_its_error_small(solo, monkeypatch):
+    """bf16 pages are lossy (the error is not zero) and, on features of
+    unit scale, off by less than 0.05 anywhere."""
+    g, svc = solo
+    ids = np.arange(1, 33, dtype=np.uint64)
+    exact = g.shards[0].get_dense_feature(ids, ["feat"])
+    rq = _fresh_handle(svc, monkeypatch, page_dtype="bf16")
+    got = rq.get_dense_feature(ids, ["feat"])
+    assert 0 < np.max(np.abs(got - exact)) < 0.05
+
+
 def test_dense_old_server_sticky_degrade(solo, monkeypatch):
     """A server predating the trailing wire-dtype arg answers the exact
     f32 block; ONE such answer pins the handle to f32 — bit-identical
@@ -476,6 +487,25 @@ def test_wal_ship_reply_shapes_and_codec(tmp_path, monkeypatch):
         )
         assert ahead[3] is True and len(ahead) == 7
         assert ahead[4] == codec.IDENTITY and ahead[6] == svc._wal.tell()
+    finally:
+        svc.stop()
+
+
+def test_wal_ship_compresses_a_backlog_past_a_ratio_of_1_5(tmp_path, monkeypatch):
+    """A follower's catch-up over a zlib offer ships the log in fewer
+    than two thirds of its bytes."""
+    monkeypatch.setenv("EULER_TPU_SNAPSHOT_EVERY", "0")
+    g = Graph.from_json(_graph_dict(), num_partitions=1)
+    svc = GraphService(g.shards[0], g.meta, 0, wal_dir=str(tmp_path / "wal"))
+    try:
+        for r, muts in enumerate(_rounds(6, k=24)):
+            _dispatch_muts([svc], muts, f"r{r}")
+        raw, _ = svc._wal.read_raw(0, 1 << 20)
+        shipped = svc.dispatch(
+            "wal_ship", [0, 1 << 20, None, "log", None, None, 0.0, "zlib"]
+        )
+        assert shipped[4] == "zlib"
+        assert len(raw) / np.asarray(shipped[1]).nbytes > 1.5
     finally:
         svc.stop()
 

@@ -343,14 +343,14 @@ def test_first_call_span_carries_the_layers_forms(config, three_steps):
         if s.name == "step.first_call" and s.args["program"] == "train_step"
     )
     assert config["model"]["layer_types_here"] == config["layer_types"][1:6]
-    assert (args["sconv_layers"], args["sconv_taps"], args["head_tied"]) == (4, 12, 1)
-    assert (args["attn_full_layers"], args["attn_ungated"], args["swa_layers"]) == (1, 1, 0)
-    assert (args["dense_layers"], args["router_sigmoid"]) == (1, 4)
-    assert (args["router_on_input"], args["experts_relu"]) == (0, 0)
-    assert args["mixer_core_kept"] == 1  # the one softmax layer; a convolution keeps nothing
+    assert (args.get("sconv_layers", 0), args.get("sconv_taps", 0), args.get("head_tied", 0)) == (4, 12, 1)
+    assert (args.get("attn_full_layers", 0), args.get("attn_ungated", 0), args.get("swa_layers", 0)) == (1, 1, 0)
+    assert (args.get("dense_layers", 0), args.get("router_sigmoid", 0)) == (1, 4)
+    assert (args.get("router_on_input", 0), args.get("experts_relu", 0)) == (0, 0)
+    assert args.get("mixer_core_kept", 0) == 1  # the one softmax layer; a convolution keeps nothing
     # 64 tokens in blocks of 16 at the rehearsal's size: no whole tile
-    assert (args["attn_core_dense"], args["attn_core_kernel"], args["attn_head_64"]) == (1, 0, 0)
-    assert (args["dsa_layers"], args["agg_grid"], args["draw_elements"]) == (0, 0, 1)
+    assert (args.get("attn_core_dense", 0), args.get("attn_core_kernel", 0), args.get("attn_head_64", 0)) == (1, 0, 0)
+    assert (args.get("dsa_layers", 0), args.get("agg_grid", 0), args.get("draw_elements", 0)) == (0, 0, 1)
 
 
 def test_a_layer_of_whole_tiles_at_a_head_of_64_counts_itself(config):

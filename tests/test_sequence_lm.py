@@ -669,10 +669,10 @@ def test_first_call_span_counts_the_four_cores_kept(bench, config):
     (args,) = [
         s.args for s in trace.spans() if s.name == "step.first_call" and s.start_ns >= since
     ]
-    assert args["program"] == "train_step" and args["mixer_core_kept"] == 4
-    assert (args["attn_core_dense"], args["attn_core_kernel"]) == (1, 0)  # a head of 16
-    assert (args["gdn_rule_xla"], args["gdn_rule_kernel"]) == (3, 0)  # dk 16: XLA's groups
-    assert (args["dsa_layers"], args["draw_elements"]) == (0, 1)
+    assert args["program"] == "train_step" and args.get("mixer_core_kept", 0) == 4
+    assert (args.get("attn_core_dense", 0), args.get("attn_core_kernel", 0)) == (1, 0)  # a head of 16
+    assert (args.get("gdn_rule_xla", 0), args.get("gdn_rule_kernel", 0)) == (3, 0)  # dk 16: XLA's groups
+    assert (args.get("dsa_layers", 0), args.get("draw_elements", 0)) == (0, 1)
 
 
 # -- (f) the device flow ------------------------------------------------------

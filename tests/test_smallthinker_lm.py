@@ -263,13 +263,13 @@ def test_first_call_span_carries_the_layers_forms(config, three_steps):
         if s.name == "step.first_call" and s.args["program"] == "train_step"
     )
     assert config["model"]["layouts_here"]["sliding_window_layout"] == [0, 1, 1, 1]
-    assert (args["router_on_input"], args["experts_relu"], args["attn_ungated"]) == (4, 4, 4)
-    assert (args["swa_layers"], args["swa_window"], args["attn_full_layers"]) == (3, 3 * 24, 1)
-    assert (args["dense_layers"], args["router_sigmoid"]) == (0, 0)
-    assert args["mixer_core_kept"] == 4  # every layer's mixer is a softmax attention
+    assert (args.get("router_on_input", 0), args.get("experts_relu", 0), args.get("attn_ungated", 0)) == (4, 4, 4)
+    assert (args.get("swa_layers", 0), args.get("swa_window", 0), args.get("attn_full_layers", 0)) == (3, 3 * 24, 1)
+    assert (args.get("dense_layers", 0), args.get("router_sigmoid", 0)) == (0, 0)
+    assert args.get("mixer_core_kept", 0) == 4  # every layer's mixer is a softmax attention
     # heads of 16 in blocks of 16 at the rehearsal's size: no whole tile
-    assert (args["attn_core_dense"], args["attn_core_kernel"]) == (4, 0)
-    assert (args["dsa_layers"], args["agg_grid"], args["draw_elements"]) == (0, 0, 1)
+    assert (args.get("attn_core_dense", 0), args.get("attn_core_kernel", 0)) == (4, 0)
+    assert (args.get("dsa_layers", 0), args.get("agg_grid", 0), args.get("draw_elements", 0)) == (0, 0, 1)
 
 
 def test_the_model_is_its_reference_and_routes_ahead_of_the_attention(bench, config):

@@ -469,6 +469,27 @@ def test_first_call_children_cover_disjoint_time():
     assert not [s for s in got if s.name == "late_compile" and s.parent == call.id]
 
 
+def test_first_call_carries_the_tallies_that_moved_and_no_other():
+    """`step.first_call` keeps each `trace.count` name that moved while
+    the program was traced, by how much, whoever counted it; a name
+    counted before the span opened is not among its args."""
+    import jax.numpy as jnp
+
+    trace.count("t_earlier")
+    since = time.perf_counter_ns()
+
+    @jax.jit
+    def counted(v):
+        trace.count("t_moved", 2)
+        return v + 1
+
+    with estimator_module._first_call("t_moved", {}):
+        jax.block_until_ready(counted(jnp.ones(3)))
+    (call,) = [s for s in _mine(since) if s.name == "step.first_call"]
+    assert call.args["t_moved"] == 2
+    assert "t_earlier" not in call.args
+
+
 # -- the change is the host's ------------------------------------------------
 
 STEP_HASHES = os.path.join(os.path.dirname(__file__), "step_program_hashes.json")

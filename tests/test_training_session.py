@@ -190,7 +190,7 @@ def test_torn_checkpoint_sweep_never_loses_previous_good(tmp_path):
     assert store.steps() == [4, 8]
 
 
-def test_estimator_retained_save_restore_and_legacy_orbax(tmp_path):
+def test_estimator_retained_save_and_restore(tmp_path):
     g = Graph.from_json(_graph_dict())
     src = resumable_node_batches(g, _flow(g), 8, seed=1)
     cfg = EstimatorConfig(model_dir=str(tmp_path / "r"), log_steps=10**9)
@@ -204,20 +204,9 @@ def test_estimator_retained_save_restore_and_legacy_orbax(tmp_path):
     assert _leaves_equal(est.params, est2.params)
     assert _leaves_equal(est.opt_state, est2.opt_state)
 
-    # legacy single-path Orbax dirs (pre-retained format) still restore
-    import orbax.checkpoint as ocp
-
-    legacy_dir = str(tmp_path / "legacy")
-    ocp.PyTreeCheckpointer().save(
-        os.path.join(os.path.abspath(legacy_dir), "ckpt"),
-        {"params": est.params, "opt_state": est.opt_state, "step": est.step},
-        force=True,
-    )
-    est3 = Estimator(
-        MODEL, src, EstimatorConfig(model_dir=legacy_dir, log_steps=10**9)
-    )
-    assert est3.restore() and est3.step == 3
-    assert _leaves_equal(est.params, est3.params)
+    # a model_dir with no complete checkpoint restores nothing
+    empty = EstimatorConfig(model_dir=str(tmp_path / "empty"), log_steps=10**9)
+    assert Estimator(MODEL, src, empty).restore() is False
 
 
 # ---------------------------------------------------------------------------
