@@ -1,8 +1,9 @@
 """Sequence mixers of decoder-only language models and what they share:
 `GatedDeltaNet` (linear attention by the gated delta rule),
 `GatedShortConv` (a causal depthwise convolution of a few taps between
-two gates), `GatedAttention` (causal softmax attention with an output
-gate, over every earlier key or over a sliding window of them),
+two gates), `Mamba2Mixer` (Mamba-2's state-space mixer),
+`GatedAttention` (causal softmax attention with an output gate, over
+every earlier key or over a sliding window of them),
 `IndexedSparseAttention` (causal softmax attention over the keys a
 learned indexer picks for each query), the zero-centred `RMSNorm` and
 the rotary embedding, by one position or by three.
@@ -11,7 +12,8 @@ Inputs are [B, T, H] float32. Projections run at the backend's default
 matmul precision; norms, gates, the delta rule's state, every softmax
 and the indexer's KL term are float32 (ops/seq_ops.py). Each mixer names
 its parts for a trace: `euler.gdn.{proj,conv,scan,out}`,
-`euler.sconv.{proj,mix,out}`, `euler.attn.{proj,core,out}` (`euler.swa.*` where the layer has a window),
+`euler.sconv.{proj,mix,out}`, `euler.ssd.{proj,conv,scan,out}`,
+`euler.attn.{proj,core,out}` (`euler.swa.*` where the layer has a window),
 `euler.dsa.{proj,index,select,core,aux,out}`. Every mixer is called as
 `(x, positions) -> (y, its own loss or None)`. The two softmax mixers
 and `GatedDeltaNet` name their core's output `CORE_OUTPUT`
@@ -36,6 +38,7 @@ from euler_tpu.utils import trace
 _MATRIX = nn.initializers.normal(stddev=0.02)
 
 CORE_OUTPUT = "mixer_core"
+_DT_RANGE = (1e-3, 1e-1)  # Mamba-2's dt_min, dt_max: where its steps start
 
 
 def _keep_core(a):
@@ -227,6 +230,95 @@ class GatedShortConv(nn.Module):
             return mixed @ w_out, None
 
 
+class Mamba2Mixer(nn.Module):
+    """Mamba-2's mixer (HF `Mamba2Mixer`, one group): `[z | xBC | dt] = x
+    W_in` (columns d_inner | d_inner + 2 N | heads); `xBC = SiLU(causal
+    conv of `conv_kernel` taps + bias)`, `[x | B | C] = xBC`; `dt =
+    softplus(dt + dt_bias)`, `A = -exp(A_log)`; per head the state-space
+    scan `S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T`, `y_t = S_t C_t + D
+    x_t` (`seq_ops.chunked_ssd`, chunks of `chunk`); `y = RMSNorm(y *
+    SiLU(z)) W_out`, the norm over all d_inner. B and C are shared by the
+    heads. The order of the sequence is all the position it knows.
+
+    `A_log`, `dt_bias`, `D` and the norm are written as departures from
+    where Mamba-2 starts them, as the zero-centred `RMSNorm` writes its
+    weight (the same function, the same gradient): `A_log = log(1..heads)
+    + w`, `dt_bias = softplus^-1(dt0) + w` with `dt0` spread
+    log-uniformly over [1e-3, 1e-1] by head, `D = 1 + w`, the norm's `1 +
+    w`, every `w` from zeros. Scopes `euler.ssd.{proj,conv,scan,out}`;
+    nothing is named `CORE_OUTPUT`, so a rematerialised layer makes the
+    mixer again whole and the scan's forward runs twice a step. Counts
+    itself `ssd_layers` and its state size into `ssd_state`, and
+    `ssd_scan_kernel` where the scan runs in the Pallas kernels or
+    `ssd_scan_xla` where it is XLA's (`seq_ops.ssd_by_kernels`: the
+    shapes decide). x [B, T, H] -> (y [B, T, H], None: no loss of its
+    own)."""
+
+    num_heads: int
+    head_dim: int
+    state_size: int
+    conv_kernel: int = 4
+    chunk: int = 256
+    eps: float = 1e-5
+
+    def starts(self):
+        """(A_log, dt_bias) where Mamba-2 starts them, by head: A =
+        -(1..heads), dt log-uniform over `_DT_RANGE`."""
+        lo, hi = np.log(_DT_RANGE)
+        dt = np.exp(lo + (hi - lo) * (np.arange(self.num_heads) + 0.5) / self.num_heads)
+        return (
+            np.log(np.arange(1, self.num_heads + 1, dtype=np.float32)),
+            (dt + np.log(-np.expm1(-dt))).astype(np.float32),
+        )
+
+    @nn.compact
+    def __call__(self, x, positions=None):
+        batch, length, hidden = x.shape
+        heads, p, n = self.num_heads, self.head_dim, self.state_size
+        inner = heads * p
+        conv_dim = inner + 2 * n
+        w_in = self.param("in_proj", _MATRIX, (hidden, inner + conv_dim + heads), jnp.float32)
+        # torch's default for a depthwise `Conv1d`: uniform +-fan_in^-0.5
+        w_conv = self.param(
+            "conv", nn.initializers.normal(stddev=(3 * self.conv_kernel) ** -0.5),
+            (conv_dim, self.conv_kernel), jnp.float32,
+        )
+        b_conv = self.param(
+            "conv_bias", nn.initializers.normal(stddev=(3 * self.conv_kernel) ** -0.5),
+            (conv_dim,), jnp.float32,
+        )
+        w_a = self.param("A_log", nn.initializers.zeros, (heads,), jnp.float32)
+        w_dt = self.param("dt_bias", nn.initializers.zeros, (heads,), jnp.float32)
+        w_d = self.param("D", nn.initializers.zeros, (heads,), jnp.float32)
+        w_norm = self.param("norm", nn.initializers.zeros, (inner,), jnp.float32)
+        w_out = self.param("out_proj", _MATRIX, (inner, hidden), jnp.float32)
+        a_log, dt_bias = self.starts()
+        trace.count("ssd_layers")
+        trace.count("ssd_state", n)
+        with trace.scope("ssd.proj"):
+            zxbcdt = x @ w_in
+            z, xbc = zxbcdt[..., :inner], zxbcdt[..., inner : inner + conv_dim]
+            dt = jax.nn.softplus(
+                zxbcdt[..., inner + conv_dim :].astype(jnp.float32) + dt_bias + w_dt
+            )
+        with trace.scope("ssd.conv"):
+            xbc = jax.nn.silu(seq_ops.causal_conv1d(xbc, w_conv, b_conv))
+        with trace.scope("ssd.scan"):
+            xs = xbc[..., :inner].reshape(batch, length, heads, p)
+            b, c = xbc[..., inner : inner + n], xbc[..., inner + n :]
+            by_kernels = seq_ops.ssd_by_kernels(xs, b, self.chunk)
+            trace.count("ssd_scan_kernel" if by_kernels else "ssd_scan_xla")
+            a = dt * -jnp.exp(a_log + w_a)  # [B, T, heads], <= 0
+            y = seq_ops.chunked_ssd(
+                (xs * dt[..., None]).transpose(0, 2, 1, 3), a.transpose(0, 2, 1), b, c,
+                chunk=self.chunk,
+            )
+        with trace.scope("ssd.out"):
+            y = y.transpose(0, 2, 1, 3) + xs * (1.0 + w_d)[:, None]
+            y = y.reshape(batch, length, inner) * jax.nn.silu(z)
+            return (rms(y, self.eps) * (1.0 + w_norm)) @ w_out, None
+
+
 class GatedAttention(nn.Module):
     """Causal softmax attention with grouped queries, a zero-centred
     RMSNorm on each query and key head, rotary embedding on the first
@@ -248,8 +340,9 @@ class GatedAttention(nn.Module):
     tiles or the half tile, 64 (`seq_ops.causal_tile`: the shapes
     decide), and block by block as dense float32 tensors everywhere
     else; a layer is tallied `attn_core_kernel` or `attn_core_dense`, and
-    `attn_head_64` where the kernels run at that head. What it returns, before the
-    gate, is the layer's `CORE_OUTPUT` (`_keep_core`).
+    `attn_head_64` where the kernels run at that head. The scores are
+    scaled by `scale`, head_dim^-0.5 unless the model says otherwise. What
+    it returns, before the gate, is the layer's `CORE_OUTPUT` (`_keep_core`).
     x [B, T, H] -> (y [B, T, H], None: no loss of its own)."""
 
     num_heads: int
@@ -262,6 +355,7 @@ class GatedAttention(nn.Module):
     window: int | None = None
     gated: bool = True
     head_norms: bool = True
+    scale: float | None = None  # the scores' factor; None: head_dim^-0.5
 
     @nn.compact
     def __call__(self, x, positions=None):
@@ -302,7 +396,8 @@ class GatedAttention(nn.Module):
                 trace.count("attn_head_64")
             o = seq_ops.blockwise_causal_attention(
                 q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
-                scale=d**-0.5, block=self.block, window=self.window, keep=_keep_core,
+                scale=d**-0.5 if self.scale is None else self.scale,
+                block=self.block, window=self.window, keep=_keep_core,
             )
         with trace.scope(f"{kind}.out"):
             o = o.transpose(0, 3, 1, 2, 4).reshape(batch, length, nq, d)

@@ -19,6 +19,7 @@ from euler_tpu.models.layerwise_models import LayerwiseGCN  # noqa: F401
 from euler_tpu.models.rgcn import RGCNSupervised  # noqa: F401
 from euler_tpu.models.autoencoders import DGI, GAE, dgi_batches, gae_batches  # noqa: F401
 from euler_tpu.models.sequence_lm import (  # noqa: F401
+    GraniteHybridLM,
     KeyeVL2LM,
     Lfm2MoeLM,
     Qwen3NextLM,

@@ -1,9 +1,10 @@
 """Decoder-only language models as `Estimator` models: one decoder,
 `DecoderLM`, whose layers differ in their mixer and in whether their
-feed-forward is dense, and the five architectures that plan their
+feed-forward is dense, and the six architectures that plan their
 layers on it.
 
-Decoder layer l: `h += Mixer_l(norm(h))`, then `h += FFN(norm(h))`; with
+Decoder layer l: `h += Mixer_l(norm(h))`, then `h += FFN(norm(h))` (each
+added times `residual_multiplier`, 1 but in Granite); with
 `sandwich_norms` each sublayer's output passes a norm of its own before
 it is added (four norms a layer). The feed-forward is a `SparseMoE` that
 holds `experts_here` of the routed experts, or in the first
@@ -20,7 +21,11 @@ window and rotary, with no output gate and no head norms, ReLU-gated
 experts and a router that reads the layer's input ahead of the attention;
 `Lfm2MoeLM` (LiquidAI's LFM2, HF `lfm2_moe`) plans `GatedShortConv` or
 `GatedAttention` by `layer_types`, behind a sigmoid-scored router, and
-ties its head to the embedding (layers/sequence.py). Embedding
+ties its head to the embedding; `GraniteHybridLM` (IBM's Granite 4.0-H,
+HF `granitemoehybrid`) plans `Mamba2Mixer` or position-free
+`GatedAttention` by `layer_types`, a dense feed-forward in every layer,
+Granite's embedding, residual and logit multipliers and a tied head
+(layers/sequence.py). Embedding
 (`nn/encoders.py:Embedding`,
 so `euler.embed` and the table's scatter-add gradient are the ones every
 embedding model here has) times `embed_scale`, the layers, a final norm,
@@ -54,6 +59,7 @@ from euler_tpu.layers.sequence import (
     GatedDeltaNet,
     GatedShortConv,
     IndexedSparseAttention,
+    Mamba2Mixer,
     RMSNorm,
 )
 from euler_tpu.nn.encoders import Embedding
@@ -80,7 +86,8 @@ class DecoderLayer(nn.Module):
     `route_on_input` the experts' router is handed the stream as it
     entered the layer, before `input_norm` and the mixer (a router that
     stands ahead of the attention: nothing of its pick waits for the
-    mixer), and the layer counts itself `router_on_input`. Run under
+    mixer), and the layer counts itself `router_on_input`. Each
+    sublayer's output is added times `residual_multiplier`. Run under
     `nn.remat` (`DecoderLM._layer`): nothing in here outlives the forward
     but what `_KEEP_CORE` names."""
 
@@ -90,6 +97,7 @@ class DecoderLayer(nn.Module):
     mlp: nn.Module | None = None  # a dense feed-forward in the experts' place
     sandwich: bool = False
     route_on_input: bool = False  # the experts' router reads the layer's input
+    residual_multiplier: float = 1.0  # each sublayer's output is added times this
 
     @nn.compact
     def __call__(self, h, positions):
@@ -97,6 +105,8 @@ class DecoderLayer(nn.Module):
         mixed, aux = self.mixer(RMSNorm(self.eps, name="input_norm")(h), positions)
         if self.sandwich:
             mixed = RMSNorm(self.eps, name="mixer_out_norm")(mixed)
+        if self.residual_multiplier != 1.0:
+            mixed = mixed * self.residual_multiplier
         h = h + mixed
         x = RMSNorm(self.eps, name="post_norm")(h)
         x = x.reshape(-1, x.shape[-1])
@@ -110,18 +120,22 @@ class DecoderLayer(nn.Module):
         y = y.reshape(h.shape)
         if self.sandwich:
             y = RMSNorm(self.eps, name="ffn_out_norm")(y)
+        if self.residual_multiplier != 1.0:
+            y = y * self.residual_multiplier
         return h + y, routed, aux
 
 
 class DecoderLM(nn.Module):
-    """The decoder the five architectures share; a subclass plans
+    """The decoder the six architectures share; a subclass plans
     `mixer(l)`. With `tie_embeddings` there is no `head` leaf: the logits
     are `x @ table[:vocab].T`, part by part as with a head of its own,
-    and the model counts itself `head_tied`. Returns `(emb, loss,
-    "routed_share", share)`: the final hidden states [B, T, H], the loss,
-    and the share of the step's token-expert assignments that landed on
-    experts held here (`experts_here[1] / num_experts` when the router is
-    even)."""
+    and the model counts itself `head_tied`; the logits are divided by
+    `logits_divisor`. Returns `(emb, loss, "routed_share", share)`: the
+    final hidden states [B, T, H], the loss, and the share of the step's
+    token-expert assignments that landed on experts held here
+    (`experts_here[1] / num_experts` when the router is even); a model
+    whose every layer is dense routes nothing and returns `(emb, loss,
+    "loss", loss)`."""
 
     vocab_size: int
     hidden_size: int
@@ -151,6 +165,8 @@ class DecoderLM(nn.Module):
     intermediate_size: int = 0
     sandwich_norms: bool = False
     embed_scale: float = 1.0
+    residual_multiplier: float = 1.0  # sublayers' outputs are added times this
+    logits_divisor: float = 1.0  # the head's logits are divided by this
     rms_norm_eps: float = 1e-6
     loss_chunks: int = 1  # the head and loss run over T in this many parts
     tie_embeddings: bool = False  # the head is the embedding table transposed
@@ -181,7 +197,7 @@ class DecoderLM(nn.Module):
             )
         return nn.remat(DecoderLayer, policy=_KEEP_CORE)(
             self.mixer(index), moe, self.rms_norm_eps, mlp, self.sandwich_norms,
-            self.route_on_input, name=f"layer_{index}",
+            self.route_on_input, self.residual_multiplier, name=f"layer_{index}",
         )
 
     @nn.compact
@@ -216,6 +232,8 @@ class DecoderLM(nn.Module):
         def part_loss(x, y, w):
             with trace.scope("head"):
                 logits = (x @ w).astype(jnp.float32)
+                if self.logits_divisor != 1.0:
+                    logits = logits / self.logits_divisor
             with trace.scope("loss"):
                 return jnp.sum(
                     optax.softmax_cross_entropy_with_integer_labels(logits, y)
@@ -234,7 +252,9 @@ class DecoderLM(nn.Module):
             loss = total / targets.size
             if own:  # the mixers' own losses, coefficient 1
                 loss = loss + sum(own) / len(own)
-        expert_layers = self.num_layers - self.num_dense_layers
+        expert_layers = max(self.num_layers - self.num_dense_layers, 0)
+        if not expert_layers:  # nothing is routed: the loss is the metric
+            return emb, loss, "loss", loss
         assignments = tokens.size * self.num_experts_per_tok * expert_layers
         share = routed.astype(jnp.float32) / assignments
         return emb, loss, "routed_share", share
@@ -452,5 +472,67 @@ class Lfm2MoeLM(DecoderLM):
             block=self.attention_block,
             eps=self.rms_norm_eps,
             gated=False,
+            parent=None,  # adopted by the layer, as its `mixer`
+        )
+
+
+class GraniteHybridLM(DecoderLM):
+    """IBM's Granite 4.0-H (HF `granitemoehybrid` with no experts): the
+    mixer of layer l is what `layer_types[l]` names — "mamba", a
+    `Mamba2Mixer` of `mamba_n_heads` heads of `mamba_d_head` over a state
+    of `mamba_d_state` (published: nine layers in ten), or "attention",
+    grouped-query attention over every earlier key with no position at
+    all (`position_embedding_type` "nope"), no head norms, no output
+    gate, the scores times `attention_multiplier` —; anything else
+    raises. Every feed-forward is the dense SwiGLU "shared MLP" of
+    `intermediate_size`. Granite's multipliers: the embedding enters
+    times `embed_scale` (`embedding_multiplier`), each sublayer's output
+    is added times `residual_multiplier`, the logits are divided by
+    `logits_divisor` (`logits_scaling`); the head is the embedding table
+    transposed. A model whose layers are all dense returns the loss as
+    its metric (`DecoderLM`)."""
+
+    num_heads: int = 32
+    num_kv_heads: int = 8
+    head_dim: int = 64
+    layer_types: tuple = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+    attention_multiplier: float = 0.015625
+    mamba_n_heads: int = 64
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    num_dense_layers: int = 1 << 30  # every layer's feed-forward is dense
+    intermediate_size: int = 8192
+    embed_scale: float = 12.0
+    residual_multiplier: float = 0.22
+    logits_divisor: float = 8.0
+    rms_norm_eps: float = 1e-5
+    tie_embeddings: bool = True
+
+    def mixer(self, index: int):
+        kind = self.layer_types[index]
+        if kind == "mamba":
+            return Mamba2Mixer(
+                num_heads=self.mamba_n_heads,
+                head_dim=self.mamba_d_head,
+                state_size=self.mamba_d_state,
+                conv_kernel=self.mamba_d_conv,
+                chunk=self.mamba_chunk_size,
+                eps=self.rms_norm_eps,
+                parent=None,  # adopted by the layer, as its `mixer`
+            )
+        if kind != "attention":
+            raise ValueError(f"layer {index} is of no known kind: {kind!r}")
+        return GatedAttention(
+            num_heads=self.num_heads,
+            num_kv_heads=self.num_kv_heads,
+            head_dim=self.head_dim,
+            rotary_dim=0,
+            block=self.attention_block,
+            eps=self.rms_norm_eps,
+            gated=False,
+            head_norms=False,
+            scale=self.attention_multiplier,
             parent=None,  # adopted by the layer, as its `mixer`
         )

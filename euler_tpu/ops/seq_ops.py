@@ -1,7 +1,8 @@
 """Device-side primitives of sequence layers (layers/sequence.py,
 layers/moe.py): a causal depthwise convolution over time, the chunked
-gated delta rule, causal softmax attention by query blocks (over every
-earlier key, or over a sliding window of them), the parts of
+gated delta rule, Mamba-2's chunked state-space scan, causal softmax
+attention by query blocks (over every earlier key, or over a sliding
+window of them), the parts of
 indexed sparse attention (an indexer's scores, the k largest of a row as
 a mask, softmax attention under that mask, the heads' probabilities
 averaged, the indexer's KL term), and the grouped matmul over rows sorted
@@ -10,8 +11,8 @@ by expert.
 Plain XLA over static shapes, but where the shapes are whole tiles of
 the chip: the softmax attention under a mask and the causal one are
 Pallas kernels (ops/masked_flash.py), and so are the delta rule's groups
-of chunks (ops/delta_rule_kernels.py), each imported when such a shape
-is first traced.
+of chunks (ops/delta_rule_kernels.py) and the state-space scan's chunks
+(ops/ssd_kernels.py), each imported when such a shape is first traced.
 Matmuls run at the backend's default precision (bf16 inputs, float32
 accumulation on the TPU) unless said. Inputs and results are float32; so
 are the delta rule's state and gates and the attention's softmax.
@@ -32,18 +33,19 @@ Array = jax.Array
 _INVERSE_PRECISION = jax.lax.Precision.HIGHEST
 
 
-def causal_conv1d(x: Array, weight: Array) -> Array:
-    """Depthwise convolution over time that sees no later step, no bias.
+def causal_conv1d(x: Array, weight: Array, bias: Array | None = None) -> Array:
+    """Depthwise convolution over time that sees no later step.
 
-    x [B, T, C], weight [C, K]: y_t = sum_j weight[:, j] * x_{t-(K-1)+j},
-    with zeros before the sequence's start (torch `Conv1d(groups=C,
-    padding=K-1)` cut to T).
+    x [B, T, C], weight [C, K]: y_t = sum_j weight[:, j] * x_{t-(K-1)+j}
+    (+ bias [C], where given), with zeros before the sequence's start
+    (torch `Conv1d(groups=C, padding=K-1)` cut to T).
     """
     length, taps = x.shape[1], weight.shape[1]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    return sum(
+    y = sum(
         padded[:, j : j + length, :] * weight[:, j] for j in range(taps)
     )
+    return y if bias is None else y + bias
 
 
 @jax.custom_vjp
@@ -263,6 +265,140 @@ def chunk_gated_delta_rule(
     out = jnp.moveaxis(out, (0, 1), (2, 3))
     out = out.reshape(out.shape[:2] + (-1, out.shape[-1]))
     return out[:, :, :length]
+
+
+def _ssd_chunk(state, xs):
+    """One chunk of the state-space scan against the state [B, H, P, N]
+    at its start -> (the state at its end, the chunk's output [B, H, C,
+    P]). u [B, H, C, P], a [B, H, C], b, c [B, C, N]."""
+    u, a, b, c = xs
+    chunk = a.shape[-1]
+    run = jnp.cumsum(a, axis=-1)  # G: [B, H, C]
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    # exp(G_i - G_j) for j <= i, where the exponent is <= 0; 0 above
+    gap = run[..., :, None] - run[..., None, :]
+    decay = jnp.where(lower, jnp.exp(jnp.where(lower, gap, 0.0)), 0.0)
+    cb = jnp.einsum("bin,bjn->bij", c, b)  # one for all heads
+    out = jnp.einsum("bhij,bhjp->bhip", cb[:, None] * decay, u)
+    out = out + jnp.exp(run)[..., None] * jnp.einsum("bin,bhpn->bhip", c, state)
+    end = run[..., -1]
+    fall = jnp.exp(end[..., None] - run)[..., None]  # exp(G_end - G)
+    state = state * jnp.exp(end)[..., None, None] + jnp.einsum(
+        "bhjp,bjn->bhpn", u * fall, b
+    )
+    return state, out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _ssd(u, a, b, c, chunk):
+    return _ssd_fwd(u, a, b, c, chunk)[0]
+
+
+def _ssd_fwd(u, a, b, c, chunk):
+    """The chunks in turn -> the output; of this forward the backward
+    reads the state at every chunk's start, [chunks, B, H, P, N], beside
+    the inputs. With `chunk` None the inputs are split into chunks
+    [chunks, B, ...] and a chunk is `_ssd_chunk`; with `chunk` given they
+    lie as the scan takes them and a chunk of a head is the forward
+    kernel's grid step (ops/ssd_kernels.py)."""
+    if chunk:
+        from euler_tpu.ops import ssd_kernels
+
+        out, starts = ssd_kernels.forward(u, a, b, c, chunk)
+    else:
+
+        def forward(state, xs):
+            end, out = _ssd_chunk(state, xs)
+            return end, (state, out)
+
+        state = jnp.zeros(u.shape[1:3] + (u.shape[-1], b.shape[-1]), jnp.float32)
+        _, (starts, out) = jax.lax.scan(forward, state, (u, a, b, c))
+    return out, ((u, a, b, c), starts)
+
+
+def _ssd_bwd(chunk, residuals, d_out):
+    """The chunks from the last to the first, the state's cotangent
+    carried: each pulled back from its kept start state, by `jax.vjp` of
+    `_ssd_chunk` or by the backward kernel."""
+    xs, starts = residuals
+    if chunk:
+        from euler_tpu.ops import ssd_kernels
+
+        return ssd_kernels.backward(*xs, starts, d_out, chunk)
+
+    def backward(d_state, one):
+        state, xs, d_out = one
+        _, pull = jax.vjp(_ssd_chunk, state, xs)
+        return pull((d_state, d_out))
+
+    _, d_xs = jax.lax.scan(
+        backward, jnp.zeros_like(starts[0]), (starts, xs, d_out), reverse=True
+    )
+    return d_xs
+
+
+_ssd.defvjp(_ssd_fwd, _ssd_bwd)
+
+
+def ssd_by_kernels(u: Array, b: Array, chunk: int) -> bool:
+    """Which form `chunked_ssd` takes for u [B, H, T, P], b [B, T, N]:
+    a chunk of a head is the grid step of two Pallas kernels
+    (ops/ssd_kernels.py) where the chunk and N are whole 128-wide tiles
+    of the chip and the head is such tiles or the half tile, 64 (the
+    cell: 256, 128, 64); `_ssd_chunk` in XLA everywhere else (a head of
+    16, a state of 8). The shapes decide; nothing a caller sets."""
+    head = u.shape[-1]
+    return (head % 128 == 0 or head == 64) and b.shape[-1] % 128 == 0 and chunk % 128 == 0
+
+
+def chunked_ssd(u: Array, a: Array, b: Array, c: Array, chunk: int = 256) -> Array:
+    """Mamba-2's state-space scan with scalar decay per head (Dao & Gu,
+    Transformers are SSMs: the chunked SSD form), B and C shared by the
+    heads (one group).
+
+    u [B, H, T, P] the input already times its step (dt x), a [B, H, T]
+    the log of the decay (dt A, <= 0), b, c [B, T, N]. Per head, from
+    S_0 = 0:
+
+        S_t = exp(a_t) S_{t-1} + u_t b_t^T  ([P, N]);   y_t = S_t c_t
+
+    Inside a chunk, with G the running sum of a and S the state at the
+    chunk's start: Y = ((C B^T) * L) U + exp(G) (C S^T), L_ij =
+    exp(G_i - G_j) for j <= i and 0 above, the exponent masked before
+    the exp (in-chunk sums reach -1e4: a difference of two of them is
+    the only form that neither overflows nor loses the small gaps); the
+    state moves on by exp(G_end) S + U^T (exp(G_end - G) B). C B^T is
+    made once a chunk for all heads. Only the state's passage is
+    sequential, in float32, as are the decays and every cotangent.
+
+    The backward is the scan's own: it keeps the state at each chunk's
+    start, [chunks, B, H, P, N] float32, and pulls one chunk back at a
+    time from the last, so only one chunk's [C, C] matrices are ever
+    alive. Where the shapes are whole tiles (`ssd_by_kernels`) forward
+    and pull-back are two Pallas kernels whose grid step is one chunk of
+    one head, the state carried between steps in the chip's fast
+    memory; they read the inputs where they lie, so the split into
+    chunks is XLA's form's alone. A length that is not a whole number of
+    chunks is padded with steps that leave the state as it is (a = 0,
+    u = 0). Returns y [B, H, T, P], without the skip D x.
+    """
+    length = u.shape[2]
+    pad = -length % chunk
+    if pad:
+        u = jnp.pad(u, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        a = jnp.pad(a, ((0, 0), (0, 0), (0, pad)))
+        b, c = (jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in (b, c))
+    if ssd_by_kernels(u, b, chunk):
+        return _ssd(u, a, b, c, chunk)[:, :, :length]
+    chunks = (length + pad) // chunk
+
+    def split(x, axis):  # the time axis cut into chunks, the chunks in front
+        x = x.reshape(x.shape[:axis] + (chunks, chunk) + x.shape[axis + 1 :])
+        return jnp.moveaxis(x, axis, 0)
+
+    out = _ssd(split(u, 2), split(a, 2), split(b, 1), split(c, 1), None)
+    out = jnp.moveaxis(out, 0, 2)  # [B, H, chunks, C, P]
+    return out.reshape(out.shape[:2] + (-1, out.shape[-1]))[:, :, :length]
 
 
 def causal_tile(q: Array, block: int) -> int:

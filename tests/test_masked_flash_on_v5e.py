@@ -1,7 +1,8 @@
 """The kernels of `ops/masked_flash.py` — indexed sparse attention's, and
 the causal and sliding-window ones of `GatedAttention` — compiled by
 Mosaic for a described TPU v5e at the `keye`, `trinity`, `qwen3`,
-`smallthinker` and `lfm2` cells' own widths — nothing runs, no chip is needed: what the
+`smallthinker` and `lfm2` cells' own widths, and the state-space scan's
+kernels at `granite`'s — nothing runs, no chip is needed: what the
 interpreter cannot show (a tile Mosaic refuses, more fast memory than a
 kernel may use), and the names the device trace will carry.
 
@@ -180,4 +181,57 @@ def test_the_delta_rules_kernels_compile_once_and_their_bodies_do_not_grow_with_
     text = jax.jit(jax.value_and_grad(three_layers, argnums=(0, 1, 2, 3, 4))).lower(
         q, q, q, gate, gate
     ).as_text()
+    assert len(bodies(text)) == 2
+
+
+def test_the_state_space_scans_kernels_compile_once_and_their_bodies_do_not_grow_with_the_sequence(
+    one_chip, quiet_cache
+):
+    """`granite`'s scan at the cell's widths (u [1, 64, T, 64], a state
+    of 128 with B and C shared by the heads, chunks of 256): value and
+    gradient are two Mosaic calls (`ops/ssd_kernels.py`), named by
+    `euler.ssd.scan` alone, the backward's under `transpose(`, which is
+    how `ssd_ms` and `ssd_scan_roofline_pct` find them, and no `while`
+    of the scan is left in the program. A kernel's body is one chunk of
+    one head: its lowered text is as long at T 4,096 as at 16,384, but
+    for the backward's index maps, which take the chunks from the last
+    and so hold the last one's number (a few bytes of a constant); and
+    nine layers' calls at one shape lower each kernel once."""
+    from euler_tpu.ops import seq_ops
+    from euler_tpu.utils import trace
+
+    def bodies(text):
+        return re.findall(r"\\22body\\22: \\22([A-Za-z0-9+/=]+)", text)
+
+    shape = lambda s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)  # noqa: E731
+    lengths = {}
+    for length in (4096, 16384):
+        u, a, b = shape((1, 64, length, 64)), shape((1, 64, length)), shape((1, length, 128))
+        assert seq_ops.ssd_by_kernels(u, b, 256)
+
+        def loss(u, a, b, c):
+            with trace.scope("ssd.scan"):
+                y = seq_ops.chunked_ssd(u, a, b, c, chunk=256)
+            return jnp.sum(jnp.sin(y))
+
+        lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).lower(u, a, b, b)
+        lengths[length] = [len(x) for x in bodies(lowered.as_text())]
+        text = lowered.compile().as_text()
+        calls = re.findall(r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
+        kernels = sorted(name.split("/")[-2] for name in calls)
+        assert kernels == ["ssd_scan_backward", "ssd_scan_forward"]
+        for name in calls:
+            assert re.findall(r"euler\.([a-z_.]+)", name) == ["ssd.scan"], name
+            assert ("transpose(" in name) == ("ssd_scan_backward" in name), name
+        assert " while(" not in text
+    assert len(lengths[4096]) == 2 and lengths[4096][0] == lengths[16384][0]
+    assert abs(lengths[4096][1] - lengths[16384][1]) <= 64
+
+    def nine_layers(u, a, b, c):
+        return sum(
+            jnp.sum(jnp.sin(seq_ops.chunked_ssd(u * s, a, b, c, chunk=256)))
+            for s in range(1, 10)
+        )
+
+    text = jax.jit(jax.value_and_grad(nine_layers, argnums=(0, 1, 2, 3))).lower(u, a, b, b).as_text()
     assert len(bodies(text)) == 2
