@@ -251,11 +251,62 @@ class SparseMoE(nn.Module):
         return y, ends[-1]
 
 
+def _contract(a, b, axis):
+    """`a` and `b` contracted over their `axis`, accumulated in float32."""
+    return jax.lax.dot_general(
+        a, b, (((axis,), (axis,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
+@jax.custom_vjp
+def swiglu(x, w_gate, w_up, w_down):
+    """`_glu("silu", x, w_gate, w_up, w_down, jnp.matmul)`, whose backward
+    is written by hand (`_swiglu_bwd`)."""
+    return _glu("silu", x, w_gate, w_up, w_down, jnp.matmul)
+
+
+def _swiglu_fwd(x, w_gate, w_up, w_down):
+    # the products are made again in the backward: the decoder layer is
+    # rematerialised whole, and keeping them would only add memory
+    return swiglu(x, w_gate, w_up, w_down), (x, w_gate, w_up, w_down)
+
+
+def _swiglu_grads(operand, kept, dy):
+    """The cotangents of x and the three weights, each product's operands
+    of `operand` type. Left to `jax.vjp`, XLA fuses the gate's derivative
+    — float32 g, u and dh, a sigmoid — into the weight gradients'
+    products as their operand, which a product reads again for every
+    block of its output rows; here `dg`, `du` and `h` are made once, in
+    one pass, x and dy cast once, and each weight gradient reads arrays."""
+    x, w_gate, w_up, w_down = kept
+    g, u = jnp.matmul(x, w_gate), jnp.matmul(x, w_up)
+    dh = jnp.matmul(dy, w_down.T)
+    s = jax.nn.sigmoid(g)
+    made = (dh * u * s * (1 + g * (1 - s)), dh * g * s, g * s * u, x, dy)
+    dg, du, h, x_, dy_ = jax.lax.optimization_barrier(tuple(a.astype(operand) for a in made))
+    dx = _contract(dg, w_gate.astype(operand), 1) + _contract(du, w_up.astype(operand), 1)
+    return dx, _contract(x_, dg, 0), _contract(x_, du, 0), _contract(h, dy_, 0)
+
+
+def _swiglu_bwd(kept, dy):
+    # the operands as XLA's default precision takes a float32 product's on
+    # each platform: bfloat16 into the TPU's MXU; float32 elsewhere
+    return jax.lax.platform_dependent(
+        kept, dy,
+        tpu=functools.partial(_swiglu_grads, jnp.bfloat16),
+        default=functools.partial(_swiglu_grads, jnp.float32),
+    )
+
+
+swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)
+
+
 class DenseMLP(nn.Module):
     """The dense feed-forward a model's leading layers have in an expert
     layer's place: `W_down (SiLU(W_gate x) * W_up x)` at `dim`, under
-    `euler.mlp`. Called as an expert layer is: x [N, H] -> (y [N, H], 0
-    assignments routed). Counts itself `dense_layers`."""
+    `euler.mlp`, its backward `swiglu`'s. Called as an expert layer is:
+    x [N, H] -> (y [N, H], 0 assignments routed). Counts itself
+    `dense_layers` and `mlp_grads_by_hand`."""
 
     dim: int
 
@@ -266,5 +317,6 @@ class DenseMLP(nn.Module):
         w_up = self.param("up", _MATRIX, shape, jnp.float32)
         w_down = self.param("down", _MATRIX, shape[::-1], jnp.float32)
         trace.count("dense_layers")
+        trace.count("mlp_grads_by_hand")
         with trace.scope("mlp"):
-            return _glu("silu", x, w_gate, w_up, w_down, jnp.matmul), jnp.zeros((), jnp.int32)
+            return swiglu(x, w_gate, w_up, w_down), jnp.zeros((), jnp.int32)

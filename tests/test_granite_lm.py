@@ -267,6 +267,7 @@ def test_first_call_span_carries_the_layers_forms(config, three_steps):
     assert (args.get("ssd_scan_xla", 0), args.get("ssd_scan_kernel", 0)) == (1, 0)
     assert (args.get("attn_full_layers", 0), args.get("attn_ungated", 0), args.get("head_tied", 0)) == (1, 1, 1)
     assert (args.get("dense_layers", 0), args.get("mixer_core_kept", 0)) == (2, 1)
+    assert args.get("mlp_grads_by_hand", 0) == 2
     assert (args.get("sconv_layers", 0), args.get("router_sigmoid", 0), args.get("swa_layers", 0)) == (0, 0, 0)
 
 

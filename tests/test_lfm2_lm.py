@@ -346,6 +346,7 @@ def test_first_call_span_carries_the_layers_forms(config, three_steps):
     assert (args.get("sconv_layers", 0), args.get("sconv_taps", 0), args.get("head_tied", 0)) == (4, 12, 1)
     assert (args.get("attn_full_layers", 0), args.get("attn_ungated", 0), args.get("swa_layers", 0)) == (1, 1, 0)
     assert (args.get("dense_layers", 0), args.get("router_sigmoid", 0)) == (1, 4)
+    assert args.get("mlp_grads_by_hand", 0) == 1
     assert (args.get("router_on_input", 0), args.get("experts_relu", 0)) == (0, 0)
     assert args.get("mixer_core_kept", 0) == 1  # the one softmax layer; a convolution keeps nothing
     # 64 tokens in blocks of 16 at the rehearsal's size: no whole tile

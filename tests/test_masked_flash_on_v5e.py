@@ -235,3 +235,54 @@ def test_the_state_space_scans_kernels_compile_once_and_their_bodies_do_not_grow
 
     text = jax.jit(jax.value_and_grad(nine_layers, argnums=(0, 1, 2, 3))).lower(u, a, b, b).as_text()
     assert len(bodies(text)) == 2
+
+
+def test_the_dense_mlps_weight_gradients_read_an_array_and_no_sigmoid(one_chip, quiet_cache):
+    """`DenseMLP`'s value and gradient under `jax.checkpoint`, as the
+    decoder layer runs it, with Adam after it as the step has it: every
+    fusion whose output has a weight's shape and holds a product reads
+    its operand as an array made once — no float32 parameter of the
+    [tokens, dim] shape of g, u and dh, no `exponential` of the gate's
+    sigmoid inside it. Left to `jax.vjp`, XLA fuses the gate's
+    derivative into those products' operand (the parent's text at this
+    shape does, as at `granite`'s), and each re-reads it, float32 and
+    with a sigmoid, for every block of its output rows."""
+    import optax
+
+    from euler_tpu.layers.moe import DenseMLP
+
+    tokens, hidden, dim = 512, 128, 256
+    layer = DenseMLP(dim)
+    adam = optax.adam(1e-3)
+    x = jax.ShapeDtypeStruct((tokens, hidden), jnp.float32, sharding=one_chip)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+    placed = lambda t: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t
+    )
+    params, state = placed(params), placed(jax.eval_shape(adam.init, params))
+
+    def step(params, state, x):
+        def loss(p, x):
+            return jnp.sum(jnp.sin(jax.checkpoint(lambda p, x: layer.apply(p, x)[0])(p, x)))
+
+        value, (grads, dx) = jax.value_and_grad(loss, (0, 1))(params, x)
+        updates, state = adam.update(grads, state, params)
+        return optax.apply_updates(params, updates), state, value, dx
+
+    text = jax.jit(step).lower(params, state, x).compile().as_text()
+    bodies = dict(re.findall(r"^(%[\w.-]+) .*?\{\n(.*?)\n\}", text, re.S | re.M))
+
+    def called(name):  # a fused computation with those it calls
+        body = bodies[name]
+        return body + "".join(called(n) for n in re.findall(r"calls=(%[\w.-]+)", body))
+
+    weights = (f"f32[{hidden},{dim}]", f"f32[{dim},{hidden}]")
+    products = []
+    for line in text.split("\nENTRY", 1)[1].splitlines():
+        found = re.search(r"= \(?(f32\[\d+,\d+\]).*? fusion\(.*calls=(%[\w.-]+)", line)
+        if found and found.group(1) in weights and "convolution(" in called(found.group(2)):
+            products.append(called(found.group(2)))
+    assert len(products) >= 3  # one a weight, Adam in its epilogue
+    for body in products:
+        assert not re.findall(rf"f32\[{tokens},{dim}\]\S* parameter\(", body)
+        assert "exponential(" not in body

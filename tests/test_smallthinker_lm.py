@@ -266,6 +266,7 @@ def test_first_call_span_carries_the_layers_forms(config, three_steps):
     assert (args.get("router_on_input", 0), args.get("experts_relu", 0), args.get("attn_ungated", 0)) == (4, 4, 4)
     assert (args.get("swa_layers", 0), args.get("swa_window", 0), args.get("attn_full_layers", 0)) == (3, 3 * 24, 1)
     assert (args.get("dense_layers", 0), args.get("router_sigmoid", 0)) == (0, 0)
+    assert args.get("mlp_grads_by_hand", 0) == 0
     assert args.get("mixer_core_kept", 0) == 4  # every layer's mixer is a softmax attention
     # heads of 16 in blocks of 16 at the rehearsal's size: no whole tile
     assert (args.get("attn_core_dense", 0), args.get("attn_core_kernel", 0)) == (4, 0)

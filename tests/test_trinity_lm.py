@@ -197,6 +197,7 @@ def test_first_call_span_carries_the_layers_forms(config, three_steps):
     assert kinds == [LOCAL, LOCAL, FULL]
     assert (args.get("swa_layers", 0), args.get("swa_window", 0), args.get("attn_full_layers", 0)) == (2, 2 * 24, 1)
     assert (args.get("dense_layers", 0), args.get("router_sigmoid", 0)) == (1, 2)
+    assert args.get("mlp_grads_by_hand", 0) == 1
     assert args.get("mixer_core_kept", 0) == 3  # every layer's mixer is a softmax attention
     # heads of 16 in blocks of 16 at the rehearsal's size: no whole tile
     assert (args.get("attn_core_dense", 0), args.get("attn_core_kernel", 0)) == (3, 0)
